@@ -2,9 +2,9 @@
 greedy MAP vs the proposed Div-DPP acceleration, N = 0..50 step 5,
 M = 1000, D = 100 synthetic (paper §5.1 setup exactly).
 
-Also reports the Pallas whole-slate kernel (interpret mode on CPU — the
-interpreter adds Python overhead, so its wall time is NOT the TPU story;
-it is included for completeness and validated for exactness).
+Also reports the Pallas whole-slate kernel (compiled on a TPU; on the
+CPU it is interpreted, which adds Python overhead, so a CPU wall time
+is NOT the TPU story; it is validated for exactness either way).
 """
 from __future__ import annotations
 

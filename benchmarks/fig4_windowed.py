@@ -92,7 +92,7 @@ def run_gate(cells, k, trials):
             return best, sel
 
         t_k, sel_k = timed(
-            lambda: dpp_greedy(V, k, window=w, eps=1e-6, interpret=True)
+            lambda: dpp_greedy(V, k, window=w, eps=1e-6)
         )
         t_j, sel_j = timed(
             lambda: dpp_greedy(V, k, window=w, eps=1e-6, force_jnp=True)

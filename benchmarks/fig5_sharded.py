@@ -12,9 +12,11 @@ well below B x the single-slate row.  (On a host-device CPU mesh the
 CSV is evidence of the scaling structure, a real multi-chip mesh is
 where the wall-clock win lands.)
 
-XLA pins the host device count at first init, so each P runs in a fresh
-subprocess (same pattern as tests/test_distributed.py); the parent
-collects and prints one CSV row per (mode, P).
+On the CPU, XLA pins the host device count at first init, so each P
+runs in a fresh subprocess (same pattern as tests/test_distributed.py);
+the parent collects and prints one CSV row per (mode, P).  On a TPU the
+process that holds the chips runs the sweep itself, over all of
+``jax.devices()`` (a child could not reach a chip its parent holds).
 
   PYTHONPATH=src python -m benchmarks.fig5_sharded [--full | --smoke]
 """
@@ -30,26 +32,29 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 from repro.launch.hostdev import force_host_device_flags  # jax-import-free
 
 
-def _inner(args) -> None:
-    """Runs inside the subprocess with the device count already forced."""
+def _inner(args) -> list:
+    """One P: runs inside the subprocess with the device count already
+    forced (CPU), or in-process over the chips (TPU).  Prints and
+    returns the CSV rows."""
     import time
 
     import numpy as np
     import jax
     import jax.numpy as jnp
+    from jax.sharding import AxisType
 
     from repro.core.sharded import dpp_greedy_sharded
-    from repro.distributed.context import make_mesh_compat
     from repro.kernels.dpp_greedy import VMEM_BUDGET_BYTES, untiled_vmem_bytes
 
     P = jax.device_count()
     M = args.mloc * P
-    mesh = make_mesh_compat((P,), ("data",))
+    mesh = jax.make_mesh((P,), ("data",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(0)
     Vb = jnp.asarray(
         rng.normal(size=(args.batch, args.dim, M)), jnp.float32
     ) / np.sqrt(args.dim)
 
+    rows = []
     # B=1 single-slate rows plus a B>1 batched row per mode: the batched
     # rows measure the users x candidates composition — B slates share
     # the mesh, per-step collectives batch over B, so us_per_user_step
@@ -78,15 +83,24 @@ def _inner(args) -> None:
                     fn().indices.block_until_ready()
                     best = min(best, time.perf_counter() - t0)
                 tl = "" if tile is None else f"_tm{tile}"
-                print(
+                rows.append(
                     f"fig5_sharded_{label}{tl}_B{B}_P{P}_M{M},{best*1e6:.1f},"
                     f"us_per_user_step={best/(args.slate*B)*1e6:.2f};"
                     f"B={B};Mloc={args.mloc};D={args.dim};N={args.slate};"
                     f"tile_m={tile or 0};past_gate={past}"
                 )
+                print(rows[-1])
+    return rows
 
 
 def run(devices, mloc, dim, slate, window, trials, batch, tile_m):
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return _inner(argparse.Namespace(
+            mloc=mloc, dim=dim, slate=slate, window=window, trials=trials,
+            batch=batch, tile_m=tile_m,
+        ))
     rows, failures = [], []
     for P in devices:
         env = dict(os.environ)

@@ -149,6 +149,9 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     fast = not args.full
     os.makedirs(args.out_dir, exist_ok=True)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from benchmarks import (
         fig1_speedup,

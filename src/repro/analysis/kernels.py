@@ -144,8 +144,8 @@ def _drive_family(tiled, family: str, D: int, R: int,
         target = tiled._full_sweep
         run = lambda: tiled._full_sweep(  # noqa: E731
             V, C, jnp.zeros((B, 1, Mp), jnp.float32),
-            jnp.zeros((B, 1, D), jnp.float32),
-            jnp.zeros((B, 1, R), jnp.float32),
+            jnp.zeros((B, D, 1), jnp.float32),
+            jnp.zeros((B, R, 1), jnp.float32),
             jnp.zeros((B, 1, 2), jnp.float32),
             jnp.zeros((B, 1, 2), jnp.int32),
             tile_m=tile, interpret=True,
@@ -155,8 +155,8 @@ def _drive_family(tiled, family: str, D: int, R: int,
         nf = 3 + 2 * (R - 1)
         run = lambda: tiled._windowed_sweep(  # noqa: E731
             V, C, jnp.zeros((B, 1, Mp), jnp.float32),
-            jnp.zeros((B, 1, D), jnp.float32),
-            jnp.zeros((B, 1, R), jnp.float32),
+            jnp.zeros((B, D, 1), jnp.float32),
+            jnp.zeros((B, R, 1), jnp.float32),
             jnp.zeros((B, 1, nf), jnp.float32),
             jnp.zeros((B, 1, 3), jnp.int32),
             w=R, tile_m=tile, interpret=True,

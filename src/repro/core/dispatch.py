@@ -17,11 +17,12 @@ Dispatch rules:
   smaller windows run the O(w M)-per-step incremental sliding-window
   greedy (unbounded slate length);
 * ``spec.backend`` — "jnp" lowers through XLA; "pallas" routes low-rank
-  inputs through the TPU kernels (interpret-mode on CPU; dense inputs
-  are rejected — the kernels never materialize L); "sharded" shards the
-  candidate axis M over ``spec.mesh``'s ``spec.axis_name`` (low-rank;
-  batched V runs all B users on the mesh at once); "auto" picks
-  "sharded" when a mesh is set, else "jnp";
+  inputs through the TPU kernels (compiled on a TPU, interpreted on
+  other platforms; dense inputs are rejected — the kernels never
+  materialize L); "sharded" shards the candidate axis M over
+  ``spec.mesh``'s ``spec.axis_name`` (low-rank; batched V runs all B
+  users on the mesh at once); "auto" picks "sharded" when a mesh is
+  set, else "jnp";
 * ``spec.tile_m`` — candidate-axis tile for the Pallas kernels.  On the
   pallas backend it forces the tiled streaming kernels (by default
   ``TilePolicy`` keeps the whole-working-set resident kernels while
@@ -85,7 +86,6 @@ class GreedySpec:
     window: Optional[int] = None  # None = exact Algorithm 1
     backend: str = "auto"  # "auto" | "jnp" | "pallas" | "sharded"
     eps: float = 1e-6
-    interpret: bool = True  # Pallas interpret mode (CPU dev/test)
     mesh: Optional[object] = None  # jax Mesh for the sharded backend
     axis_name: str = "data"  # mesh axis the candidate axis shards over
     # Pallas candidate-axis tile: an explicit LANE multiple, "auto"
@@ -233,7 +233,6 @@ def greedy_map(
             eps=spec.eps,
             mask=mask,
             tile_m=spec.tile_m,
-            interpret=spec.interpret,
         )
 
     if spec.backend == "pallas":
@@ -247,7 +246,6 @@ def greedy_map(
             spec.k,
             mask=mb,
             eps=spec.eps,
-            interpret=spec.interpret,
             window=spec.window,
             tile_m=spec.tile_m,
         )

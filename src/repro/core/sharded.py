@@ -60,7 +60,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.greedy_chol import NEG_INF, GreedyResult
-from repro.distributed.context import shard_map_compat
 
 
 def _mesh_axis_size(mesh, axis_name: str) -> int:
@@ -100,7 +99,7 @@ def _bcast_from_owner(parts, owner, axis_name):
 
 def _exact_step_fn(
     eps: float, axis_name: str,
-    tile_m: Optional[int] = None, interpret: bool = True,
+    tile_m: Optional[int] = None,
 ):
     """Per-step body of sharded Algorithm 1, factored out so the
     whole-slate loop and the chunked streaming executor run the
@@ -125,7 +124,7 @@ def _exact_step_fn(
         vj, cj = z[:D], z[D:]
         e, d2 = tiled_update_exact(
             Vl, C, d2, vj, cj, dj, stopped, j, off,
-            tile_m=tile_m, interpret=interpret,
+            tile_m=tile_m,
         )
         C = C.at[t].set(e)
         return C, d2, stopped, j, dj
@@ -153,7 +152,7 @@ def _exact_step_fn(
 
 def _exact_body(
     k: int, eps: float, axis_name: str,
-    tile_m: Optional[int] = None, interpret: bool = True,
+    tile_m: Optional[int] = None,
 ):
     """Algorithm 1 with the candidate axis sharded; mirrors
     ``greedy_chol._greedy_loop`` operation-for-operation on each shard.
@@ -165,7 +164,7 @@ def _exact_body(
     global column offset makes the winner masking land on the owner —
     so an M/P shard past the VMEM budget streams in double-buffered
     tiles instead of lowering through unfused jnp."""
-    step = _exact_step_fn(eps, axis_name, tile_m, interpret)
+    step = _exact_step_fn(eps, axis_name, tile_m)
     # row layout (k, Mloc) for the tiled pass, column layout (Mloc, k)
     # for jnp — the latter kept so the reduction order (and therefore
     # d_hist) stays bitwise identical to the single-device path
@@ -199,7 +198,7 @@ def _exact_body(
 
 def _windowed_body(
     k: int, window: int, eps: float, axis_name: str,
-    tile_m: Optional[int] = None, interpret: bool = True,
+    tile_m: Optional[int] = None,
 ):
     """Sliding-window greedy with the candidate axis sharded; mirrors
     ``windowed._windowed_loop``.
@@ -220,7 +219,7 @@ def _windowed_body(
     over the shard.
     """
     w = min(window, k)
-    step = _windowed_step_fn(w, eps, axis_name, tile_m, interpret)
+    step = _windowed_step_fn(w, eps, axis_name, tile_m)
 
     def body_fn(Vl, maskl):
         Mloc = Vl.shape[1]
@@ -253,7 +252,7 @@ def _windowed_body(
 
 def _windowed_step_fn(
     w: int, eps: float, axis_name: str,
-    tile_m: Optional[int] = None, interpret: bool = True,
+    tile_m: Optional[int] = None,
 ):
     """Per-step body of the sharded sliding-window greedy, factored out
     so the whole-slate loop and the chunked streaming executor run the
@@ -293,7 +292,7 @@ def _windowed_step_fn(
         pos = jnp.minimum(t, w - 1)
         C, d2 = tiled_update_windowed(
             Vl, C, d2, vj, cj_post, djp, stopped, full, cos, sin,
-            j, off, pos, w=w, tile_m=tile_m, interpret=interpret,
+            j, off, pos, w=w, tile_m=tile_m,
         )
         win_shift = jnp.roll(win, -1)
         win1 = jnp.where(full, win_shift.at[w - 1].set(-1), win)
@@ -381,12 +380,11 @@ def _windowed_step_fn(
 def _greedy_fn(
     mesh, axis_name: str, k: int, window: Optional[int], eps: float,
     batched: bool = False, tile_m: Optional[int] = None,
-    interpret: bool = True,
 ):
     if window is None:
-        body = _exact_body(k, eps, axis_name, tile_m, interpret)
+        body = _exact_body(k, eps, axis_name, tile_m)
     else:
-        body = _windowed_body(k, window, eps, axis_name, tile_m, interpret)
+        body = _windowed_body(k, window, eps, axis_name, tile_m)
     if batched:
         # vmap inside shard_map: every device runs all B users on its
         # (B, D, Mloc) block and the per-step collectives batch over B
@@ -395,11 +393,12 @@ def _greedy_fn(
     else:
         in_specs = (P(None, axis_name), P(axis_name))
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(P(), P(), P()),
+            check_vma=False,
         )
     )
 
@@ -426,8 +425,9 @@ def _stream_init_fn(mesh, axis_name: str, batched: bool = False):
         in_specs = (P(None, axis_name), P(axis_name))
         out_specs = P(axis_name)
     return jax.jit(
-        shard_map_compat(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+        jax.shard_map(
+            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
     )
 
@@ -436,7 +436,7 @@ def _stream_init_fn(mesh, axis_name: str, batched: bool = False):
 def _stream_chunk_fn(
     mesh, axis_name: str, chunk: int, w: Optional[int], eps: float,
     batched: bool = False, tile_m: Optional[int] = None,
-    interpret: bool = True, t_batched: bool = False,
+    t_batched: bool = False,
 ):
     """Compiled shard_map advancing ``chunk`` greedy steps on resumable
     sharded state.  The per-device loop body is built from the same step
@@ -448,7 +448,7 @@ def _stream_chunk_fn(
     windowed = w is not None
 
     if windowed:
-        step = _windowed_step_fn(w, eps, axis_name, tile_m, interpret)
+        step = _windowed_step_fn(w, eps, axis_name, tile_m)
 
         def body(Vl, C, d2, win, stopped, t0):
             Mloc = Vl.shape[1]
@@ -474,7 +474,7 @@ def _stream_chunk_fn(
         state_in = (c_spec, P(axis_name), P(), P())
         state_out = (c_spec, P(axis_name), P(), P())
     else:
-        step = _exact_step_fn(eps, axis_name, tile_m, interpret)
+        step = _exact_step_fn(eps, axis_name, tile_m)
 
         def body(Vl, C, d2, stopped, t0):
             Mloc = Vl.shape[1]
@@ -524,9 +524,24 @@ def _stream_chunk_fn(
         in_specs = (P(None, axis_name),) + state_in + (P(),)
         out_specs = state_out + (P(), P())
     return jax.jit(
-        shard_map_compat(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+        jax.shard_map(
+            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
+    )
+
+
+def _record_dispatch(V, M, k, window, tile_m):
+    """Count the per-device update path: the tiled Pallas pass with
+    ``tile_m`` set, the jnp step bodies without."""
+    from repro.kernels.platform import resolve_interpret
+    from repro.obs.dispatch import record_kernel_dispatch
+
+    record_kernel_dispatch(
+        "tiled" if tile_m is not None else "jnp", D=V.shape[-2], M=M,
+        state_rows=k if window is None else min(window, k),
+        windowed=window is not None, tile_m=tile_m,
+        interpret=resolve_interpret(),
     )
 
 
@@ -579,6 +594,8 @@ def dpp_greedy_sharded_stream_init(
         mask = jnp.ones(mask_shape, bool)
     elif mask.shape != mask_shape:
         mask = jnp.broadcast_to(mask, mask_shape)
+    windowed = window is not None and window < k
+    _record_dispatch(V, M, k, window if windowed else None, tile_m)
     quantum = nshards * (tile_m or 1)
     Mp = -(-M // quantum) * quantum
     V = _stream_pad(V, Mp)
@@ -589,7 +606,6 @@ def dpp_greedy_sharded_stream_init(
         )
     d2 = _stream_init_fn(mesh, axis_name, batched)(V, mask)
     dtype = V.dtype
-    windowed = window is not None and window < k
     lead = (B,) if batched else ()
     if windowed:
         w = min(window, k)
@@ -612,7 +628,6 @@ def dpp_greedy_sharded_stream_chunk(
     axis_name: str = "data",
     eps: float = 1e-6,
     tile_m: Optional[int] = None,
-    interpret: bool = True,
 ):
     """Advance ``chunk`` sharded greedy steps on a resumable state.
 
@@ -635,8 +650,7 @@ def dpp_greedy_sharded_stream_chunk(
     w = state.win.shape[-1] if windowed else None
     t_batched = batched and jnp.ndim(state.t) == 1
     fn = _stream_chunk_fn(
-        mesh, axis_name, chunk, w, float(eps), batched, tile_m, interpret,
-        t_batched,
+        mesh, axis_name, chunk, w, float(eps), batched, tile_m, t_batched,
     )
     if windowed:
         C, d2, win, stopped, sel, dh = fn(
@@ -661,7 +675,6 @@ def dpp_greedy_sharded(
     eps: float = 1e-6,
     mask: Optional[jnp.ndarray] = None,
     tile_m: Optional[int] = None,
-    interpret: bool = True,
 ) -> GreedyResult:
     """Greedy DPP MAP with the candidate axis of ``V`` sharded.
 
@@ -688,8 +701,8 @@ def dpp_greedy_sharded(
     ``tile_m``-column blocks — the same kernel the single-device tiled
     path runs — so shards whose (D, M/P) working set exceeds the VMEM
     budget stream through it instead of lowering through unfused jnp.
-    ``M`` is padded up to a multiple of ``P * tile_m``.  ``interpret``
-    applies to those Pallas calls (interpret mode on CPU meshes).
+    ``M`` is padded up to a multiple of ``P * tile_m``; those Pallas
+    calls run compiled on a TPU mesh and interpreted on a CPU one.
     """
     if V.ndim not in (2, 3):
         raise ValueError(
@@ -718,9 +731,9 @@ def dpp_greedy_sharded(
         V = jnp.pad(V, pad)
         mask = jnp.pad(mask, pad[1:], constant_values=False)
     window_eff = window if (window is not None and window < k) else None
+    _record_dispatch(V, M, k, window_eff, tile_m)
     fn = _greedy_fn(
         mesh, axis_name, k, window_eff, float(eps), batched, tile_m,
-        interpret,
     )
     sel, n, d_hist = fn(V, mask)
     return GreedyResult(sel, n, d_hist)
@@ -777,11 +790,12 @@ def _topk_fn(mesh, axis_name: str, c: int, batched: bool = False):
     else:
         in_specs = (P(axis_name),)
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(P(), P()),
+            check_vma=False,
         )
     )
 

@@ -242,14 +242,13 @@ def greedy_chunk(
 
         return dpp_greedy_sharded_stream_chunk(
             V, state, chunk, mesh=spec.mesh, axis_name=spec.axis_name,
-            eps=spec.eps, tile_m=spec.tile_m, interpret=spec.interpret,
+            eps=spec.eps, tile_m=spec.tile_m,
         )
     if spec.backend == "pallas":
         from repro.kernels.dpp_greedy import dpp_greedy_stream_chunk
 
         return dpp_greedy_stream_chunk(
             V, state, chunk, eps=spec.eps, tile_m=spec.tile_m,
-            interpret=spec.interpret,
         )
     fn = _chunk_dense if L is not None else _chunk_lowrank
     return fn(L if L is not None else V, state, chunk, float(spec.eps))
@@ -296,7 +295,9 @@ def _delta_cols(V, C, d2, win, start, V_blk, mask_blk, keep_dead: bool):
     # empty ring slots become identity rows so the solve is a no-op there.
     Vw = jnp.where(valid[:, None], C[:, ids].T, jnp.eye(w, dtype=dtype))
     # b[r] = L_{win[r], blk} from the (unchanged) window columns of V
-    b = jnp.where(valid[:, None], V[:, ids].T @ V_blk, 0.0)
+    # full f32 products: a TPU's default f32 matmul rounds through bf16
+    b = jnp.matmul(V[:, ids].T, V_blk, precision=jax.lax.Precision.HIGHEST)
+    b = jnp.where(valid[:, None], b, 0.0)
     c = jax.scipy.linalg.solve_triangular(Vw, b, lower=True)  # (w, dm)
     diag_blk = jnp.sum(V_blk * V_blk, axis=0)
     d2_blk = jnp.where(mask_blk, diag_blk - jnp.sum(c * c, axis=0), NEG_INF)
@@ -582,13 +583,12 @@ def greedy_chunk_slots(spec, state: GreedyState, V_slots, chunk: int):
 
         return dpp_greedy_sharded_stream_chunk(
             V_slots, state, chunk, mesh=spec.mesh, axis_name=spec.axis_name,
-            eps=spec.eps, tile_m=spec.tile_m, interpret=spec.interpret,
+            eps=spec.eps, tile_m=spec.tile_m,
         )
     if spec.backend == "pallas":
         from repro.kernels.dpp_greedy import dpp_greedy_stream_chunk
 
         return dpp_greedy_stream_chunk(
             V_slots, state, chunk, eps=spec.eps, tile_m=spec.tile_m,
-            interpret=spec.interpret,
         )
     return _chunk_lowrank_slots(V_slots, state, chunk, float(spec.eps))

@@ -249,9 +249,11 @@ def windowed_state_rebuild(V, shown, dead):
     Vwin = jnp.where(valid[:, None], V[:, ids].T, 0.0)  # (w, D) rows
     eye = jnp.eye(w, dtype=dtype)
     vm = valid[:, None] & valid[None, :]
-    Lw = jnp.where(vm, Vwin @ Vwin.T, eye)
+    # full f32 products: a TPU's default f32 matmul rounds through bf16
+    hi = jax.lax.Precision.HIGHEST
+    Lw = jnp.where(vm, jnp.matmul(Vwin, Vwin.T, precision=hi), eye)
     F = jnp.linalg.cholesky(Lw)
-    Lwi = Vwin @ V  # (w, M); zero rows at empty ring slots
+    Lwi = jnp.matmul(Vwin, V, precision=hi)  # (w, M); zero rows at empty slots
     C = jax.scipy.linalg.solve_triangular(F, Lwi, lower=True)
     C = jnp.where(valid[:, None], C, 0.0)
     d2 = jnp.sum(V * V, axis=0) - jnp.sum(C * C, axis=0)

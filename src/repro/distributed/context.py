@@ -89,38 +89,6 @@ def recsys_a2a_rules(multi_pod: bool) -> Mapping[str, AxisVal]:
     return base
 
 
-def make_mesh_compat(axis_shapes, axis_names, devices=None):
-    """``jax.make_mesh`` with Auto axis types where supported.
-
-    ``jax.sharding.AxisType`` landed after jax 0.4.x; on older jax the
-    plain mesh (implicitly auto) is equivalent for our profiles, so fall
-    back rather than pinning a floor we can't install everywhere.
-    """
-    kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check=False):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.5 exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  Same
-    semantics for our SPMD bodies either way.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 _MESH: contextvars.ContextVar = contextvars.ContextVar("mesh", default=None)
 
 

@@ -2,7 +2,9 @@
 
 Each subpackage ships ``<name>.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jit'd public wrapper with padding + fallback) and ``ref.py``
-(pure-jnp oracle); tests sweep shapes/dtypes in interpret mode.
+(pure-jnp oracle).  Kernels run compiled on a TPU and interpreted
+elsewhere (``repro.kernels.platform``), so the CPU test sweeps run them
+interpreted.
 """
 from repro.kernels.dpp_greedy import dpp_greedy
 from repro.kernels.fm_interaction import fm_interaction

@@ -99,10 +99,11 @@ def bucket_m(M: int) -> int:
 
 
 def _norm_field(value: object) -> str:
-    """Normalize a free-text key field (device kind etc.): lowercase,
-    trimmed, with the ``|`` delimiter and whitespace runs collapsed to
-    ``-`` so no field can smuggle a delimiter into the key."""
-    s = " ".join(str(value).strip().lower().split())
+    """Normalize a free-text key field (device kind etc.): case-folded
+    (``casefold``, so e.g. U+00B5 and U+03BC agree, which ``lower``
+    misses), trimmed, with the ``|`` delimiter and whitespace runs
+    collapsed to ``-`` so no field can smuggle a delimiter into the key."""
+    s = " ".join(str(value).strip().casefold().split())
     return s.replace("|", "-").replace(" ", "-") or "unknown"
 
 
@@ -420,7 +421,7 @@ def _case_inputs(case: SweepCase):
 
 
 def _time_case(case: SweepCase, tile: int, trials: int,
-               interpret: bool = True) -> float:
+               interpret: Optional[bool] = None) -> float:
     """Best-of-``trials`` wall seconds for one real dispatch of the
     case's seam with an explicit ``TilePolicy(tile_m=tile)`` (the
     policy object bypasses the ``DPP_TILE_M`` env override, so a sweep
@@ -469,13 +470,18 @@ def run_sweep(
     trials: int = 2,
     limit: Optional[int] = None,
     path: Optional[str] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
     log=None,
 ) -> tuple[list[dict], str]:
     """Measure every case, persist the winners (merging into whatever
     the cache file already holds), and return
-    ``([{case, key, tile_m, best_us, candidates}, ...], path)``."""
+    ``([{case, key, tile_m, best_us, candidates}, ...], path)``.  The
+    kernels run compiled on a TPU and interpreted elsewhere; entries
+    record which."""
+    from repro.kernels.platform import resolve_interpret
+
+    interpret = resolve_interpret(interpret)
     path = path or active_cache_path()
     cache = AutotuneCache.load(path)
     if cache.corrupt:
@@ -564,9 +570,6 @@ def main(argv: Optional[Iterable[str]] = None) -> int:
     ap.add_argument("--trials", type=int, default=None,
                     help="timing trials per candidate (default 1 smoke, "
                          "3 full)")
-    ap.add_argument("--compiled", action="store_true",
-                    help="measure compiled pallas_call launches instead "
-                         "of interpret mode (real TPU/GPU)")
     args = ap.parse_args(list(argv) if argv is not None else None)
 
     smoke = args.smoke or not args.full
@@ -576,8 +579,7 @@ def main(argv: Optional[Iterable[str]] = None) -> int:
 
     print("name,us_per_call,derived")
     results, path = run_sweep(
-        cases, trials=trials, limit=limit, path=args.out,
-        interpret=not args.compiled, log=print,
+        cases, trials=trials, limit=limit, path=args.out, log=print,
     )
     for r in results:
         case = r["case"]

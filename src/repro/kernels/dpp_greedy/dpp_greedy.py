@@ -4,11 +4,11 @@ TPU-native adaptation of the paper's Algorithm 1 (DESIGN.md §3):
 
 * the kernel never materializes ``L`` — it holds the *scaled feature*
   matrix ``V (D, M)`` (``L = V^T V``) in VMEM and recomputes the needed
-  kernel row ``L_j = V[:, j]^T V`` on the MXU each step;
+  kernel row ``L_j = V[:, j]^T V`` each step (an f32 multiply-reduce);
 * the Cholesky-state matrix ``C`` is laid out **(N, M)** — step ``t``
-  writes *row* ``t`` (a contiguous lane-dim store) instead of the paper's
-  per-candidate column append, and the update inner product
-  ``<c_j, c_i>`` for all ``i`` is the matvec ``c_j^T C`` on the MXU;
+  writes *row* ``t`` instead of the paper's per-candidate column
+  append, and the update inner product ``<c_j, c_i>`` for all ``i`` is
+  the matvec ``c_j^T C``;
 * the entire N-step greedy loop runs inside one kernel invocation with
   zero HBM round-trips between steps; the grid dimension is the *user
   batch* (one program = one user's slate).
@@ -22,10 +22,15 @@ the rows of ``C``, with the rotation residue row repairing ``d2``), and
 append (the same eq. 16-18 row append as the full kernel, against the
 post-eviction window).  See ``repro.core.windowed`` for the math.
 
+Both kernels call the per-tile update functions of ``tiled.py`` over the
+whole M (one tile), so resident, tiled and fused chunk kernels compute
+the same bits; the winner's columns are read by one-hot reduction
+(Mosaic has no lane-axis dynamic slice).
+
 VMEM working set (resident mode): ``V`` (D*M*4) + ``C`` (N*M*4, or
 w*M*4 windowed) + ``d2/e`` rows — e.g. D=128, M=4096, N=64: 2 MB +
-1 MB, comfortably inside 16 MB v5e VMEM
-(``tiling.untiled_vmem_bytes``).  These kernels hold that working set
+1 MB (``tiling.untiled_vmem_bytes``), against a 12 MB budget and a
+64 MB scoped-VMEM compile limit.  These kernels hold that working set
 *whole*, which is what buys the zero-HBM-round-trip greedy loop — and
 what caps M.  Past the budget the ops.py wrapper dispatches the tiled
 streaming kernels in ``tiled.py`` instead (per-step grid sweeps over
@@ -42,10 +47,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dpp_greedy.tiled import (
+    _argmax_first,
+    _evict_coeffs_tile,
+    _lane_pick,
+    _lane_set,
+    _row_set,
+    _tile_update_full,
+    _tile_update_windowed,
+    COMPILER_PARAMS,
+)
+from repro.kernels.platform import resolve_interpret
+
 NEG_INF = float("-inf")
 
 
-def _kernel(v_ref, mask_ref, sel_ref, dhist_ref, c_ref, *, k: int, eps: float):
+def _init(v_ref, mask_ref, sel_ref, dhist_ref, c_ref, d2_ref):
+    V = v_ref[...]
+    diag = jnp.sum(V * V, axis=0, keepdims=True)  # (1, M)
+    d2_ref[...] = jnp.where(mask_ref[...] > 0, diag, NEG_INF)
+    c_ref[...] = jnp.zeros(c_ref.shape, jnp.float32)
+    sel_ref[...] = jnp.full(sel_ref.shape, -1, jnp.int32)
+    dhist_ref[...] = jnp.zeros(dhist_ref.shape, jnp.float32)
+
+
+def _kernel(v_ref, mask_ref, sel_ref, dhist_ref, c_ref, d2_ref, *, k: int,
+            eps: float):
     """One user's full greedy slate.
 
     v_ref:    (D, M) f32 — scaled features, L = V^T V
@@ -53,52 +80,37 @@ def _kernel(v_ref, mask_ref, sel_ref, dhist_ref, c_ref, *, k: int, eps: float):
     sel_ref:  (1, N) i32 out
     dhist_ref:(1, N) f32 out
     c_ref:    (N, M) f32 VMEM scratch — incremental Cholesky rows
+    d2_ref:   (1, M) f32 VMEM scratch — marginal gains
     """
-    V = v_ref[...]
-    mask = mask_ref[...]  # (1, M)
-    M = V.shape[1]
     eps2 = eps * eps
+    _init(v_ref, mask_ref, sel_ref, dhist_ref, c_ref, d2_ref)
+    M = v_ref.shape[1]
 
-    diag = jnp.sum(V * V, axis=0, keepdims=True)  # (1, M)
-    d2 = jnp.where(mask > 0, diag, NEG_INF)
-    c_ref[...] = jnp.zeros_like(c_ref)
-    sel_ref[...] = jnp.full(sel_ref.shape, -1, jnp.int32)
-    dhist_ref[...] = jnp.zeros(dhist_ref.shape, jnp.float32)
-
-    def body(t, carry):
-        d2, stopped = carry
-        j = jnp.argmax(d2[0])
-        dj2 = d2[0, j]
-        stopped = jnp.logical_or(stopped, dj2 <= eps2)
+    def body(t, stopped):  # stopped: (1, 1) int32 (no i1 loop carries)
+        V, C, d2 = v_ref[...], c_ref[...], d2_ref[...]
+        dj2, j = _argmax_first(d2)
+        stopped = jnp.logical_or(stopped > 0, dj2 <= eps2)
         dj = jnp.sqrt(jnp.maximum(dj2, eps2))
+        # the winner's columns by one-hot lane reduction (Mosaic has no
+        # lane-axis dynamic slice), then the shared per-tile update
+        e, d2o = _tile_update_full(
+            V, C, d2, _lane_pick(V, j), _lane_pick(C, j), dj, stopped, j,
+            0, 0, M,
+        )
+        c_ref[...] = _row_set(C, t, e)
+        d2_ref[...] = d2o
+        sel_ref[...] = _lane_set(sel_ref[...], t, jnp.where(stopped, -1, j))
+        dhist_ref[...] = _lane_set(
+            dhist_ref[...], t, jnp.where(stopped, 0.0, dj)
+        )
+        return stopped.astype(jnp.int32)
 
-        # kernel row L_j = V[:, j]^T V  — (1, D) x (D, M) on the MXU
-        vj = jax.lax.dynamic_slice(V, (0, j), (V.shape[0], 1))  # (D, 1)
-        lj = jnp.dot(vj.T, V, preferred_element_type=jnp.float32)  # (1, M)
-
-        # <c_j, c_i> for all i — (1, N) x (N, M) on the MXU
-        cj = jax.lax.dynamic_slice(c_ref[...], (0, j), (c_ref.shape[0], 1))  # (N,1)
-        dots = jnp.dot(cj.T, c_ref[...], preferred_element_type=jnp.float32)
-
-        e = (lj - dots) / dj  # (1, M)
-        e = jnp.where(stopped, jnp.zeros_like(e), e)
-        pl.store(c_ref, (pl.dslice(t, 1), pl.dslice(0, M)), e)
-
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, M), 1)
-        d2_next = jnp.where(iota == j, NEG_INF, d2 - e * e)
-        d2 = jnp.where(stopped, d2, d2_next)
-
-        sel_val = jnp.where(stopped, -1, j).astype(jnp.int32)
-        pl.store(sel_ref, (pl.dslice(0, 1), pl.dslice(t, 1)), sel_val[None, None])
-        d_val = jnp.where(stopped, 0.0, dj).astype(jnp.float32)
-        pl.store(dhist_ref, (pl.dslice(0, 1), pl.dslice(t, 1)), d_val[None, None])
-        return d2, stopped
-
-    jax.lax.fori_loop(0, k, body, (d2, jnp.asarray(False)))
+    jax.lax.fori_loop(0, k, body, jnp.zeros((1, 1), jnp.int32))
 
 
 def _kernel_windowed(
-    v_ref, mask_ref, sel_ref, dhist_ref, c_ref, *, k: int, w: int, eps: float
+    v_ref, mask_ref, sel_ref, dhist_ref, c_ref, d2_ref, *, k: int, w: int,
+    eps: float,
 ):
     """One user's full slate with a sliding diversity window of ``w``.
 
@@ -108,83 +120,56 @@ def _kernel_windowed(
     dhist_ref:(1, N) f32 out
     c_ref:    (w, M) f32 VMEM scratch — ring of window Cholesky rows in
               window order (row 0 = oldest pick still in the window)
-    """
-    V = v_ref[...]
-    mask = mask_ref[...]  # (1, M)
-    M = V.shape[1]
-    eps2 = eps * eps
-    tiny = 1e-30
+    d2_ref:   (1, M) f32 VMEM scratch — marginal gains
 
-    diag = jnp.sum(V * V, axis=0, keepdims=True)  # (1, M)
-    d2 = jnp.where(mask > 0, diag, NEG_INF)
-    c_ref[...] = jnp.zeros_like(c_ref)
-    sel_ref[...] = jnp.full(sel_ref.shape, -1, jnp.int32)
-    dhist_ref[...] = jnp.zeros(dhist_ref.shape, jnp.float32)
+    Each step gathers the (w, w) window factor ``C[:, win]``, derives
+    the eviction rotations from it (:func:`_evict_coeffs_tile`) and
+    applies evict + append in one pass — the same per-tile update the
+    tiled and fused chunk kernels run, over the whole M.
+    """
+    eps2 = eps * eps
+    _init(v_ref, mask_ref, sel_ref, dhist_ref, c_ref, d2_ref)
+    M = v_ref.shape[1]
 
     def body(t, carry):
-        d2, win, stopped = carry
-        # ---- select against the current window of min(t, w) picks
-        j = jnp.argmax(d2[0])
-        dj2 = d2[0, j]
-        stopped = jnp.logical_or(stopped, dj2 <= eps2)
+        win, stopped = carry
+        V, C, d2 = v_ref[...], c_ref[...], d2_ref[...]
+        dj2, j = _argmax_first(d2)
+        stopped = jnp.logical_or(stopped > 0, dj2 <= eps2)
         dj = jnp.sqrt(jnp.maximum(dj2, eps2))
-
-        # ---- evict the oldest pick: first-row Cholesky downdate as
-        # w - 1 Givens rotations swept over the rows of C; identity
-        # rotation (cos=1, sin=0, read==write row) when not evicting
         full = jnp.logical_and(t >= w, jnp.logical_not(stopped))
-        u0 = jnp.where(full, c_ref[0:1, :], jnp.zeros((1, M), jnp.float32))
-        win_shift = jnp.roll(win, -1, axis=1)  # win_shift[0, r] = old win[0, r+1]
 
-        def rot(r, u):
-            read = jnp.where(full, r + 1, r)
-            row = pl.load(c_ref, (pl.dslice(read, 1), pl.dslice(0, M)))  # (1, M)
-            idx = jnp.maximum(win_shift[0, r], 0)
-            a = jax.lax.dynamic_slice(row, (0, idx), (1, 1))[0, 0]
-            b = jax.lax.dynamic_slice(u, (0, idx), (1, 1))[0, 0]
-            rho = jnp.maximum(jnp.sqrt(a * a + b * b), tiny)
-            cos = jnp.where(full, a / rho, 1.0)
-            sin = jnp.where(full, b / rho, 0.0)
-            pl.store(c_ref, (pl.dslice(r, 1), pl.dslice(0, M)), cos * row + sin * u)
-            return cos * u - sin * row
-
-        u = jax.lax.fori_loop(0, w - 1, rot, u0)
-        last = c_ref[w - 1 : w, :]
-        c_ref[w - 1 : w, :] = jnp.where(full, jnp.zeros_like(last), last)
-        d2 = jnp.where(full, d2 + u * u, d2)
-        win = jnp.where(full, win_shift.at[0, w - 1].set(-1), win)
-
-        # ---- append j against the post-eviction window (eqs. 16-18)
-        djp = jnp.sqrt(jnp.maximum(d2[0, j], eps2))
-        vj = jax.lax.dynamic_slice(V, (0, j), (V.shape[0], 1))  # (D, 1)
-        lj = jnp.dot(vj.T, V, preferred_element_type=jnp.float32)  # (1, M)
-        cj = jax.lax.dynamic_slice(c_ref[...], (0, j), (w, 1))  # (w, 1)
-        dots = jnp.dot(cj.T, c_ref[...], preferred_element_type=jnp.float32)
-        e = (lj - dots) / djp  # (1, M)
-
+        Cw = jnp.zeros((w, w), jnp.float32)
+        for r in range(w):
+            idx = _lane_pick(win, r)
+            col = jnp.where(idx >= 0, _lane_pick(C, idx), 0.0)
+            Cw = _lane_set(Cw, r, col)
+        coss, sins, cj_post, d2j = _evict_coeffs_tile(
+            Cw, _lane_pick(C, j), dj2, full, w
+        )
+        djp = jnp.sqrt(jnp.maximum(d2j, eps2))
         pos = jnp.minimum(t, w - 1)
-        old = pl.load(c_ref, (pl.dslice(pos, 1), pl.dslice(0, M)))
-        pl.store(
-            c_ref,
-            (pl.dslice(pos, 1), pl.dslice(0, M)),
-            jnp.where(stopped, old, e),
+        C_out, d2o, _ = _tile_update_windowed(
+            V, C, d2, _lane_pick(V, j), cj_post, djp, stopped, full,
+            coss, sins, j, 0, pos, 0, w, M,
         )
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, M), 1)
-        d2_next = jnp.where(iota == j, NEG_INF, d2 - e * e)
-        d2 = jnp.where(stopped, d2, d2_next)
-        win_next = jax.lax.dynamic_update_slice(
-            win, j[None, None].astype(jnp.int32), (0, pos)
-        )
-        win = jnp.where(stopped, win, win_next)
+        c_ref[...] = C_out
+        d2_ref[...] = d2o
 
-        sel_val = jnp.where(stopped, -1, j).astype(jnp.int32)
-        pl.store(sel_ref, (pl.dslice(0, 1), pl.dslice(t, 1)), sel_val[None, None])
-        d_val = jnp.where(stopped, 0.0, dj).astype(jnp.float32)
-        pl.store(dhist_ref, (pl.dslice(0, 1), pl.dslice(t, 1)), d_val[None, None])
-        return d2, win, stopped
+        shifted = jnp.full((1, w), -1, jnp.int32)
+        for c in range(w - 1):
+            shifted = _lane_set(shifted, c, _lane_pick(win, c + 1))
+        win1 = jnp.where(full, shifted, win)
+        win = jnp.where(stopped, win, _lane_set(win1, pos, j))
+
+        sel_ref[...] = _lane_set(sel_ref[...], t, jnp.where(stopped, -1, j))
+        dhist_ref[...] = _lane_set(
+            dhist_ref[...], t, jnp.where(stopped, 0.0, dj)
+        )
+        return win, stopped.astype(jnp.int32)
 
     win0 = jnp.full((1, w), -1, jnp.int32)
-    jax.lax.fori_loop(0, k, body, (d2, win0, jnp.asarray(False)))
+    jax.lax.fori_loop(0, k, body, (win0, jnp.zeros((1, 1), jnp.int32)))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "window", "eps", "interpret"))
@@ -194,7 +179,7 @@ def dpp_greedy_kernel(
     k: int,
     window: int | None = None,
     eps: float = 1e-3,
-    interpret: bool = True,
+    interpret=None,
 ):
     """Batched greedy DPP MAP on TPU.
 
@@ -228,7 +213,11 @@ def dpp_greedy_kernel(
             jax.ShapeDtypeStruct((B, 1, k), jnp.int32),
             jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((state_rows, M), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[
+            pltpu.VMEM((state_rows, M), jnp.float32),
+            pltpu.VMEM((1, M), jnp.float32),
+        ],
+        compiler_params=COMPILER_PARAMS,
+        interpret=resolve_interpret(interpret),
     )(V.astype(jnp.float32), mask)
     return sel[:, 0, :], dhist[:, 0, :]

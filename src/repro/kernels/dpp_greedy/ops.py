@@ -46,6 +46,7 @@ from repro.kernels.dpp_greedy.tiling import (  # noqa: F401
     untiled_vmem_bytes,
     validate_tile_m,
 )
+from repro.kernels.platform import resolve_interpret
 from repro.obs.dispatch import (
     record_kernel_dispatch,
     record_tile_override,
@@ -118,7 +119,7 @@ def dpp_greedy(
     k: int,
     mask: jnp.ndarray | None = None,
     eps: float = 1e-3,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     force_jnp: bool = False,
     window: int | None = None,
     tile_m: _TileM = None,
@@ -138,6 +139,8 @@ def dpp_greedy(
     working set fits VMEM and the widest model-fitting tile otherwise.
     The ``DPP_TILE_M`` env var (an int or ``auto``) overrides ``tile_m``
     process-wide; an explicit ``tile_policy=`` object bypasses the env.
+    ``interpret`` defaults to the platform (compiled on a TPU,
+    interpreted elsewhere — ``repro.kernels.platform``).
     """
     B, D, M = V.shape
     if window is not None and window < 1:
@@ -154,8 +157,10 @@ def dpp_greedy(
 
     policy = _resolve_tile_policy(tile_m, tile_policy)
     mode, tm = policy.decide(D, M, state_rows, windowed)
+    interpret = resolve_interpret(interpret)
     record_kernel_dispatch(
         mode, D=D, M=M, state_rows=state_rows, windowed=windowed, tile_m=tm,
+        interpret=interpret,
         vmem_bytes=(
             untiled_vmem_bytes(D, M, state_rows) if mode == "resident"
             else tile_vmem_bytes(D, tm, state_rows, windowed)
@@ -239,7 +244,7 @@ def dpp_greedy_stream_init(
     tile, Mp = _stream_tile(D, M, R, windowed, tile_m, tile_policy)
     record_kernel_dispatch(
         "fused_chunk", D=D, M=M, state_rows=R, windowed=windowed,
-        tile_m=tile,
+        tile_m=tile, interpret=resolve_interpret(),
         vmem_bytes=tile_vmem_bytes(D, tile, R, windowed, chunked=True),
     )
     if mask is None:
@@ -288,7 +293,7 @@ def dpp_greedy_stream_chunk(
     eps: float = 1e-3,
     tile_m: _TileM = None,
     tile_policy: Optional[TilePolicy] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Advance ``chunk`` greedy steps on a Pallas streaming state.
 

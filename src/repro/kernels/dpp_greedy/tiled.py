@@ -13,19 +13,30 @@ streaming):
 
 1. **streamed pass** (``_pass_full`` / ``_pass_windowed``): every tile
    applies the update for the *previously selected* winner ``j`` —
-   ``e = (L_j - c_j^T C) / d_j`` on the MXU, ``d2 -= e^2``, the row
-   append (and, windowed, the eviction Givens rotations) — and folds a
-   running ``(d2_max, argmax)`` reduction into revisited ``(1, 1)``
-   output cells, so the next winner is known when the sweep ends;
+   ``e = (L_j - c_j^T C) / d_j``, ``d2 -= e^2``, the row append (and,
+   windowed, the eviction Givens rotations) — and folds a running
+   ``(d2_max, argmax)`` reduction into revisited ``(1, 1)`` output
+   cells, so the next winner is known when the sweep ends;
 2. **winner-column visit**: only the winner's column is touched —
    ``V[:, j]`` and ``C[:, j]`` are gathered at the JAX level (an O(D)
    /O(state_rows) dynamic slice into HBM, not another sweep) and fed
-   to the next step's pass as tiny replicated operands.
+   to the next step's pass as tiny replicated ``(rows, 1)`` columns.
 
 Everything data-dependent but small — the winner column, the windowed
 eviction rotation coefficients (computed from the ``(w, w)`` window
 factor ``C[:, win]``), the eps-stop flag — is resolved between sweeps
 at the JAX level, so the kernels themselves stay shape-static.
+
+Kernel bodies are written for the Mosaic TPU compiler, which has no
+lane-axis dynamic slice and no scalar stores to VMEM: a "scalar" inside
+a kernel is a ``(1, 1)`` vector, a data-dependent column or lane is
+read with a one-hot masked reduction (:func:`_lane_pick`), and a
+data-dependent row or lane is written with a one-hot select
+(:func:`_row_set` / :func:`_lane_set`).  The matvecs ``v_j^T V`` and
+``c_j^T C`` are broadcast-multiply-reduce on the VPU in f32 — exact
+f32 products, no MXU precision mode to pick — and every kernel family
+calls the same per-tile update functions, so the resident, per-step
+and fused chunk kernels compute identical bits.
 
 The same pass kernels serve the candidate-sharded backend: each device
 of ``repro.core.sharded`` runs the identical local update on its
@@ -43,8 +54,182 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dpp_greedy.tiling import VMEM_LIMIT_BYTES
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = float("-inf")
+
+
+# Mosaic parameters shared by every dpp_greedy pallas_call: the
+# scoped-VMEM limit the TilePolicy budget was checked against.
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+# ---------------------------------------------------------------------------
+# In-kernel vector helpers (Mosaic-lowerable forms of gather/scatter)
+# ---------------------------------------------------------------------------
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _index(idx):
+    """A ``(1, 1)`` index vector as a scalar (a full reduction, whose
+    result Mosaic can broadcast into any one-hot compare; ints and
+    scalars pass through)."""
+    return jnp.max(idx) if getattr(idx, "ndim", 0) == 2 else idx
+
+
+def _flag(b):
+    """A ``(1, 1)`` bool as a scalar bool, for selects over 2-D blocks."""
+    return jnp.max(b.astype(jnp.int32)) > 0
+
+
+def _lane_pick(x, idx):
+    """Column ``idx`` of ``x (R, n)`` as ``(R, 1)``: a one-hot masked
+    lane-sum, exact (every other term is zero).  ``idx`` may be a static
+    int, a traced scalar or a ``(1, 1)`` vector; an index outside
+    ``[0, n)`` picks zeros."""
+    hit = _iota(x.shape, 1) == _index(idx)
+    return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=1, keepdims=True)
+
+
+def _row_pick(x, idx):
+    """Row ``idx`` of ``x (R, n)`` as ``(1, n)`` (sublane one-hot sum)."""
+    hit = _iota(x.shape, 0) == _index(idx)
+    return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=0, keepdims=True)
+
+
+def _cell(ref):
+    """A ``(1, 1)`` cell read through a lane reduction: a raw ``(1, 1)``
+    load keeps a memory layout Mosaic cannot broadcast over both
+    sublanes and lanes, a reduction result it can."""
+    return _lane_pick(ref[...], 0)
+
+
+def _lane_set(x, idx, v):
+    """``x`` with column ``idx`` replaced by ``v`` (broadcast)."""
+    return jnp.where(_iota(x.shape, 1) == _index(idx), v, x)
+
+
+def _row_set(x, idx, v):
+    """``x`` with row ``idx`` replaced by ``v`` (broadcast)."""
+    return jnp.where(_iota(x.shape, 0) == _index(idx), v, x)
+
+
+def _lane_row(vals, n):
+    """Pack ``(1, 1)`` values into one ``(1, n)`` row, value ``i`` at
+    lane ``i`` (lanes past ``len(vals)`` are zero)."""
+    dtype = jnp.result_type(*vals)
+    row = jnp.zeros((1, n), dtype)
+    for i, v in enumerate(vals):
+        row = _lane_set(row, i, jnp.asarray(v, dtype))
+    return row
+
+
+def _argmax_first(d2):
+    """``(max (1, 1), argmax (1, 1) int32)`` of ``d2 (1, n)`` with
+    ``jnp.argmax``'s first-occurrence tie-breaking (an all ``-inf`` row
+    gives index 0)."""
+    mx = jnp.max(d2, axis=1, keepdims=True)
+    lanes = _iota(d2.shape, 1)
+    am = jnp.min(
+        jnp.where(d2 == mx, lanes, d2.shape[1]), axis=1, keepdims=True
+    )
+    return mx, am
+
+
+# ---------------------------------------------------------------------------
+# Per-tile update math (shared by every kernel family)
+# ---------------------------------------------------------------------------
+
+
+def _tile_update_full(V, C, d2, vj, cj, dj, stopped, j, base, i, tile_m):
+    """The exact-step math for one (D, TM) tile, on plain values.
+
+    ``vj (D, 1)`` / ``cj (R, 1)`` are the winner's columns; ``dj``,
+    ``stopped``, ``j`` and ``base`` are ``(1, 1)`` (or scalars).  Shared
+    by the resident, per-step and fused chunk kernels so all three run
+    the identical op sequence.  Returns ``(e, d2o)``.
+    """
+    lj = jnp.sum(vj * V, axis=0, keepdims=True)
+    dots = jnp.sum(cj * C, axis=0, keepdims=True)
+    e = (lj - dots) / dj
+    e = jnp.where(stopped, jnp.zeros_like(e), e)
+    gid = _iota((1, tile_m), 1) + i * tile_m + _index(base)
+    d2_next = jnp.where(gid == _index(j), NEG_INF, d2 - e * e)
+    d2o = jnp.where(stopped, d2, d2_next)
+    return e, d2o
+
+
+def _tile_update_windowed(
+    V, C, d2, vj, cj_post, djp, stopped, full, coss, sins, j, base, pos,
+    i, w, tile_m,
+):
+    """The windowed-step math (evict + append fused) for one tile, on
+    plain values.  ``coss``/``sins`` are length-(w-1) sequences of
+    ``(1, 1)`` Givens coefficients, ``cj_post (w, 1)`` the winner's
+    post-eviction column.  Returns ``(C_out, d2o, e)`` with ``C_out``
+    already holding the stopped-passthrough."""
+    # ---- evict the oldest pick: first-row Cholesky downdate; the
+    # rotation residue u repairs d2 (see repro.core.windowed)
+    zero = jnp.zeros((1, tile_m), jnp.float32)
+    u = jnp.where(full, C[0:1, :], zero)
+    Cpost = jnp.zeros((w, tile_m), jnp.float32)
+    for r in range(w - 1):
+        row = jnp.where(full, C[r + 1 : r + 2, :], C[r : r + 1, :])
+        Cpost = _row_set(Cpost, r, coss[r] * row + sins[r] * u)
+        u = coss[r] * u - sins[r] * row
+    Cpost = _row_set(Cpost, w - 1, jnp.where(full, zero, C[w - 1 : w, :]))
+    d2e = jnp.where(full, d2 + u * u, d2)
+
+    # ---- append j against the post-eviction window (eqs. 16-18)
+    lj = jnp.sum(vj * V, axis=0, keepdims=True)
+    dots = jnp.sum(cj_post * Cpost, axis=0, keepdims=True)
+    e = (lj - dots) / djp
+    Cnew = _row_set(Cpost, pos, e)
+    C_out = jnp.where(stopped, C, Cnew)
+
+    gid = _iota((1, tile_m), 1) + i * tile_m + _index(base)
+    d2_next = jnp.where(gid == _index(j), NEG_INF, d2e - e * e)
+    d2o = jnp.where(stopped, d2, d2_next)
+    return C_out, d2o, e
+
+
+def _evict_coeffs_tile(Cw, cj, dj2, full, w):
+    """In-kernel form of :func:`eviction_coeffs` on one problem.
+
+    ``Cw (w, w)`` the window factor, ``cj (w, 1)`` the winner's
+    pre-eviction column, ``dj2``/``full`` ``(1, 1)``.  Returns
+    ``(coss, sins, cj_post (w, 1), d2j (1, 1))`` with ``coss``/``sins``
+    lists of ``(1, 1)`` values — the identical recurrence, element for
+    element."""
+    tiny = 1e-30
+    u_w = jnp.where(full, _row_pick(Cw, 0), jnp.zeros((1, w), jnp.float32))
+    u_c = jnp.where(full, _row_pick(cj, 0), 0.0)
+    coss, sins = [], []
+    cpost = jnp.zeros((w, 1), jnp.float32)
+    for r in range(w - 1):
+        row_w = jnp.where(full, _row_pick(Cw, r + 1), _row_pick(Cw, r))
+        row_c = jnp.where(full, _row_pick(cj, r + 1), _row_pick(cj, r))
+        a = _lane_pick(row_w, r + 1)
+        b = _lane_pick(u_w, r + 1)
+        rho = jnp.maximum(jnp.sqrt(a * a + b * b), tiny)
+        cos = jnp.where(full, a / rho, 1.0)
+        sin = jnp.where(full, b / rho, 0.0)
+        coss.append(cos)
+        sins.append(sin)
+        cpost = _row_set(cpost, r, cos * row_c + sin * u_c)
+        u_c = cos * u_c - sin * row_c
+        u_w = cos * u_w - sin * row_w
+    cpost = _row_set(
+        cpost, w - 1, jnp.where(full, 0.0, _row_pick(cj, w - 1))
+    )
+    d2j = jnp.where(full, dj2 + u_c * u_c, dj2)
+    return coss, sins, cpost, d2j
 
 
 # ---------------------------------------------------------------------------
@@ -62,68 +247,11 @@ def _reduce_running_argmax(i, d2, mx_ref, am_ref, tile_m):
         mx_ref[...] = jnp.full(mx_ref.shape, NEG_INF, jnp.float32)
         am_ref[...] = jnp.zeros(am_ref.shape, jnp.int32)
 
-    lm = jnp.max(d2[0])
-    la = jnp.argmax(d2[0]).astype(jnp.int32) + i * tile_m
-    better = lm > mx_ref[0, 0]
-    mx_ref[0, 0] = jnp.where(better, lm, mx_ref[0, 0])
-    am_ref[0, 0] = jnp.where(better, la, am_ref[0, 0])
-
-
-def _tile_update_full(V, C, d2, vj, cj, dj, stopped, j, base, i, tile_m):
-    """The exact-step math for one (D, TM) tile, on plain values.
-
-    Shared by the per-step kernel (:func:`_pass_full`, values from
-    operands) and the fused multi-step chunk kernel
-    (:func:`_chunk_pass_full`, values from VMEM-resident cells) so the
-    two paths run the identical op sequence.  ``vj (1, D)`` /
-    ``cj (1, R)`` are the winner's columns.  Returns ``(e, d2o)``.
-    """
-    lj = jnp.dot(vj, V, preferred_element_type=jnp.float32)
-    dots = jnp.dot(cj, C, preferred_element_type=jnp.float32)
-    e = (lj - dots) / dj
-    e = jnp.where(stopped, jnp.zeros_like(e), e)
-    gid = jax.lax.broadcasted_iota(jnp.int32, (1, tile_m), 1) + i * tile_m + base
-    d2_next = jnp.where(gid == j, NEG_INF, d2 - e * e)
-    d2o = jnp.where(stopped, d2, d2_next)
-    return e, d2o
-
-
-def _tile_update_windowed(
-    V, C, d2, vj, cj_post, djp, stopped, full, coss, sins, j, base, pos,
-    i, w, tile_m,
-):
-    """The windowed-step math (evict + append fused) for one tile, on
-    plain values — shared by :func:`_pass_windowed` and
-    :func:`_chunk_pass_windowed`.  ``coss``/``sins`` are length-(w-1)
-    sequences of scalar Givens coefficients.  Returns
-    ``(C_out, d2o, e)`` with ``C_out`` already holding the
-    stopped-passthrough."""
-    # ---- evict the oldest pick: first-row Cholesky downdate; the
-    # rotation residue u repairs d2 (see repro.core.windowed)
-    u = jnp.where(full, C[0:1, :], jnp.zeros((1, tile_m), jnp.float32))
-    rows = []
-    for r in range(w - 1):
-        cos = coss[r]
-        sin = sins[r]
-        row = jnp.where(full, C[r + 1 : r + 2, :], C[r : r + 1, :])
-        rows.append(cos * row + sin * u)
-        u = cos * u - sin * row
-    last = jnp.where(full, jnp.zeros((1, tile_m), jnp.float32), C[w - 1 : w, :])
-    Cpost = jnp.concatenate(rows + [last], axis=0) if w > 1 else last
-    d2e = jnp.where(full, d2 + u * u, d2)
-
-    # ---- append j against the post-eviction window (eqs. 16-18)
-    lj = jnp.dot(vj, V, preferred_element_type=jnp.float32)
-    dots = jnp.dot(cj_post, Cpost, preferred_element_type=jnp.float32)
-    e = (lj - dots) / djp
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
-    Cnew = jnp.where(ridx == pos, e, Cpost)
-    C_out = jnp.where(stopped, C, Cnew)
-
-    gid = jax.lax.broadcasted_iota(jnp.int32, (1, tile_m), 1) + i * tile_m + base
-    d2_next = jnp.where(gid == j, NEG_INF, d2e - e * e)
-    d2o = jnp.where(stopped, d2, d2_next)
-    return C_out, d2o, e
+    lm, la = _argmax_first(d2)
+    cur = mx_ref[...]
+    better = lm > cur
+    mx_ref[...] = jnp.where(better, lm, cur)
+    am_ref[...] = jnp.where(better, la + i * tile_m, am_ref[...])
 
 
 def _pass_full(
@@ -135,7 +263,7 @@ def _pass_full(
     v_ref:  (D, TM) f32 — tile of the scaled features, L = V^T V
     c_ref:  (R, TM) f32 — tile of the Cholesky rows (rows >= t are 0)
     d2_ref: (1, TM) f32 — tile of the marginal gains
-    vj_ref: (1, D), cj_ref: (1, R) — the winner's columns (replicated)
+    vj_ref: (D, 1), cj_ref: (R, 1) — the winner's columns (replicated)
     flt_ref:(1, 2) f32 — [d_j, stopped]
     int_ref:(1, 2) i32 — [j (global id), base (global id of column 0)]
     e_ref:  (1, TM) out — the appended Cholesky row (eqs. 16-18)
@@ -143,14 +271,11 @@ def _pass_full(
     mx/am:  (1, 1) out — running (d2_max, argmax), revisited across tiles
     """
     i = pl.program_id(1)
-    dj = flt_ref[0, 0]
-    stopped = flt_ref[0, 1] > 0
-    j = int_ref[0, 0]
-    base = int_ref[0, 1]
-
+    flt, ints = flt_ref[...], int_ref[...]
     e, d2o = _tile_update_full(
         v_ref[...], c_ref[...], d2_ref[...], vj_ref[...], cj_ref[...],
-        dj, stopped, j, base, i, tile_m,
+        _lane_pick(flt, 0), _lane_pick(flt, 1) > 0,
+        _lane_pick(ints, 0), _lane_pick(ints, 1), i, tile_m,
     )
     e_ref[...] = e
     d2o_ref[...] = d2o
@@ -165,7 +290,7 @@ def _pass_windowed(
     rotations with precomputed coefficients) fused with the append.
 
     c_ref:  (w, TM) — tile of the window Cholesky ring (window order)
-    cj_ref: (1, w)  — the winner's POST-eviction column (replicated)
+    cj_ref: (w, 1)  — the winner's POST-eviction column (replicated)
     flt_ref:(1, 3 + 2(w-1)) f32 — [d_j', stopped, full,
             cos_0..cos_{w-2}, sin_0..sin_{w-2}]; identity rotations
             (cos=1, sin=0) are passed when the window is not yet full
@@ -173,18 +298,14 @@ def _pass_windowed(
     co_ref: (w, TM) out — post-eviction, post-append ring tile
     """
     i = pl.program_id(1)
-    djp = flt_ref[0, 0]
-    stopped = flt_ref[0, 1] > 0
-    full = flt_ref[0, 2] > 0
-    j = int_ref[0, 0]
-    base = int_ref[0, 1]
-    pos = int_ref[0, 2]
-    coss = [flt_ref[0, 3 + r] for r in range(w - 1)]
-    sins = [flt_ref[0, 3 + (w - 1) + r] for r in range(w - 1)]
-
+    flt, ints = flt_ref[...], int_ref[...]
+    coss = [_lane_pick(flt, 3 + r) for r in range(w - 1)]
+    sins = [_lane_pick(flt, 3 + (w - 1) + r) for r in range(w - 1)]
     C_out, d2o, _ = _tile_update_windowed(
         v_ref[...], c_ref[...], d2_ref[...], vj_ref[...], cj_ref[...],
-        djp, stopped, full, coss, sins, j, base, pos, i, w, tile_m,
+        _lane_pick(flt, 0), _lane_pick(flt, 1) > 0, _lane_pick(flt, 2) > 0,
+        coss, sins, _lane_pick(ints, 0), _lane_pick(ints, 1),
+        _lane_pick(ints, 2), i, w, tile_m,
     )
     co_ref[...] = C_out
     d2o_ref[...] = d2o
@@ -200,8 +321,8 @@ def _tile_spec(rows, tile_m):
     return pl.BlockSpec((None, rows, tile_m), lambda b, i: (b, 0, i))
 
 
-def _small_spec(cols):
-    return pl.BlockSpec((None, 1, cols), lambda b, i: (b, 0, 0))
+def _small_spec(rows, cols):
+    return pl.BlockSpec((None, rows, cols), lambda b, i: (b, 0, 0))
 
 
 def _sweep(kernel, row_out, V, C, d2, vj, cj, flt, ints, tile_m, interpret):
@@ -218,16 +339,16 @@ def _sweep(kernel, row_out, V, C, d2, vj, cj, flt, ints, tile_m, interpret):
             _tile_spec(D, tile_m),
             _tile_spec(R, tile_m),
             _tile_spec(1, tile_m),
-            _small_spec(D),
-            _small_spec(R),
-            _small_spec(flt.shape[-1]),
-            _small_spec(ints.shape[-1]),
+            _small_spec(D, 1),
+            _small_spec(R, 1),
+            _small_spec(1, flt.shape[-1]),
+            _small_spec(1, ints.shape[-1]),
         ],
         out_specs=[
             _tile_spec(row_out, tile_m),
             _tile_spec(1, tile_m),
-            _small_spec(1),
-            _small_spec(1),
+            _small_spec(1, 1),
+            _small_spec(1, 1),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, row_out, Mp), jnp.float32),
@@ -235,16 +356,18 @@ def _sweep(kernel, row_out, V, C, d2, vj, cj, flt, ints, tile_m, interpret):
             jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
-        interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        interpret=resolve_interpret(interpret),
     )(V, C, d2, vj, cj, flt, ints)
 
 
-def _full_sweep(V, C, d2, vj, cj, flt, ints, *, tile_m, interpret):
+def _full_sweep(V, C, d2, vj, cj, flt, ints, *, tile_m, interpret=None):
     kernel = functools.partial(_pass_full, tile_m=tile_m)
     return _sweep(kernel, 1, V, C, d2, vj, cj, flt, ints, tile_m, interpret)
 
 
-def _windowed_sweep(V, C, d2, vj, cj, flt, ints, *, w, tile_m, interpret):
+def _windowed_sweep(V, C, d2, vj, cj, flt, ints, *, w, tile_m,
+                    interpret=None):
     kernel = functools.partial(_pass_windowed, w=w, tile_m=tile_m)
     return _sweep(kernel, w, V, C, d2, vj, cj, flt, ints, tile_m, interpret)
 
@@ -271,6 +394,7 @@ def eviction_coeffs(Cw, cj, dj2, full, w: int):
     any column reproduces bit-for-bit what the in-place rotation sweep
     of ``repro.core.windowed`` / ``core.sharded`` computes, because the
     sweep only ever reads not-yet-rotated rows (row r+1 at iteration r).
+    :func:`_evict_coeffs_tile` is the same recurrence inside a kernel.
     """
     tiny = 1e-30
     fullb = full[..., None]
@@ -305,7 +429,8 @@ def eviction_coeffs(Cw, cj, dj2, full, w: int):
 
 
 def tiled_update_exact(
-    Vl, C, d2, vj, cj, dj, stopped, j, base, *, tile_m: int, interpret: bool = True
+    Vl, C, d2, vj, cj, dj, stopped, j, base, *, tile_m: int,
+    interpret=None,
 ):
     """One exact greedy step's local update on a column shard.
 
@@ -318,15 +443,15 @@ def tiled_update_exact(
     flt = jnp.stack([dj, stopped.astype(jnp.float32)])[None, None, :]
     ints = jnp.stack([j, base]).astype(jnp.int32)[None, None, :]
     e, d2o, _, _ = _full_sweep(
-        Vl[None], C[None], d2[None, None, :], vj[None, None, :],
-        cj[None, None, :], flt, ints, tile_m=tile_m, interpret=interpret,
+        Vl[None], C[None], d2[None, None, :], vj[None, :, None],
+        cj[None, :, None], flt, ints, tile_m=tile_m, interpret=interpret,
     )
     return e[0, 0], d2o[0, 0]
 
 
 def tiled_update_windowed(
     Vl, C, d2, vj, cj_post, djp, stopped, full, cos, sin, j, base, pos,
-    *, w: int, tile_m: int, interpret: bool = True,
+    *, w: int, tile_m: int, interpret=None,
 ):
     """One windowed greedy step's local update (evict + append fused) on
     a column shard; coefficients from :func:`eviction_coeffs`.
@@ -340,8 +465,8 @@ def tiled_update_windowed(
     )[None, None, :]
     ints = jnp.stack([j, base, pos]).astype(jnp.int32)[None, None, :]
     Co, d2o, _, _ = _windowed_sweep(
-        Vl[None], C[None], d2[None, None, :], vj[None, None, :],
-        cj_post[None, None, :], flt, ints, w=w, tile_m=tile_m,
+        Vl[None], C[None], d2[None, None, :], vj[None, :, None],
+        cj_post[None, :, None], flt, ints, w=w, tile_m=tile_m,
         interpret=interpret,
     )
     return Co[0], d2o[0, 0]
@@ -357,15 +482,15 @@ def tiled_update_windowed(
 # round-trip — once per chunk instead of once per step.  Everything the
 # next step needs from the previous one (the running argmax, the
 # winner's V / Cholesky columns and, windowed, the (w, w) window factor
-# and ring ids) is carried in constant-index (1, ·) cells that stay
+# and ring ids) is carried in constant-index cells that stay
 # VMEM-resident across the whole grid: the per-step JAX-level winner
 # gather / row write-back of the per-step path disappears entirely.
 #
-# Caveat (mirrors the ROADMAP's compiled-mode item): CI exercises
-# interpret mode, where revisited output blocks read back the bits the
-# previous sweep wrote.  A compiled TPU lowering must preserve that
-# read-back (non-consecutive revisits re-fetch from HBM) — on-hardware
-# validation of exactly this contract is tracked in the ROADMAP.
+# Caveat (ROADMAP speed item 4): interpret mode keeps every output block
+# live for the whole grid, so revisited blocks read back the bits the
+# previous sweep wrote.  Compiled Mosaic guarantees that only for
+# consecutive revisits, i.e. a single whole-M tile; the multi-tile
+# schedule is fenced by _require_interpret_for_multitile.
 # ---------------------------------------------------------------------------
 
 
@@ -373,26 +498,34 @@ def _reduce_argmax_and_cols(i, d2, V, C, mx_ref, am_ref, wv_ref, wc_ref,
                             tile_m):
     """The running (max, argmax) fold of :func:`_reduce_running_argmax`
     extended to also capture the running winner's columns — its
-    ``V[:, j]`` as a (1, D) row in ``wv_ref`` and its post-update
-    Cholesky column as a (1, R) row in ``wc_ref`` — so the next sweep
+    ``V[:, j]`` as a (D, 1) column in ``wv_ref`` and its post-update
+    Cholesky column as a (R, 1) column in ``wc_ref`` — so the next sweep
     starts with the winner's columns already VMEM-resident."""
 
     @pl.when(i == 0)
     def _():
         mx_ref[...] = jnp.full(mx_ref.shape, NEG_INF, jnp.float32)
         am_ref[...] = jnp.zeros(am_ref.shape, jnp.int32)
+        wv_ref[...] = jnp.zeros(wv_ref.shape, jnp.float32)
+        wc_ref[...] = jnp.zeros(wc_ref.shape, jnp.float32)
 
-    lm = jnp.max(d2[0])
-    jl = jnp.argmax(d2[0]).astype(jnp.int32)
-    la = jl + i * tile_m
-    better = lm > mx_ref[0, 0]
-    mx_ref[0, 0] = jnp.where(better, lm, mx_ref[0, 0])
-    am_ref[0, 0] = jnp.where(better, la, am_ref[0, 0])
-    D, R = V.shape[0], C.shape[0]
-    vcol = jax.lax.dynamic_slice(V, (0, jl), (D, 1)).reshape(1, D)
-    ccol = jax.lax.dynamic_slice(C, (0, jl), (R, 1)).reshape(1, R)
-    wv_ref[...] = jnp.where(better, vcol, wv_ref[...])
-    wc_ref[...] = jnp.where(better, ccol, wc_ref[...])
+    lm, jl = _argmax_first(d2)
+    cur = mx_ref[...]
+    better = lm > cur
+    mx_ref[...] = jnp.where(better, lm, cur)
+    am_ref[...] = jnp.where(better, jl + i * tile_m, am_ref[...])
+    wv_ref[...] = jnp.where(better, _lane_pick(V, jl), wv_ref[...])
+    wc_ref[...] = jnp.where(better, _lane_pick(C, jl), wc_ref[...])
+
+
+def _emit(sel_ref, dh_ref, s, stopped, j, dj):
+    """Write step ``s``'s selection into the (1, chunk) output cells."""
+    sel_ref[...] = _lane_set(
+        sel_ref[...], s, jnp.where(stopped, -1, j).astype(jnp.int32)
+    )
+    dh_ref[...] = _lane_set(
+        dh_ref[...], s, jnp.where(stopped, 0.0, dj).astype(jnp.float32)
+    )
 
 
 def _chunk_pass_full(
@@ -407,7 +540,7 @@ def _chunk_pass_full(
     Inputs: V tile (D, TM); C/d2 state tiles (read at sweep 0 only —
     later sweeps read the revisited output blocks); f0 (1, 2) f32
     [dj2_0, stopped_0], i0 (1, 2) i32 [j_0, t0] and the winner's
-    columns vj0 (1, D) / cj0 (1, R), all computed at the JAX level once
+    columns vj0 (D, 1) / cj0 (R, 1), all computed at the JAX level once
     per chunk from the resumable state.
 
     Cells: stepf (1, 2) [d_j, stopped] and stepi (1, 2) [j, t0] hold
@@ -422,41 +555,38 @@ def _chunk_pass_full(
 
     @pl.when(i == 0)
     def _setup():
-        dj2 = jnp.where(first, f0_ref[0, 0], mxn_ref[0, 0])
-        prev_stop = jnp.where(first, f0_ref[0, 1] > 0, stepf_ref[0, 1] > 0)
-        j = jnp.where(first, i0_ref[0, 0], amn_ref[0, 0])
-        t0 = i0_ref[0, 1]
+        f0, i0 = f0_ref[...], i0_ref[...]
+        dj2 = jnp.where(first, _lane_pick(f0, 0), _cell(mxn_ref))
+        prev_stop = jnp.where(
+            first, _lane_pick(f0, 1), _lane_pick(stepf_ref[...], 1)
+        ) > 0
+        j = jnp.where(first, _lane_pick(i0, 0), _cell(amn_ref))
         stopped = jnp.logical_or(prev_stop, dj2 <= eps2)
         dj = jnp.sqrt(jnp.maximum(dj2, eps2))
-        stepf_ref[...] = jnp.stack([dj, stopped.astype(jnp.float32)])[None]
-        stepi_ref[...] = jnp.stack([j, t0]).astype(jnp.int32)[None]
+        stepf_ref[...] = _lane_row([dj, stopped.astype(jnp.float32)], 2)
+        stepi_ref[...] = _lane_row([j, _lane_pick(i0, 1)], 2)
         wvc_ref[...] = jnp.where(first, vj0_ref[...], wvn_ref[...])
         wcc_ref[...] = jnp.where(first, cj0_ref[...], wcn_ref[...])
-        sel_val = jnp.where(stopped, -1, j).astype(jnp.int32)
-        pl.store(sel_ref, (pl.dslice(0, 1), pl.dslice(s, 1)),
-                 sel_val[None, None])
-        d_val = jnp.where(stopped, 0.0, dj).astype(jnp.float32)
-        pl.store(dh_ref, (pl.dslice(0, 1), pl.dslice(s, 1)),
-                 d_val[None, None])
+        _emit(sel_ref, dh_ref, s, stopped, j, dj)
 
-    dj = stepf_ref[0, 0]
-    stopped = stepf_ref[0, 1] > 0
-    j = stepi_ref[0, 0]
-    t = stepi_ref[0, 1] + s
+    stepf, stepi = stepf_ref[...], stepi_ref[...]
+    dj = _lane_pick(stepf, 0)
+    stopped = _lane_pick(stepf, 1) > 0
+    j = _lane_pick(stepi, 0)
+    t = _lane_pick(stepi, 1) + s
     C = jnp.where(first, cin_ref[...], cout_ref[...])
     d2 = jnp.where(first, d2in_ref[...], d2out_ref[...])
+    V = v_ref[...]
     e, d2o = _tile_update_full(
-        v_ref[...], C, d2, wvc_ref[...], wcc_ref[...],
-        dj, stopped, j, 0, i, tile_m,
+        V, C, d2, wvc_ref[...], wcc_ref[...], dj, stopped, j, 0, i, tile_m,
     )
     # append the new Cholesky row in place (row t; zeros once stopped,
     # exactly as the per-step driver's dynamic_update_slice writes)
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (C.shape[0], 1), 0)
-    Cnew = jnp.where(ridx == t, e, C)
+    Cnew = _row_set(C, t, e)
     cout_ref[...] = Cnew
     d2out_ref[...] = d2o
     _reduce_argmax_and_cols(
-        i, d2o, v_ref[...], Cnew, mxn_ref, amn_ref, wvn_ref, wcn_ref, tile_m
+        i, d2o, V, Cnew, mxn_ref, amn_ref, wvn_ref, wcn_ref, tile_m
     )
 
 
@@ -476,8 +606,8 @@ def _chunk_pass_windowed(
     to their columns, its appended row filled in by whichever tile owns
     each window member) — and ``wring (1, w)`` — the ring ids.  Tile 0
     derives the step's eviction rotations from these cells with
-    :func:`eviction_coeffs` (the identical recurrence the per-step JAX
-    driver uses), so no JAX-level gather happens inside a chunk.
+    :func:`_evict_coeffs_tile` (the identical recurrence the per-step
+    JAX driver uses), so no JAX-level gather happens inside a chunk.
     """
     s = pl.program_id(1)
     i = pl.program_id(2)
@@ -486,83 +616,79 @@ def _chunk_pass_windowed(
 
     @pl.when(i == 0)
     def _setup():
-        dj2 = jnp.where(first, f0_ref[0, 0], mxn_ref[0, 0])
-        prev_stop = jnp.where(first, f0_ref[0, 1] > 0, stepf_ref[0, 1] > 0)
-        j = jnp.where(first, i0_ref[0, 0], amn_ref[0, 0])
-        t0 = i0_ref[0, 1]
-        t = t0 + s
+        f0, i0 = f0_ref[...], i0_ref[...]
+        dj2 = jnp.where(first, _lane_pick(f0, 0), _cell(mxn_ref))
+        prev_stop = jnp.where(
+            first, _lane_pick(f0, 1), _lane_pick(stepf_ref[...], 1)
+        ) > 0
+        j = jnp.where(first, _lane_pick(i0, 0), _cell(amn_ref))
+        t0 = _lane_pick(i0, 1)
+        t = _lane_pick(t0 + s, 0)  # re-reduced: see _cell
         stopped = jnp.logical_or(prev_stop, dj2 <= eps2)
         dj = jnp.sqrt(jnp.maximum(dj2, eps2))
         full = jnp.logical_and(t >= w, jnp.logical_not(stopped))
-        cj_pre = jnp.where(first, cj0_ref[...], wcn_ref[...])[0]  # (w,)
+        cj_pre = jnp.where(first, cj0_ref[...], wcn_ref[...])  # (w, 1)
         Cw = jnp.where(first, cw0_ref[...], cwc_ref[...])  # (w, w)
         W = jnp.where(first, win0_ref[...], wring_ref[...])  # (1, w) i32
-        cos_arr, sin_arr, cj_post, d2j = eviction_coeffs(
+        coss, sins, cj_post, d2j = _evict_coeffs_tile(
             Cw, cj_pre, dj2, full, w
         )
         djp = jnp.sqrt(jnp.maximum(d2j, eps2))
         pos = jnp.minimum(t, w - 1)
-        stepf_ref[...] = jnp.concatenate(
-            [
-                jnp.stack([djp, stopped.astype(jnp.float32),
-                           full.astype(jnp.float32)]),
-                cos_arr, sin_arr,
-            ]
-        )[None]
-        stepi_ref[...] = jnp.stack([j, pos, t0]).astype(jnp.int32)[None]
+        stepf_ref[...] = _lane_row(
+            [djp, stopped.astype(jnp.float32), full.astype(jnp.float32)]
+            + coss + sins,
+            stepf_ref.shape[-1],
+        )
+        stepi_ref[...] = _lane_row([j, pos, t0], 3)
         wvc_ref[...] = jnp.where(first, vj0_ref[...], wvn_ref[...])
-        wcp_ref[...] = cj_post[None]
+        wcp_ref[...] = cj_post
 
         # maintain the (w, w) window factor through evict + append:
         # rotate its rows with the step's coefficients (the same
         # recurrence the tiles apply to their columns) ...
-        u_w = jnp.where(full, Cw[0, :], jnp.zeros((w,), jnp.float32))
-        rows = []
+        full_s, stop_s, pos_s = _flag(full), _flag(stopped), _index(pos)
+        zero_w = jnp.zeros((1, w), jnp.float32)
+        u_w = jnp.where(full_s, _row_pick(Cw, 0), zero_w)
+        rotated = jnp.zeros((w, w), jnp.float32)
         for r in range(w - 1):
-            row = jnp.where(full, Cw[r + 1, :], Cw[r, :])
-            rows.append(cos_arr[r] * row + sin_arr[r] * u_w)
-            u_w = cos_arr[r] * u_w - sin_arr[r] * row
-        last = jnp.where(full, jnp.zeros((w,), jnp.float32), Cw[w - 1, :])
-        rotated = jnp.stack(rows + [last], axis=0)  # (w, w)
+            row = jnp.where(full_s, _row_pick(Cw, r + 1), _row_pick(Cw, r))
+            rotated = _row_set(rotated, r, coss[r] * row + sins[r] * u_w)
+            u_w = coss[r] * u_w - sins[r] * row
+        rotated = _row_set(
+            rotated, w - 1, jnp.where(full_s, zero_w, _row_pick(Cw, w - 1))
+        )
         # ... shift out the evicted member's column / enter the winner's
-        colidx = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
-        if w > 1:
-            shifted = jnp.concatenate(
-                [rotated[:, 1:], cj_post[:, None]], axis=1
-            )
-        else:
-            shifted = cj_post[:, None]
-        not_full = jnp.where(colidx == pos, cj_post[:, None], rotated)
-        Cw_new = jnp.where(full, shifted, not_full)
+        shifted = _lane_set(rotated, w - 1, cj_post)
+        W_shift = jnp.full((1, w), -1, jnp.int32)
+        for c in range(w - 1):
+            shifted = _lane_set(shifted, c, _lane_pick(rotated, c + 1))
+            W_shift = _lane_set(W_shift, c, _lane_pick(W, c + 1))
+        not_full = _lane_set(rotated, pos_s, cj_post)
+        Cw_new = jnp.where(full_s, shifted, not_full)
         # row pos is the appended e-row: zero it here, the owning tiles
         # fill in e[win_r] for their members during the sweep
-        ridxw = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
-        Cw_new = jnp.where(ridxw == pos, 0.0, Cw_new)
-        cwc_ref[...] = jnp.where(stopped, Cw, Cw_new)
+        Cw_new = _row_set(Cw_new, pos_s, 0.0)
+        cwc_ref[...] = jnp.where(stop_s, Cw, Cw_new)
 
-        W_shift = jnp.roll(W, -1, axis=1)
-        W1 = jnp.where(full, jnp.where(colidx == w - 1, -1, W_shift), W)
-        W_new = jnp.where(stopped, W, jnp.where(colidx == pos, j, W1))
-        wring_ref[...] = W_new
+        W1 = jnp.where(full_s, W_shift, W)
+        wring_ref[...] = jnp.where(stop_s, W, _lane_set(W1, pos_s, j))
+        _emit(sel_ref, dh_ref, s, stopped, j, dj)
 
-        sel_val = jnp.where(stopped, -1, j).astype(jnp.int32)
-        pl.store(sel_ref, (pl.dslice(0, 1), pl.dslice(s, 1)),
-                 sel_val[None, None])
-        d_val = jnp.where(stopped, 0.0, dj).astype(jnp.float32)
-        pl.store(dh_ref, (pl.dslice(0, 1), pl.dslice(s, 1)),
-                 d_val[None, None])
-
-    djp = stepf_ref[0, 0]
-    stopped = stepf_ref[0, 1] > 0
-    full = stepf_ref[0, 2] > 0
-    coss = [stepf_ref[0, 3 + r] for r in range(w - 1)]
-    sins = [stepf_ref[0, 3 + (w - 1) + r] for r in range(w - 1)]
-    j = stepi_ref[0, 0]
-    pos = stepi_ref[0, 1]
+    stepf = stepf_ref[...]
+    djp = _lane_pick(stepf, 0)
+    stopped = _lane_pick(stepf, 1) > 0
+    full = _lane_pick(stepf, 2) > 0
+    coss = [_lane_pick(stepf, 3 + r) for r in range(w - 1)]
+    sins = [_lane_pick(stepf, 3 + (w - 1) + r) for r in range(w - 1)]
+    stepi = stepi_ref[...]
+    j = _lane_pick(stepi, 0)
+    pos = _lane_pick(stepi, 1)
     C = jnp.where(first, cin_ref[...], cout_ref[...])
     d2 = jnp.where(first, d2in_ref[...], d2out_ref[...])
+    V = v_ref[...]
     C_out, d2o, e = _tile_update_windowed(
-        v_ref[...], C, d2, wvc_ref[...], wcp_ref[...], djp, stopped, full,
+        V, C, d2, wvc_ref[...], wcp_ref[...], djp, stopped, full,
         coss, sins, j, 0, pos, i, w, tile_m,
     )
     cout_ref[...] = C_out
@@ -571,23 +697,21 @@ def _chunk_pass_windowed(
     # fill the appended window-factor row: e[win_r] for the members this
     # tile owns (each global id lives in exactly one tile)
     W_new = wring_ref[...]
+    Cw = cwc_ref[...]
+    at_pos = _iota((w, w), 0) == _index(pos)
     for r in range(w):
-        idx = W_new[0, r]
+        idx = _lane_pick(W_new, r)
         loc = idx - i * tile_m
         owned = (idx >= 0) & (loc >= 0) & (loc < tile_m) & jnp.logical_not(
             stopped
         )
-        val = jax.lax.dynamic_slice(
-            e, (0, jnp.clip(loc, 0, tile_m - 1)), (1, 1)
-        )[0, 0]
-        cur = pl.load(cwc_ref, (pl.dslice(pos, 1), pl.dslice(r, 1)))
-        pl.store(
-            cwc_ref, (pl.dslice(pos, 1), pl.dslice(r, 1)),
-            jnp.where(owned, val, cur[0, 0])[None, None],
-        )
+        cur = _lane_pick(_row_pick(Cw, pos), r)
+        val = jnp.where(owned, _lane_pick(e, loc), cur)
+        Cw = jnp.where(at_pos & (_iota((w, w), 1) == r), val, Cw)
+    cwc_ref[...] = Cw
 
     _reduce_argmax_and_cols(
-        i, d2o, v_ref[...], C_out, mxn_ref, amn_ref, wvn_ref, wcn_ref, tile_m
+        i, d2o, V, C_out, mxn_ref, amn_ref, wvn_ref, wcn_ref, tile_m
     )
 
 
@@ -616,8 +740,8 @@ def _require_interpret_for_multitile(interpret: bool, nt: int) -> None:
             f"fused chunk kernels compile only with a single whole-M tile "
             f"(nt={nt} tiles requested): cross-step state lives in output "
             f"blocks revisited non-consecutively, which compiled Mosaic "
-            f"does not guarantee — use interpret=True, widen tile_m to "
-            f"cover M, or step with the per-step tiled kernels"
+            f"does not guarantee — widen tile_m to cover M, or step with "
+            f"the per-step tiled kernels"
         )
 
 
@@ -628,7 +752,8 @@ def _fused_chunk_call(kernel, *, grid, in_specs, out_specs, out_shape,
     round-trip — per chunk, however many steps the chunk spans."""
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, compiler_params=COMPILER_PARAMS,
+        interpret=interpret,
     )(*ins)
 
 
@@ -657,25 +782,33 @@ def pallas_call_structure(jaxpr, in_loop=False, counts=None):
     return counts
 
 
+def _winner_cols(V, C, d2):
+    """JAX-level winner of ``d2 (B, Mp)``: ``(j, dj2, V[:, j] (B, D, 1),
+    C[:, j] (B, R, 1))``."""
+    j = jnp.argmax(d2, axis=1).astype(jnp.int32)
+    dj2 = jnp.take_along_axis(d2, j[:, None], axis=1)[:, 0]
+    vj = jnp.take_along_axis(V, j[:, None, None], axis=2)
+    cj = jnp.take_along_axis(C, j[:, None, None], axis=2)
+    return j, dj2, vj, cj
+
+
 @functools.partial(
     jax.jit, static_argnames=("chunk", "eps", "tile_m", "interpret")
 )
 def fused_chunk_exact(V, C, d2, t0, stopped, *, chunk: int, eps: float,
-                      tile_m: int, interpret: bool = True):
+                      tile_m: int, interpret=None):
     """Advance ``chunk`` exact greedy steps in one fused pallas_call.
 
     V (B, D, Mp) / C (B, R, Mp) / d2 (B, Mp) / stopped (B,), ``t0`` the
     absolute step of the chunk's first selection.  Returns
     ``(C', d2', stopped', sel (B, chunk), dh (B, chunk))``.
     """
+    interpret = resolve_interpret(interpret)
     B, D, Mp = V.shape
     R = C.shape[1]
     nt = Mp // tile_m
     _require_interpret_for_multitile(interpret, nt)
-    j0 = jnp.argmax(d2, axis=1).astype(jnp.int32)
-    dj20 = jnp.take_along_axis(d2, j0[:, None], axis=1)[:, 0]
-    vj0 = jnp.take_along_axis(V, j0[:, None, None], axis=2)[:, :, 0][:, None, :]
-    cj0 = jnp.take_along_axis(C, j0[:, None, None], axis=2)[:, :, 0][:, None, :]
+    j0, dj20, vj0, cj0 = _winner_cols(V, C, d2)
     f0 = jnp.stack([dj20, stopped.astype(jnp.float32)], axis=1)[:, None, :]
     t0b = jnp.broadcast_to(jnp.asarray(t0, jnp.int32), (B,))
     i0 = jnp.stack([j0, t0b], axis=1)[:, None, :]
@@ -687,15 +820,15 @@ def fused_chunk_exact(V, C, d2, t0, stopped, *, chunk: int, eps: float,
             _ctile_spec(D, tile_m), _ctile_spec(R, tile_m),
             _ctile_spec(1, tile_m),
             _ccell_spec(1, 2), _ccell_spec(1, 2),
-            _ccell_spec(1, D), _ccell_spec(1, R),
+            _ccell_spec(D, 1), _ccell_spec(R, 1),
         ],
         out_specs=[
             _ctile_spec(R, tile_m), _ctile_spec(1, tile_m),
             _ccell_spec(1, chunk), _ccell_spec(1, chunk),
             _ccell_spec(1, 2), _ccell_spec(1, 2),
-            _ccell_spec(1, D), _ccell_spec(1, R),
+            _ccell_spec(D, 1), _ccell_spec(R, 1),
             _ccell_spec(1, 1), _ccell_spec(1, 1),
-            _ccell_spec(1, D), _ccell_spec(1, R),
+            _ccell_spec(D, 1), _ccell_spec(R, 1),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, R, Mp), jnp.float32),
@@ -704,12 +837,12 @@ def fused_chunk_exact(V, C, d2, t0, stopped, *, chunk: int, eps: float,
             jax.ShapeDtypeStruct((B, 1, chunk), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 2), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 2), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1, R), jnp.float32),
+            jax.ShapeDtypeStruct((B, D, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, R, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1, R), jnp.float32),
+            jax.ShapeDtypeStruct((B, D, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, R, 1), jnp.float32),
         ],
         interpret=interpret,
         ins=(V, C, d2[:, None, :], f0, i0, vj0, cj0),
@@ -723,20 +856,17 @@ def fused_chunk_exact(V, C, d2, t0, stopped, *, chunk: int, eps: float,
     jax.jit, static_argnames=("chunk", "eps", "w", "tile_m", "interpret")
 )
 def fused_chunk_windowed(V, C, d2, win, t0, stopped, *, chunk: int,
-                         eps: float, w: int, tile_m: int,
-                         interpret: bool = True):
+                         eps: float, w: int, tile_m: int, interpret=None):
     """Advance ``chunk`` sliding-window greedy steps in one fused
     pallas_call.  ``C (B, w, Mp)`` is the window ring, ``win (B, w)``
     the ring ids (oldest first).  Returns
     ``(C', d2', win', stopped', sel (B, chunk), dh (B, chunk))``.
     """
+    interpret = resolve_interpret(interpret)
     B, D, Mp = V.shape
     nt = Mp // tile_m
     _require_interpret_for_multitile(interpret, nt)
-    j0 = jnp.argmax(d2, axis=1).astype(jnp.int32)
-    dj20 = jnp.take_along_axis(d2, j0[:, None], axis=1)[:, 0]
-    vj0 = jnp.take_along_axis(V, j0[:, None, None], axis=2)[:, :, 0][:, None, :]
-    cj0 = jnp.take_along_axis(C, j0[:, None, None], axis=2)[:, :, 0][:, None, :]
+    j0, dj20, vj0, cj0 = _winner_cols(V, C, d2)
     Cw0 = jnp.take_along_axis(C, jnp.clip(win, 0)[:, None, :], axis=2)
     Cw0 = jnp.where((win >= 0)[:, None, :], Cw0, 0.0)  # (B, w, w)
     win0 = win[:, None, :]
@@ -754,17 +884,17 @@ def fused_chunk_windowed(V, C, d2, win, t0, stopped, *, chunk: int,
             _ctile_spec(D, tile_m), _ctile_spec(w, tile_m),
             _ctile_spec(1, tile_m),
             _ccell_spec(1, 2), _ccell_spec(1, 2),
-            _ccell_spec(1, D), _ccell_spec(1, w),
+            _ccell_spec(D, 1), _ccell_spec(w, 1),
             _ccell_spec(w, w), _ccell_spec(1, w),
         ],
         out_specs=[
             _ctile_spec(w, tile_m), _ctile_spec(1, tile_m),
             _ccell_spec(1, chunk), _ccell_spec(1, chunk),
             _ccell_spec(1, nf), _ccell_spec(1, 3),
-            _ccell_spec(1, D), _ccell_spec(1, w),
+            _ccell_spec(D, 1), _ccell_spec(w, 1),
             _ccell_spec(w, w), _ccell_spec(1, w),
             _ccell_spec(1, 1), _ccell_spec(1, 1),
-            _ccell_spec(1, D), _ccell_spec(1, w),
+            _ccell_spec(D, 1), _ccell_spec(w, 1),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, w, Mp), jnp.float32),
@@ -773,14 +903,14 @@ def fused_chunk_windowed(V, C, d2, win, t0, stopped, *, chunk: int,
             jax.ShapeDtypeStruct((B, 1, chunk), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, nf), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 3), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1, w), jnp.float32),
+            jax.ShapeDtypeStruct((B, D, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, w, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, w, w), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, w), jnp.int32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1, w), jnp.float32),
+            jax.ShapeDtypeStruct((B, D, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, w, 1), jnp.float32),
         ],
         interpret=interpret,
         ins=(V, C, d2[:, None, :], f0, i0, vj0, cj0, Cw0, win0),
@@ -806,7 +936,7 @@ def dpp_greedy_tiled(
     window: int | None = None,
     eps: float = 1e-3,
     tile_m: int = 512,
-    interpret: bool = True,
+    interpret=None,
 ):
     """Batched greedy DPP MAP with the candidate axis streamed in tiles.
 
@@ -844,18 +974,17 @@ def dpp_greedy_tiled(
         dj = jnp.sqrt(jnp.maximum(dj2, eps2))
         sel = sel.at[:, t].set(jnp.where(stopped, -1, j))
         dh = dh.at[:, t].set(jnp.where(stopped, 0.0, dj))
-        vj = jnp.take_along_axis(V, j[:, None, None], axis=2)[:, :, 0]
+        vj = jnp.take_along_axis(V, j[:, None, None], axis=2)  # (B, D, 1)
         return sel, dh, stopped, dj, vj
 
     def step_full(t, carry):
         C, d2, sel, dh, stopped, j, dj2 = carry
         sel, dh, stopped, dj, vj = select(t, sel, dh, stopped, j, dj2)
-        cj = jnp.take_along_axis(C, j[:, None, None], axis=2)[:, :, 0]
+        cj = jnp.take_along_axis(C, j[:, None, None], axis=2)  # (B, R, 1)
         flt = jnp.stack([dj, stopped.astype(jnp.float32)], 1)[:, None, :]
         ints = jnp.stack([j, zero], 1)[:, None, :]
         e, d2, mx, am = _full_sweep(
-            V, C, d2, vj[:, None, :], cj[:, None, :], flt, ints,
-            tile_m=tile_m, interpret=interpret,
+            V, C, d2, vj, cj, flt, ints, tile_m=tile_m, interpret=interpret,
         )
         C = jax.lax.dynamic_update_slice(C, e, (0, t, 0))
         return C, d2, sel, dh, stopped, am[:, 0, 0], mx[:, 0, 0]
@@ -882,7 +1011,7 @@ def dpp_greedy_tiled(
         )[:, None, :]
         ints = jnp.stack([j, zero, zero + pos], 1)[:, None, :]
         C, d2, mx, am = _windowed_sweep(
-            V, C, d2, vj[:, None, :], cj_post[:, None, :], flt, ints,
+            V, C, d2, vj, cj_post[:, :, None], flt, ints,
             w=w, tile_m=tile_m, interpret=interpret,
         )
         win_shift = jnp.roll(win, -1, axis=1)

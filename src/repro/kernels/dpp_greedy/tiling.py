@@ -40,9 +40,17 @@ TileM = Union[int, str, None]
 
 LANE = 128
 SUBLANE = 8
-# Budget for f32 working sets inside ~16 MB/core VMEM, leaving headroom
-# for the compiler's own temporaries.
+# Budget for the f32 working set the models below count (operand blocks,
+# double-buffered where the pipeline streams them, plus scratch).
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# Scoped-VMEM limit every dpp_greedy pallas_call compiles with.  Under
+# Mosaic's default (16 MiB on v5e) the compiler refuses the resident
+# kernels at the budget's edge: the pipeline double-buffers the whole
+# V block and the kernels hold full-width temporaries (one-hot masks,
+# products) beside it.  TPU v5e has 128 MiB of VMEM per core; every
+# geometry TilePolicy can pick compiles under this limit
+# (tests/test_tpu_compile.py).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 # Upper bound for auto-chosen tiles: past this, wider tiles stop paying
 # (DMA is already fully amortized) and only lengthen the pipeline warmup.
 MAX_AUTO_TILE = 1 << 16

@@ -1,7 +1,9 @@
 """Batched serving driver with DPP slate diversification.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch deepfm --reduced \
+  PYTHONPATH=src python -m repro.launch.serve --arch deepfm \
       --requests 32 --candidates 2000 --slate 10 --alpha 3.0
+
+``--no-reduced`` serves the arch's full-size config.
 
 Serving pipeline per request batch (the paper's §5 scenario end-to-end):
   1. score all candidates with the CTR model (batched forward);
@@ -24,6 +26,7 @@ import numpy as np
 from repro.configs import get_arch
 from repro.core import mean_slate_diversity, top_n_select
 from repro.data import recsys_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import recsys as recsys_mod
 from repro.serving import DPPRerankConfig, Reranker, RerankRequest
 
@@ -31,7 +34,9 @@ from repro.serving import DPPRerankConfig, Reranker, RerankRequest
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepfm")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the arch's reduced config (--no-reduced: full size)")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--candidates", type=int, default=2000)
     ap.add_argument("--slate", type=int, default=10)
@@ -40,6 +45,7 @@ def main(argv=None):
     ap.add_argument("--use-kernel", action="store_true")
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     assert spec.family == "recsys", "serving driver targets the recsys family"

@@ -36,6 +36,7 @@ from repro import obs
 from repro.configs import get_arch
 from repro.models import recsys as recsys_mod
 from repro.data import recsys_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (
     DPPRerankConfig,
     ObsConfig,
@@ -49,7 +50,9 @@ from repro.serving.router import RouterQueueFull
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepfm")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the arch's reduced config (--no-reduced: full size)")
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--candidates", type=int, default=2000)
     ap.add_argument("--slate", type=int, default=16)
@@ -67,6 +70,7 @@ def main(argv=None):
                     help="write the run's spans as Chrome trace_event JSON "
                          "(Perfetto-loadable)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # observability is threaded through the serving configs, not turned
     # on globally here — the run exercises the same wiring users get

@@ -4,10 +4,12 @@ set far larger than any single device would hold.
   PYTHONPATH=src python -m repro.launch.serve_sharded \
       --devices 8 --candidates 1000000 --dim 32 --slate 20 --window 8
 
-Forces ``--devices`` host (CPU) devices via XLA_FLAGS — which must
-happen before the first jax import, so this module keeps its top-level
-imports jax-free (same contract as ``repro.launch.dryrun``) — builds a
-("data",) mesh over them, synthesizes scores/features for M candidates,
+On the CPU, forces ``--devices`` host devices via XLA_FLAGS — which
+must happen before the first jax import, so this module keeps its
+top-level imports jax-free (same contract as ``repro.launch.dryrun``);
+on a TPU the flag shapes nothing and the mesh spans the process's
+chips, one process for all of them.  Builds a ("data",) mesh over the
+devices, synthesizes scores/features for M candidates,
 and runs the full sharded pipeline end to end: sharded top-k shortlist
 mask -> candidate-sharded greedy MAP (exact or sliding-window).  Each
 device only ever holds a (D, M/P) column shard of the scaled feature
@@ -45,7 +47,8 @@ import time
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=8,
-                    help="force N host devices before jax init (0 = leave as-is)")
+                    help="host (CPU) devices to create when JAX runs on the "
+                         "CPU (0 = leave as-is); a TPU uses its own chips")
     ap.add_argument("--candidates", type=int, default=1_000_000)
     ap.add_argument("--dim", type=int, default=32)
     ap.add_argument("--slate", type=int, default=20)
@@ -75,15 +78,18 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.sharding import AxisType
 
-    from repro.distributed.context import make_mesh_compat
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import DPPRerankConfig, Reranker, RerankRequest
+
+    enable_compile_cache()
 
     if args.stream and args.batch > 1:
         raise SystemExit("--stream serves a single request; keep --batch 1")
 
     ndev = jax.device_count()
-    mesh = make_mesh_compat((ndev,), ("data",))
+    mesh = jax.make_mesh((ndev,), ("data",), axis_types=(AxisType.Auto,))
     M, D, N, B = args.candidates, args.dim, args.slate, args.batch
 
     rng = np.random.default_rng(args.seed)

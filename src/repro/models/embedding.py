@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed import context as dctx
-from repro.distributed.context import shard_map_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,12 +136,12 @@ def embedding_bag(
             got = got * valid_loc[..., None].astype(got.dtype)
             return got.sum(axis=2)
 
-        return shard_map_compat(
+        return jax.shard_map(
             body_a2a,
             mesh=mesh,
             in_specs=(P(ex_axes, None), ids_spec, ids_spec),
             out_specs=ids_spec,
-            check=False,
+            check_vma=False,
         )(table, flat, valid)
 
     def body(table_loc, flat_loc, valid_loc):
@@ -159,12 +158,12 @@ def embedding_bag(
     # psum path: ids must NOT be sharded over the model axis
     psum_batch = tuple(a for a in batch_axes if a != model_axis)
     ids_spec = P(psum_batch if psum_batch else None, None, None)
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(model_axis, None), ids_spec, ids_spec),
         out_specs=ids_spec,
-        check=False,
+        check_vma=False,
     )(table, flat, valid)
 
 

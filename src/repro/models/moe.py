@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import context as dctx
-from repro.distributed.context import shard_map_compat
 from repro.models.layers import dense_init
 
 
@@ -174,11 +173,11 @@ def moe_apply(p, x: jnp.ndarray, cfg: MoEConfig) -> Tuple[jnp.ndarray, jnp.ndarr
         aux = jax.lax.pmean(aux, pmean_axes)
         return out, aux
 
-    out, aux = shard_map_compat(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, p_specs),
         out_specs=(x_spec, P()),
-        check=False,
+        check_vma=False,
     )(xt, p)
     return out.reshape(B, S, d), aux

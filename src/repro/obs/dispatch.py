@@ -145,16 +145,23 @@ def record_kernel_dispatch(
     windowed: bool,
     tile_m: Optional[int] = None,
     vmem_bytes: Optional[int] = None,
+    interpret: bool = False,
 ) -> None:
-    """One ``ops.py`` execution-mode decision: which kernel path won
-    (``jnp`` / ``resident`` / ``tiled`` / ``fused_chunk``) and the
-    ``TilePolicy`` numbers behind it."""
+    """One kernel execution-mode decision: which kernel path won
+    (``jnp`` / ``resident`` / ``tiled`` / ``fused_chunk``), whether the
+    Pallas kernel runs interpreted, and the ``TilePolicy`` numbers
+    behind it."""
     reg = _obs.registry()
     if reg is None:
         return
     reg.counter(
         "dpp_kernel_dispatch_total", "kernel execution modes chosen by ops.py"
     ).inc(mode=mode, windowed=str(bool(windowed)))
+    if interpret and mode != "jnp":
+        reg.counter(
+            "dpp_kernel_interpreted_total",
+            "Pallas dispatches run by the interpreter (0 on a TPU)",
+        ).inc(mode=mode)
     g = reg.gauge(
         "dpp_tile_m", "candidate-axis tile of the last tiled dispatch (0 = "
         "whole-M resident)"

@@ -406,6 +406,5 @@ def _sharded_rerank_impl(scores, feats, cfg, mask, sharded_kernel):
         eps=cfg.eps,
         mask=smask,
         tile_m=cfg.tile_m,
-        interpret=cfg.interpret,
     )
     return res.indices.astype(jnp.int32), res.d_hist

@@ -7,9 +7,9 @@ paper's fast greedy MAP (Algorithm 1) — all inside the jitted serve step.
 
 All greedy variants are reached through ``repro.core.greedy_map``:
 
-* ``use_kernel=True`` routes through the Pallas kernels (interpret-mode
-  on CPU); the default jnp path lowers through XLA for the dry-run
-  cells.  Shortlists whose working set fits VMEM run the resident
+* ``use_kernel=True`` routes through the Pallas kernels (compiled on a
+  TPU, interpreted on other platforms); the default jnp path lowers
+  through XLA.  Shortlists whose working set fits VMEM run the resident
   whole-slate-in-VMEM kernel; past the budget the tiled streaming
   kernels take over (``TilePolicy`` — there is no silent jnp fallback
   at scale any more), and ``tile_m=`` pins the tile width explicitly.
@@ -85,14 +85,13 @@ class DPPRerankConfig:
     shortlist: int = 1000  # C (session default; RerankRequest overrides)
     alpha: float = 4.0  # trade-off (paper eq. 21); 1.0 = pure diversity
     eps: float = 1e-3
-    use_kernel: bool = False  # Pallas path (interpret on CPU)
+    use_kernel: bool = False  # Pallas path
     window: Optional[int] = None  # sliding diversity window (None = exact)
     mesh: Optional[object] = None  # shard the candidate axis over this mesh
     axis_name: str = "data"  # mesh axis carrying the candidate shards
     # Pallas candidate-axis tile: an explicit LANE multiple, "auto"
     # (measured autotune cache, model fallback), or None (VMEM model)
     tile_m: Union[int, str, None] = None
-    interpret: bool = True  # Pallas interpret mode (False on real TPU)
     chunk_size: Optional[int] = None  # Reranker.stream emission granularity
     obs: Optional[ObsConfig] = None  # observability (installed by Reranker)
 
@@ -147,7 +146,6 @@ class DPPRerankConfig:
             mesh=self.mesh,
             axis_name=self.axis_name,
             tile_m=self.tile_m,
-            interpret=self.interpret,
             # the jnp spec cannot carry a chunk size (its whole-slate
             # path would silently ignore it — GreedySpec rejects that);
             # Reranker.stream passes it to the chunk executor directly

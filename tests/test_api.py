@@ -15,9 +15,10 @@ import dataclasses
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
-from repro.distributed.context import make_mesh_compat
 from repro.serving import DPPRerankConfig, Reranker, RerankRequest
 from repro.serving.api import _rerank_impl
 
@@ -159,7 +160,7 @@ def test_stream_prep_is_hoisted(monkeypatch):
 
 
 def test_sharded_dispatch_one_device():
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     cfg = DPPRerankConfig(slate_size=6, shortlist=24, alpha=3.0, mesh=mesh,
                           chunk_size=3)
     s, f = _problem(48, seed=7)

@@ -41,7 +41,8 @@ def test_moe_sharded_matches_local():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.context import axis_rules, make_mesh_compat, single_pod_rules
+        from jax.sharding import AxisType
+        from repro.distributed.context import axis_rules, single_pod_rules
         from repro.models.moe import MoEConfig, moe_init, moe_apply
         # capacity_factor high enough that no token drops in either the
         # local (global-capacity) or sharded (per-source-capacity) path —
@@ -51,7 +52,7 @@ def test_moe_sharded_matches_local():
         p = moe_init(rng, 16, cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16))
         out_local, aux_local = moe_apply(p, x, cfg)  # no mesh
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         with axis_rules(single_pod_rules(), mesh):
             out_sh, aux_sh = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p, x)
         np.testing.assert_allclose(np.asarray(out_local), np.asarray(out_sh),
@@ -68,7 +69,8 @@ def test_embedding_bag_sharded_matches_local():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.context import axis_rules, make_mesh_compat, single_pod_rules
+        from jax.sharding import AxisType
+        from repro.distributed.context import axis_rules, single_pod_rules
         from repro.models.embedding import EmbeddingSpec, embedding_bag, init_table
         spec = EmbeddingSpec((100, 60, 200), 8, pad_to_multiple=8)
         table = init_table(jax.random.PRNGKey(0), spec)
@@ -77,7 +79,7 @@ def test_embedding_bag_sharded_matches_local():
         ids[:, :, 1] = np.where(rng.uniform(size=(16, 3)) < 0.5, -1, ids[:, :, 1])
         ids = jnp.asarray(ids.astype(np.int32))
         ref = embedding_bag(table, ids, spec)  # no mesh -> local
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         with axis_rules(single_pod_rules(), mesh):
             got = jax.jit(lambda t, i: embedding_bag(t, i, spec))(table, ids)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got), rtol=1e-5, atol=1e-6)
@@ -91,7 +93,8 @@ def test_lm_train_step_sharded_matches_single():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.context import axis_rules, make_mesh_compat, single_pod_rules
+        from jax.sharding import AxisType
+        from repro.distributed.context import axis_rules, single_pod_rules
         from repro.models.transformer import TransformerConfig, init_params, train_loss
         from repro.models.moe import MoEConfig
         # aux_loss_coef=0: the aux term is per-shard averaged when sharded
@@ -104,7 +107,7 @@ def test_lm_train_step_sharded_matches_single():
         params = init_params(jax.random.PRNGKey(0), cfg)
         toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 64)
         l0 = float(train_loss(params, {"tokens": toks}, cfg))
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         with axis_rules(single_pod_rules(), mesh):
             l1 = float(jax.jit(lambda p, b: train_loss(p, b, cfg))(params, {"tokens": toks}))
         assert abs(l0 - l1) < 5e-3, (l0, l1)
@@ -147,6 +150,7 @@ def test_elastic_reshard_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.distributed.elastic import make_elastic_mesh, reshard
         devs = jax.devices()

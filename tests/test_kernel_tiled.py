@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from conftest import assert_greedy_parity, make_greedy_inputs as make_inputs
 from repro.core import GreedySpec, GreedySpecError, greedy_map
@@ -384,13 +385,12 @@ def test_rerank_config_tile_m():
 def test_sharded_tiled_local_update_one_device(window):
     from repro.core import dpp_greedy_lowrank, dpp_greedy_sharded
     from repro.core.windowed import dpp_greedy_windowed_lowrank
-    from repro.distributed.context import make_mesh_compat
 
     rng = np.random.default_rng(45)
     M, D, k = 300, 24, 12
     V = jnp.asarray(rng.normal(size=(D, M)), jnp.float32) / np.sqrt(D)
     mask = jnp.asarray(rng.uniform(size=M) > 0.3)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     if window is None:
         ref = dpp_greedy_lowrank(V, k, eps=1e-6, mask=mask)
     else:
@@ -409,13 +409,12 @@ def test_sharded_tiled_batched_one_device():
     """The batched sharded path vmaps the SPMD body — the tiled Pallas
     pass inside must batch correctly (vmap-of-pallas_call)."""
     from repro.core import dpp_greedy_lowrank_batch, dpp_greedy_sharded
-    from repro.distributed.context import make_mesh_compat
 
     rng = np.random.default_rng(46)
     B, D, M, k = 3, 12, 200, 8
     V = jnp.asarray(rng.normal(size=(B, D, M)), jnp.float32) / np.sqrt(D)
     mask = jnp.asarray(rng.uniform(size=(B, M)) > 0.3)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     ref = dpp_greedy_lowrank_batch(V, k, 1e-6, mask)
     got = dpp_greedy_sharded(V, k, mesh=mesh, eps=1e-6, mask=mask, tile_m=128)
     np.testing.assert_array_equal(np.asarray(ref.indices),
@@ -442,9 +441,10 @@ def test_sharded_tiled_multidevice_parity():
             from repro.core import (dpp_greedy_sharded, dpp_greedy_lowrank,
                                     dpp_greedy_lowrank_batch)
             from repro.core.windowed import dpp_greedy_windowed_lowrank
-            from repro.distributed.context import make_mesh_compat
             assert jax.device_count() == 8
-            mesh = make_mesh_compat((8,), ("data",))
+            import jax
+            from jax.sharding import AxisType
+            mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
             rng = np.random.default_rng(0)
             M, D, k = 3001, 16, 12  # not divisible by 8*128 (padded shards)
             V = jnp.asarray(rng.normal(size=(D, M)), jnp.float32) / np.sqrt(D)

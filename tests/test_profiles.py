@@ -30,7 +30,8 @@ def test_fsdp_ep_rules_match_reference_loss():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.context import axis_rules, fsdp_ep_rules, make_mesh_compat
+        from jax.sharding import AxisType
+        from repro.distributed.context import axis_rules, fsdp_ep_rules
         from repro.models.transformer import TransformerConfig, init_params, train_loss
         from repro.models.moe import MoEConfig
         cfg = TransformerConfig(name="t", n_layers=2, d_model=32, n_heads=4,
@@ -41,7 +42,7 @@ def test_fsdp_ep_rules_match_reference_loss():
         params = init_params(jax.random.PRNGKey(0), cfg)
         toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 64)
         l0 = float(train_loss(params, {"tokens": toks}, cfg))
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         rules = dict(fsdp_ep_rules(False))
         with axis_rules(rules, mesh):
             l1 = float(jax.jit(lambda p, b: train_loss(p, b, cfg))(params, {"tokens": toks}))
@@ -55,7 +56,8 @@ def test_a2a_recsys_profile_matches_reference_loss():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.context import axis_rules, make_mesh_compat, recsys_a2a_rules
+        from jax.sharding import AxisType
+        from repro.distributed.context import axis_rules, recsys_a2a_rules
         from repro.models import recsys
         from repro.data import recsys_batches
         cfg = recsys.RecsysConfig(
@@ -67,7 +69,7 @@ def test_a2a_recsys_profile_matches_reference_loss():
         ids = jnp.asarray(b["ids"]); y = jnp.asarray(b["labels"])
         ref_cfg = recsys.RecsysConfig(**{**cfg.__dict__, "emb_mode": "psum"})
         l0 = float(recsys.bce_loss(params, {"ids": ids, "labels": y}, ref_cfg))
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         with axis_rules(recsys_a2a_rules(False), mesh):
             l1 = float(jax.jit(lambda p: recsys.bce_loss(p, {"ids": ids, "labels": y}, cfg))(params))
         assert abs(l0 - l1) < 1e-4, (l0, l1)

@@ -338,12 +338,13 @@ def test_router_multidevice_sharded_parity():
         )
         import numpy as np
         import jax.numpy as jnp
-        from repro.distributed.context import make_mesh_compat
         from repro.serving import (
             DPPRerankConfig, Reranker, RerankRequest, RouterConfig,
         )
 
-        mesh = make_mesh_compat((8,), ("data",))
+        import jax
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         M = 64  # bucket: every request padded to the full sharded width
         cfg = DPPRerankConfig(slate_size=6, shortlist=48, alpha=3.0,
                               mesh=mesh, chunk_size=2)

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from conftest import assert_greedy_parity, make_greedy_inputs, serve_rerank
 from repro.core import (
@@ -33,7 +34,6 @@ from repro.core import (
     sharded_topk,
 )
 from repro.core.windowed import dpp_greedy_windowed_lowrank
-from repro.distributed.context import make_mesh_compat
 from repro.serving import DPPRerankConfig, Reranker, RerankRequest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -76,18 +76,21 @@ def test_spec_validation_at_construction():
     with pytest.raises(GreedySpecError, match="mesh"):
         GreedySpec(k=5, backend="sharded")
     with pytest.raises(GreedySpecError, match="mesh"):
-        GreedySpec(k=5, backend="pallas", mesh=make_mesh_compat((1,), ("data",)))
+        GreedySpec(k=5, backend="pallas", mesh=jax.make_mesh(
+            (1,), ("data",), axis_types=(AxisType.Auto,)))
     with pytest.raises(GreedySpecError, match="silently ignored"):
-        GreedySpec(k=5, backend="jnp", mesh=make_mesh_compat((1,), ("data",)))
+        GreedySpec(k=5, backend="jnp", mesh=jax.make_mesh(
+            (1,), ("data",), axis_types=(AxisType.Auto,)))
     # GreedySpecError is a ValueError: existing except-ValueError callers hold
     assert issubclass(GreedySpecError, ValueError)
     # valid specs still construct
     GreedySpec(k=5, window=5)
-    GreedySpec(k=5, backend="sharded", mesh=make_mesh_compat((1,), ("data",)))
+    GreedySpec(k=5, backend="sharded", mesh=jax.make_mesh(
+        (1,), ("data",), axis_types=(AxisType.Auto,)))
 
 
 def test_rerank_config_validation():
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     with pytest.raises(ValueError, match="mutually exclusive"):
         DPPRerankConfig(use_kernel=True, mesh=mesh)
     spec = DPPRerankConfig(slate_size=4, mesh=mesh).greedy_spec()
@@ -125,7 +128,8 @@ def test_rerank_config_validates_at_construction():
 def test_sharded_matches_lowrank_one_device(seed):
     V = _problem(seed)
     ref = dpp_greedy_lowrank(V, 10, eps=1e-6)
-    got = dpp_greedy_sharded(V, 10, mesh=make_mesh_compat((1,), ("data",)), eps=1e-6)
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
+    got = dpp_greedy_sharded(V, 10, mesh=mesh, eps=1e-6)
     np.testing.assert_array_equal(np.asarray(ref.indices), np.asarray(got.indices))
     np.testing.assert_array_equal(np.asarray(ref.d_hist), np.asarray(got.d_hist))
     assert int(ref.n_selected) == int(got.n_selected)
@@ -138,9 +142,9 @@ def test_sharded_matches_shared_oracle(greedy_oracle, window):
     V = _problem(7)
     rng = np.random.default_rng(7)
     mask = jnp.asarray(rng.uniform(size=V.shape[1]) > 0.25)
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     got = dpp_greedy_sharded(
-        V, 10, mesh=make_mesh_compat((1,), ("data",)), window=window,
-        eps=1e-6, mask=mask,
+        V, 10, mesh=mesh, window=window, eps=1e-6, mask=mask,
     )
     assert_greedy_parity(greedy_oracle, got.indices, got.d_hist, V, 10,
                          window=window, eps=1e-6, mask=mask)
@@ -148,7 +152,7 @@ def test_sharded_matches_shared_oracle(greedy_oracle, window):
 
 def test_sharded_windowed_matches_one_device():
     V = _problem(3)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     ref = dpp_greedy_windowed_lowrank(V, 24, window=5, eps=1e-6)
     got = dpp_greedy_sharded(V, 24, mesh=mesh, window=5, eps=1e-6)
     np.testing.assert_array_equal(np.asarray(ref.indices), np.asarray(got.indices))
@@ -162,7 +166,7 @@ def test_sharded_mask_and_dispatch():
     M = V.shape[1]
     rng = np.random.default_rng(4)
     mask = jnp.asarray(rng.uniform(size=M) > 0.4)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     ref = dpp_greedy_lowrank(V, 8, eps=1e-6, mask=mask)
     got = greedy_map(
         GreedySpec(k=8, backend="sharded", mesh=mesh, eps=1e-6), V=V, mask=mask
@@ -175,7 +179,7 @@ def test_sharded_mask_and_dispatch():
 
 
 def test_sharded_rejects_dense_and_bad_rank():
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     spec = GreedySpec(k=4, backend="sharded", mesh=mesh)
     L = jnp.eye(8)
     with pytest.raises(ValueError, match="low-rank V"):
@@ -189,7 +193,7 @@ def test_sharded_rejects_dense_and_bad_rank():
 def test_sharded_topk_one_device():
     rng = np.random.default_rng(7)
     s = jnp.asarray(rng.uniform(size=97), jnp.float32)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     v1, i1 = jax.lax.top_k(s, 13)
     v2, i2 = sharded_topk(s, 13, mesh=mesh)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
@@ -202,7 +206,7 @@ def test_sharded_rerank_matches_dense_one_device():
     scores = jnp.asarray(rng.uniform(size=M), jnp.float32)
     feats = jnp.asarray(rng.normal(size=(M, D)), jnp.float32)
     feats = feats / jnp.linalg.norm(feats, axis=1, keepdims=True)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     for window in (None, 4):
         dense, _ = serve_rerank(
             scores, feats,
@@ -231,7 +235,7 @@ def test_sharded_batched_matches_lowrank_batch_one_device():
     B, D, M, k = 4, 12, 90, 8
     V = jnp.asarray(rng.normal(size=(B, D, M)), jnp.float32) / np.sqrt(D)
     mask = jnp.asarray(rng.uniform(size=(B, M)) > 0.3)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     ref = dpp_greedy_lowrank_batch(V, k, 1e-6, mask)
     got = dpp_greedy_sharded(V, k, mesh=mesh, eps=1e-6, mask=mask)
     assert got.indices.shape == (B, k)
@@ -254,7 +258,7 @@ def test_sharded_batched_matches_lowrank_batch_one_device():
 def test_sharded_topk_batched_one_device():
     rng = np.random.default_rng(22)
     s = jnp.asarray(rng.uniform(size=(3, 97)), jnp.float32)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     v1, i1 = jax.lax.top_k(s, 13)  # top_k batches over leading axes
     v2, i2 = sharded_topk(s, 13, mesh=mesh)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
@@ -276,7 +280,7 @@ def test_rerank_batch_sharded_matches_vmap_one_device(window, per_user_feats):
     feats /= np.linalg.norm(feats, axis=-1, keepdims=True)
     feats = jnp.asarray(feats)
     mask = jnp.asarray(rng.uniform(size=(B, M)) > 0.25)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     kw = dict(slate_size=6, shortlist=64, alpha=3.0, eps=1e-6, window=window)
     ref, ref_dh = serve_rerank(scores, feats, DPPRerankConfig(**kw), mask=mask)
     got, got_dh = serve_rerank(
@@ -298,7 +302,7 @@ def test_rerank_batch_sharded_eps_stop():
     feats = rng.normal(size=(B, M, D)).astype(np.float32)
     feats /= np.linalg.norm(feats, axis=-1, keepdims=True)
     feats = jnp.asarray(feats)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     kw = dict(slate_size=10, shortlist=64, alpha=2.0, eps=1e-2)
     ref, _ = serve_rerank(scores, feats, DPPRerankConfig(**kw))
     got, _ = serve_rerank(scores, feats, DPPRerankConfig(mesh=mesh, **kw))
@@ -324,7 +328,7 @@ def test_shared_mask_batched_V_all_backends(backend):
     mask = jnp.asarray(rng.uniform(size=M) > 0.4)  # shared across users
     kw = dict(k=k, eps=1e-6)
     if backend == "sharded":
-        kw["mesh"] = make_mesh_compat((1,), ("data",))
+        kw["mesh"] = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     spec = GreedySpec(backend=backend, **kw)
     got = greedy_map(spec, V=V, mask=mask)
     ref = greedy_map(
@@ -353,7 +357,7 @@ def test_sharded_rerank_masked_score_poison(poison):
     clean = jnp.asarray(scores)
     scores = scores.copy()
     scores[7] = poison
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     cfg = DPPRerankConfig(
         slate_size=8, shortlist=64, alpha=3.0, eps=1e-6, mesh=mesh
     )
@@ -376,7 +380,7 @@ def test_sharded_rerank_rejects_rank_inconsistent_inputs():
     M, D, B = 64, 6, 3
     scores = jnp.asarray(rng.uniform(size=M), jnp.float32)
     feats = jnp.asarray(rng.normal(size=(M, D)), jnp.float32)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     cfg = DPPRerankConfig(slate_size=4, shortlist=32, mesh=mesh)
     with pytest.raises(ValueError, match="feats must be"):
         RerankRequest(scores=scores, feats=jnp.stack([feats] * B))
@@ -400,7 +404,7 @@ def test_sharded_rerank_inf_relevance_outside_shortlist():
     scores[11] = -130.0  # 0.5 ** -130 overflows float32 -> inf relevance
     feats = rng.normal(size=(M, D)).astype(np.float32)
     feats /= np.linalg.norm(feats, axis=1, keepdims=True)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     kw = dict(slate_size=8, shortlist=64, alpha=0.5, eps=1e-6)
     ref, _ = serve_rerank(jnp.asarray(scores), jnp.asarray(feats),
                           DPPRerankConfig(**kw))
@@ -470,9 +474,10 @@ def test_sharded_matches_lowrank_multidevice_property():
         from hypothesis import given, settings, strategies as st
         from repro.core import dpp_greedy_sharded, dpp_greedy_lowrank
         from repro.core.windowed import dpp_greedy_windowed_lowrank
-        from repro.distributed.context import make_mesh_compat
         assert jax.device_count() == 8
-        mesh = make_mesh_compat((8,), ("data",))
+        import jax
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
         @settings(max_examples=20, deadline=None)
         @given(
@@ -523,13 +528,14 @@ def test_sharded_rerank_multidevice_serving_parity():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import sharded_topk
-        from repro.distributed.context import make_mesh_compat
         from repro.serving import DPPRerankConfig, Reranker, RerankRequest
         def rr(s, f, cfg, mask=None):
             return Reranker(cfg).rerank(
                 RerankRequest(scores=s, feats=f, mask=mask))
         assert jax.device_count() == 8
-        mesh = make_mesh_compat((8,), ("data",))
+        import jax
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         M, D = 3001, 16  # deliberately not divisible by 8 (padded shards)
         scores = jnp.asarray(rng.uniform(size=M), jnp.float32)
@@ -565,13 +571,14 @@ def test_rerank_batch_sharded_multidevice_parity():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import numpy as np, jax, jax.numpy as jnp
-        from repro.distributed.context import make_mesh_compat
         from repro.serving import DPPRerankConfig, Reranker, RerankRequest
         def rr(s, f, cfg, mask=None):
             return Reranker(cfg).rerank(
                 RerankRequest(scores=s, feats=f, mask=mask))
         assert jax.device_count() == 8
-        mesh = make_mesh_compat((8,), ("data",))
+        import jax
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(1)
         B, M, D = 5, 1501, 12  # M not divisible by 8 (padded shards)
         scores = jnp.asarray(rng.uniform(size=(B, M)), jnp.float32)

@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from conftest import (
     assert_greedy_parity,
@@ -45,7 +46,6 @@ from repro.core import (
     greedy_map_chunks,
     greedy_step,
 )
-from repro.distributed.context import make_mesh_compat
 from repro.serving.reranker import DPPRerankConfig
 
 # the CI autotune lane sets DPP_TILE_M=auto — a policy mode, not a
@@ -74,7 +74,7 @@ def _spec(backend, k, window, chunk=None, eps=1e-6):
     if backend == "pallas_tiled":
         return GreedySpec(k=k, window=window, backend="pallas", eps=eps,
                           tile_m=tile, chunk_size=chunk)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     tm = tile if backend == "sharded_tiled" else None
     return GreedySpec(k=k, window=window, backend="sharded", mesh=mesh,
                       eps=eps, tile_m=tm, chunk_size=chunk)
@@ -234,7 +234,7 @@ def test_prefix_of_chunks_equals_whole_prefix_property():
 
 
 def _serving_cfgs():
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     tile = _ENV_TILE or 128
     return {
         "jnp": {},
@@ -346,7 +346,7 @@ def test_spec_rejects_chunk_size_on_backends_that_ignore_it():
     # backends with a chunked execution path accept it
     GreedySpec(k=8, backend="pallas", chunk_size=4)
     GreedySpec(k=8, backend="sharded", chunk_size=4,
-               mesh=make_mesh_compat((1,), ("data",)))
+               mesh=jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,)))
     # serving config mirrors the positivity check, and its greedy_spec()
     # never forwards chunk_size onto a jnp spec
     with pytest.raises(ValueError, match="chunk_size"):

@@ -1,0 +1,179 @@
+"""AOT compiles of the reranking path's Pallas kernels for a TPU v5e.
+
+Interpret mode (every other kernel test) cannot see what the Mosaic
+compiler refuses: lane-axis dynamic slices, scalar stores to VMEM, a
+working set over the scoped-VMEM limit.  These tests compile each
+``dpp_greedy`` kernel family at the served widths for one chip of a
+described ``v5e:2x2`` topology — nothing runs, no chip is needed — and
+the geometries ``TilePolicy`` picks at the edge of its VMEM budget.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU compiler library, and every
+test worker imports this file.
+"""
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.dpp_greedy.dpp_greedy import dpp_greedy_kernel
+from repro.kernels.dpp_greedy.tiled import (
+    dpp_greedy_tiled,
+    fused_chunk_exact,
+    fused_chunk_windowed,
+)
+from repro.kernels.dpp_greedy.tiling import (
+    LANE,
+    VMEM_BUDGET_BYTES,
+    TilePolicy,
+    round_up,
+    untiled_vmem_bytes,
+)
+
+# served widths: a shortlist of 1000 at D=100 (padded to the (8, 128)
+# tile), B=8 users; the past-the-budget pool of 131072 at D=64
+B, D, M, K, W, CHUNK = 8, round_up(100, 8), round_up(1000, LANE), 50, 8, 10
+D_BIG, M_BIG = 64, 131072
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    """``shape(dims, dtype)`` -> an abstract operand on one v5e chip.
+
+    The persistent compilation cache is off while these compile: an
+    entry written for a described chip cannot be read back without one.
+    """
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *operands):
+    return jax.jit(fn).lower(*operands).compile()
+
+
+def _resident(shape, k, window, Bn, Dn, Mn):
+    fn = functools.partial(
+        dpp_greedy_kernel, k=k, window=window, interpret=False
+    )
+    return _compile(fn, shape((Bn, Dn, Mn)), shape((Bn, Mn)))
+
+
+def _tiled(shape, k, window, tile, Dn, Mn):
+    fn = functools.partial(
+        dpp_greedy_tiled, k=k, window=window, tile_m=tile, interpret=False
+    )
+    return _compile(fn, shape((1, Dn, Mn)), shape((1, Mn)))
+
+
+def _chunk(shape, R, windowed, Dn, tile):
+    ops = [shape((1, Dn, tile)), shape((1, R, tile)), shape((1, tile))]
+    if windowed:
+        fn = functools.partial(
+            fused_chunk_windowed, t0=0, chunk=CHUNK, eps=1e-3, w=R,
+            tile_m=tile, interpret=False,
+        )
+        ops.append(shape((1, R), jnp.int32))
+    else:
+        fn = functools.partial(
+            fused_chunk_exact, t0=0, chunk=CHUNK, eps=1e-3, tile_m=tile,
+            interpret=False,
+        )
+    return _compile(
+        lambda *a: fn(*a, stopped=jnp.zeros((1,), bool)), *ops
+    )
+
+
+def _policy_tile(Dn, R, windowed, chunked=False):
+    """The tile TilePolicy picks for a pool far past the budget."""
+    mode, tm = TilePolicy().decide(
+        Dn, 1 << 22, R, windowed, chunked=chunked
+    )
+    assert mode == "tiled"
+    return tm
+
+
+def test_resident_exact_compiles(shape):
+    _resident(shape, K, None, B, D, M)
+
+
+def test_resident_windowed_compiles(shape):
+    _resident(shape, 2 * K, W, B, D, M)
+
+
+def test_tiled_exact_compiles(shape):
+    tile = _policy_tile(D_BIG, K, False)
+    _tiled(shape, K, None, tile, D_BIG, round_up(M_BIG, tile))
+
+
+def test_tiled_windowed_compiles(shape):
+    tile = _policy_tile(D_BIG, W, True)
+    _tiled(shape, K, W, tile, D_BIG, round_up(M_BIG, tile))
+
+
+def test_fused_chunk_exact_one_tile_compiles(shape):
+    _chunk(shape, K, False, D, M)
+
+
+def test_fused_chunk_windowed_one_tile_compiles(shape):
+    _chunk(shape, W, True, D, M)
+
+
+def test_compiled_multitile_fused_chunk_raises(shape):
+    """Past one tile the fused chunk's cross-step state sits in output
+    blocks revisited non-consecutively; compiling that must raise, never
+    interpret or fall back quietly."""
+    with pytest.raises(NotImplementedError, match="single whole-M tile"):
+        _compile(
+            lambda V, C, d2: fused_chunk_exact(
+                V, C, d2, 0, jnp.zeros((1,), bool), chunk=CHUNK, eps=1e-3,
+                tile_m=LANE, interpret=False,
+            ),
+            shape((1, D, 2 * LANE)), shape((1, K, 2 * LANE)),
+            shape((1, 2 * LANE)),
+        )
+
+
+@pytest.mark.parametrize(
+    "Dn,R,windowed", [(8, 8, True), (104, 50, False), (256, 128, False)]
+)
+def test_budget_edge_geometries_compile(shape, Dn, R, windowed):
+    """The widest resident M (also the whole-M tile a stream of that
+    pool chunks with), per-step tile and chunk tile the VMEM budget
+    admits all fit what the compiler accepts."""
+    Mr = LANE
+    while untiled_vmem_bytes(Dn, Mr + LANE, R) <= VMEM_BUDGET_BYTES:
+        Mr += LANE
+    k = 2 * R if windowed else R
+    w = R if windowed else None
+    _resident(shape, k, w, 1, Dn, Mr)
+    _chunk(shape, R, windowed, Dn, Mr)
+    tile = _policy_tile(Dn, R, windowed)
+    _tiled(shape, k, w, tile, Dn, 2 * tile)
+    _chunk(shape, R, windowed, Dn, _policy_tile(Dn, R, windowed, True))
