@@ -84,17 +84,19 @@ def _global_argmax(d2, ax, off, axis_name):
     PartitionId op the SPMD partitioner rejects (observed on jax 0.4.x
     when the w=1 eviction loop folds away).
     """
-    jl = jnp.argmax(d2).astype(jnp.int32)
-    dv = jax.lax.all_gather(d2[jl], axis_name)  # (P,)
-    gv = jax.lax.all_gather(jl + off, axis_name)
-    p = jnp.argmax(dv)
-    return jl, dv[p], gv[p], p == ax
+    with jax.named_scope("sharded.merge"):
+        jl = jnp.argmax(d2).astype(jnp.int32)
+        dv = jax.lax.all_gather(d2[jl], axis_name)  # (P,)
+        gv = jax.lax.all_gather(jl + off, axis_name)
+        p = jnp.argmax(dv)
+        return jl, dv[p], gv[p], p == ax
 
 
 def _bcast_from_owner(parts, owner, axis_name):
     """Replicate the owner shard's small vectors to every device (one psum)."""
-    z = jnp.concatenate([jnp.atleast_1d(x) for x in parts])
-    return jax.lax.psum(jnp.where(owner, z, jnp.zeros_like(z)), axis_name)
+    with jax.named_scope("sharded.merge"):
+        z = jnp.concatenate([jnp.atleast_1d(x) for x in parts])
+        return jax.lax.psum(jnp.where(owner, z, jnp.zeros_like(z)), axis_name)
 
 
 def _exact_step_fn(

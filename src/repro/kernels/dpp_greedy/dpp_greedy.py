@@ -194,12 +194,13 @@ def dpp_greedy_kernel(
 
     if window is not None and window < k:
         kernel = functools.partial(_kernel_windowed, k=k, w=window, eps=eps)
-        state_rows = window
+        state_rows, name = window, "dpp_resident_windowed"
     else:
         kernel = functools.partial(_kernel, k=k, eps=eps)
-        state_rows = k
+        state_rows, name = k, "dpp_resident_exact"
     sel, dhist = pl.pallas_call(
         kernel,
+        name=name,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((None, D, M), lambda b: (b, 0, 0)),

@@ -325,15 +325,18 @@ def _small_spec(rows, cols):
     return pl.BlockSpec((None, rows, cols), lambda b, i: (b, 0, 0))
 
 
-def _sweep(kernel, row_out, V, C, d2, vj, cj, flt, ints, tile_m, interpret):
+def _sweep(kernel, name, row_out, V, C, d2, vj, cj, flt, ints, tile_m,
+           interpret):
     """Run one per-step grid sweep.  ``row_out`` is the row count of the
     first (streamed) output: 1 for the exact append row, w for the
-    windowed post-eviction ring."""
+    windowed post-eviction ring.  ``name`` names the kernel family in
+    the compiled program (``dpp_step_exact`` / ``dpp_step_windowed``)."""
     B, D, Mp = V.shape
     R = C.shape[1]
     nt = Mp // tile_m
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=(B, nt),
         in_specs=[
             _tile_spec(D, tile_m),
@@ -363,13 +366,15 @@ def _sweep(kernel, row_out, V, C, d2, vj, cj, flt, ints, tile_m, interpret):
 
 def _full_sweep(V, C, d2, vj, cj, flt, ints, *, tile_m, interpret=None):
     kernel = functools.partial(_pass_full, tile_m=tile_m)
-    return _sweep(kernel, 1, V, C, d2, vj, cj, flt, ints, tile_m, interpret)
+    return _sweep(kernel, "dpp_step_exact", 1, V, C, d2, vj, cj, flt, ints,
+                  tile_m, interpret)
 
 
 def _windowed_sweep(V, C, d2, vj, cj, flt, ints, *, w, tile_m,
                     interpret=None):
     kernel = functools.partial(_pass_windowed, w=w, tile_m=tile_m)
-    return _sweep(kernel, w, V, C, d2, vj, cj, flt, ints, tile_m, interpret)
+    return _sweep(kernel, "dpp_step_windowed", w, V, C, d2, vj, cj, flt,
+                  ints, tile_m, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -745,13 +750,15 @@ def _require_interpret_for_multitile(interpret: bool, nt: int) -> None:
         )
 
 
-def _fused_chunk_call(kernel, *, grid, in_specs, out_specs, out_shape,
-                      interpret, ins):
+def _fused_chunk_call(kernel, *, name, grid, in_specs, out_specs,
+                      out_shape, interpret, ins):
     """The single ``pallas_call`` a fused chunk makes.  Kept as a named
     seam so tests can count invocations: one call — one C/d2 HBM
-    round-trip — per chunk, however many steps the chunk spans."""
+    round-trip — per chunk, however many steps the chunk spans.
+    ``name`` names the kernel family in the compiled program
+    (``dpp_chunk_exact`` / ``dpp_chunk_windowed``)."""
     return pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        kernel, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*ins)
@@ -815,6 +822,7 @@ def fused_chunk_exact(V, C, d2, t0, stopped, *, chunk: int, eps: float,
     kernel = functools.partial(_chunk_pass_full, eps=eps, tile_m=tile_m)
     outs = _fused_chunk_call(
         kernel,
+        name="dpp_chunk_exact",
         grid=(B, chunk, nt),
         in_specs=[
             _ctile_spec(D, tile_m), _ctile_spec(R, tile_m),
@@ -879,6 +887,7 @@ def fused_chunk_windowed(V, C, d2, win, t0, stopped, *, chunk: int,
     )
     outs = _fused_chunk_call(
         kernel,
+        name="dpp_chunk_windowed",
         grid=(B, chunk, nt),
         in_specs=[
             _ctile_spec(D, tile_m), _ctile_spec(w, tile_m),
@@ -950,6 +959,13 @@ def dpp_greedy_tiled(
     Cholesky state round-trips through HBM between steps — that is the
     price of M not fitting in VMEM, and it is streamed, double-buffered
     traffic, not a fallback to unfused jnp.
+
+    The phases carry ``jax.named_scope`` names in the compiled program's
+    op metadata, for a profile to be read by: ``greedy.init`` (the
+    diagonal and the first argmax), ``greedy.select`` (the pick and the
+    winner's V / C column gathers), ``greedy.sweep`` (the kernel launch
+    and its operands) and ``greedy.append`` (the Cholesky row or window
+    ring write-back).
     """
     B, D, M = V.shape
     if M % tile_m != 0:
@@ -959,14 +975,15 @@ def dpp_greedy_tiled(
     R = k if w is None else w
     eps2 = eps * eps
 
-    diag = jnp.sum(V * V, axis=1)  # (B, M)
-    d2 = jnp.where(mask > 0, diag, NEG_INF)[:, None, :]  # (B, 1, M)
-    C = jnp.zeros((B, R, M), jnp.float32)
-    sel = jnp.full((B, k), -1, jnp.int32)
-    dh = jnp.zeros((B, k), jnp.float32)
-    j0 = jnp.argmax(d2[:, 0, :], axis=1).astype(jnp.int32)
-    dj20 = jnp.take_along_axis(d2[:, 0, :], j0[:, None], axis=1)[:, 0]
-    stopped0 = jnp.zeros((B,), bool)
+    with jax.named_scope("greedy.init"):
+        diag = jnp.sum(V * V, axis=1)  # (B, M)
+        d2 = jnp.where(mask > 0, diag, NEG_INF)[:, None, :]  # (B, 1, M)
+        C = jnp.zeros((B, R, M), jnp.float32)
+        sel = jnp.full((B, k), -1, jnp.int32)
+        dh = jnp.zeros((B, k), jnp.float32)
+        j0 = jnp.argmax(d2[:, 0, :], axis=1).astype(jnp.int32)
+        dj20 = jnp.take_along_axis(d2[:, 0, :], j0[:, None], axis=1)[:, 0]
+        stopped0 = jnp.zeros((B,), bool)
     zero = jnp.zeros((B,), jnp.int32)
 
     def select(t, sel, dh, stopped, j, dj2):
@@ -979,44 +996,56 @@ def dpp_greedy_tiled(
 
     def step_full(t, carry):
         C, d2, sel, dh, stopped, j, dj2 = carry
-        sel, dh, stopped, dj, vj = select(t, sel, dh, stopped, j, dj2)
-        cj = jnp.take_along_axis(C, j[:, None, None], axis=2)  # (B, R, 1)
-        flt = jnp.stack([dj, stopped.astype(jnp.float32)], 1)[:, None, :]
-        ints = jnp.stack([j, zero], 1)[:, None, :]
-        e, d2, mx, am = _full_sweep(
-            V, C, d2, vj, cj, flt, ints, tile_m=tile_m, interpret=interpret,
-        )
-        C = jax.lax.dynamic_update_slice(C, e, (0, t, 0))
+        with jax.named_scope("greedy.select"):
+            sel, dh, stopped, dj, vj = select(t, sel, dh, stopped, j, dj2)
+            cj = jnp.take_along_axis(C, j[:, None, None], axis=2)  # (B, R, 1)
+        with jax.named_scope("greedy.sweep"):
+            flt = jnp.stack([dj, stopped.astype(jnp.float32)], 1)[:, None, :]
+            ints = jnp.stack([j, zero], 1)[:, None, :]
+            e, d2, mx, am = _full_sweep(
+                V, C, d2, vj, cj, flt, ints, tile_m=tile_m,
+                interpret=interpret,
+            )
+        with jax.named_scope("greedy.append"):
+            C = jax.lax.dynamic_update_slice(C, e, (0, t, 0))
         return C, d2, sel, dh, stopped, am[:, 0, 0], mx[:, 0, 0]
 
     def step_windowed(t, carry):
         C, d2, win, sel, dh, stopped, j, dj2 = carry
-        sel, dh, stopped, dj, vj = select(t, sel, dh, stopped, j, dj2)
-        cj_pre = jnp.take_along_axis(C, j[:, None, None], axis=2)[:, :, 0]
+        with jax.named_scope("greedy.select"):
+            sel, dh, stopped, dj, vj = select(t, sel, dh, stopped, j, dj2)
+            cj_pre = jnp.take_along_axis(C, j[:, None, None], axis=2)[:, :, 0]
+            Cw = jnp.take_along_axis(C, jnp.clip(win, 0)[:, None, :], axis=2)
+            Cw = jnp.where((win >= 0)[:, None, :], Cw, 0.0)
         full = (t >= w) & ~stopped  # (B,)
-        Cw = jnp.take_along_axis(C, jnp.clip(win, 0)[:, None, :], axis=2)
-        Cw = jnp.where((win >= 0)[:, None, :], Cw, 0.0)
-        cos, sin, cj_post, d2j = eviction_coeffs(Cw, cj_pre, dj2, full, w)
-        djp = jnp.sqrt(jnp.maximum(d2j, eps2))
         pos = jnp.minimum(t, w - 1)
-        flt = jnp.concatenate(
-            [
-                jnp.stack(
-                    [djp, stopped.astype(jnp.float32), full.astype(jnp.float32)],
-                    1,
-                ),
-                cos, sin,
-            ],
-            axis=1,
-        )[:, None, :]
-        ints = jnp.stack([j, zero, zero + pos], 1)[:, None, :]
-        C, d2, mx, am = _windowed_sweep(
-            V, C, d2, vj, cj_post[:, :, None], flt, ints,
-            w=w, tile_m=tile_m, interpret=interpret,
-        )
-        win_shift = jnp.roll(win, -1, axis=1)
-        win1 = jnp.where(full[:, None], win_shift.at[:, w - 1].set(-1), win)
-        win = jnp.where(stopped[:, None], win, win1.at[:, pos].set(j))
+        with jax.named_scope("greedy.sweep"):
+            cos, sin, cj_post, d2j = eviction_coeffs(
+                Cw, cj_pre, dj2, full, w
+            )
+            djp = jnp.sqrt(jnp.maximum(d2j, eps2))
+            flt = jnp.concatenate(
+                [
+                    jnp.stack(
+                        [djp, stopped.astype(jnp.float32),
+                         full.astype(jnp.float32)],
+                        1,
+                    ),
+                    cos, sin,
+                ],
+                axis=1,
+            )[:, None, :]
+            ints = jnp.stack([j, zero, zero + pos], 1)[:, None, :]
+            C, d2, mx, am = _windowed_sweep(
+                V, C, d2, vj, cj_post[:, :, None], flt, ints,
+                w=w, tile_m=tile_m, interpret=interpret,
+            )
+        with jax.named_scope("greedy.append"):
+            win_shift = jnp.roll(win, -1, axis=1)
+            win1 = jnp.where(
+                full[:, None], win_shift.at[:, w - 1].set(-1), win
+            )
+            win = jnp.where(stopped[:, None], win, win1.at[:, pos].set(j))
         return C, d2, win, sel, dh, stopped, am[:, 0, 0], mx[:, 0, 0]
 
     if w is None:

@@ -136,6 +136,18 @@ class CompileMonitor:
 # ---------------------------------------------------------------------------
 
 
+def record_rerank_call(path: str) -> None:
+    """One ``Reranker.rerank`` call down ``path`` (``single`` /
+    ``batched`` / ``sharded``): the count the per-call readers of the
+    ``serving.rerank.*`` spans divide by."""
+    reg = _obs.registry()
+    if reg is None:
+        return
+    reg.counter(
+        "serving_rerank_calls_total", "Reranker.rerank calls by path"
+    ).inc(path=path)
+
+
 def record_kernel_dispatch(
     mode: str,
     *,

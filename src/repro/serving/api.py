@@ -46,6 +46,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core.dispatch import greedy_map
+from repro.obs.dispatch import record_rerank_call
 from repro.serving.reranker import DPPRerankConfig, _shortlist_kernel
 
 
@@ -215,6 +216,8 @@ class Reranker:
             "serving.rerank", M=req.num_candidates, k=cfg.slate_size,
             batched=req.batched,
         ):
+            record_rerank_call("sharded" if cfg.mesh is not None
+                               else "batched" if req.batched else "single")
             if cfg.mesh is not None:
                 from repro.serving.sharded_rerank import _sharded_kernel
 
@@ -373,11 +376,15 @@ def _rerank_impl(scores, feats, cfg, mask):
             f"ndim={jnp.ndim(scores)}; batched scores dispatch through "
             f"Reranker.rerank"
         )
-    V, m_top, top_i = _shortlist_kernel(scores, feats, cfg, mask)
-    res = greedy_map(cfg.greedy_spec(), V=V, mask=m_top)
-    sel, dh = res.indices, res.d_hist
-    out = jnp.where(sel >= 0, top_i[jnp.clip(sel, 0)], -1)
-    return out.astype(jnp.int32), dh
+    # the phase spans run once per call on the batched path too: vmap
+    # traces this body once, dispatching each op as it goes
+    with obs.span("serving.rerank.shortlist"):
+        V, m_top, top_i = _shortlist_kernel(scores, feats, cfg, mask)
+    with obs.span("serving.rerank.greedy"):
+        res = greedy_map(cfg.greedy_spec(), V=V, mask=m_top)
+        sel, dh = res.indices, res.d_hist
+        out = jnp.where(sel >= 0, top_i[jnp.clip(sel, 0)], -1)
+        return out.astype(jnp.int32), dh
 
 
 def _rerank_batch_impl(scores, feats, cfg, mask):
@@ -396,15 +403,17 @@ def _rerank_batch_impl(scores, feats, cfg, mask):
 def _sharded_rerank_impl(scores, feats, cfg, mask, sharded_kernel):
     from repro.core.sharded import dpp_greedy_sharded
 
-    V, smask = sharded_kernel(scores, feats, cfg, mask)
-    res = dpp_greedy_sharded(
-        V,
-        cfg.slate_size,
-        mesh=cfg.mesh,
-        axis_name=cfg.axis_name,
-        window=cfg.window,
-        eps=cfg.eps,
-        mask=smask,
-        tile_m=cfg.tile_m,
-    )
-    return res.indices.astype(jnp.int32), res.d_hist
+    with obs.span("serving.rerank.shortlist"):
+        V, smask = sharded_kernel(scores, feats, cfg, mask)
+    with obs.span("serving.rerank.greedy"):
+        res = dpp_greedy_sharded(
+            V,
+            cfg.slate_size,
+            mesh=cfg.mesh,
+            axis_name=cfg.axis_name,
+            window=cfg.window,
+            eps=cfg.eps,
+            mask=smask,
+            tile_m=cfg.tile_m,
+        )
+        return res.indices.astype(jnp.int32), res.d_hist

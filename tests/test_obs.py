@@ -234,6 +234,129 @@ def test_rerank_emits_dispatch_and_eval_counters(fresh_obs):
 
 
 # ---------------------------------------------------------------------------
+# Front door: Reranker.rerank's phase spans and call counter
+# ---------------------------------------------------------------------------
+
+PHASES = ["serving.rerank.shortlist", "serving.rerank.greedy"]
+CALLS = 2
+
+
+def _phase_request(path):
+    """A request down ``path``: ``single`` scores (M,), ``batched``
+    (B, M), ``masked`` (B, M) with a per-user mask, ``sharded`` (M,) on
+    every device of a mesh."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.serving import DPPRerankConfig, Reranker, RerankRequest
+
+    rng = np.random.default_rng(3)
+    M, D, B = 64, 8, 3
+    feats = rng.normal(size=(M, D)).astype(np.float32)
+    batched = path in ("batched", "masked")
+    scores = rng.uniform(0.1, 1.0, size=(B, M) if batched else M)
+    mask = rng.uniform(size=(B, M)) > 0.25 if path == "masked" else None
+    mesh = None
+    if path == "sharded":
+        mesh = jax.make_mesh((jax.device_count(),), ("data",),
+                             axis_types=(AxisType.Auto,))
+    rr = Reranker(DPPRerankConfig(slate_size=5, shortlist=32, alpha=3.0,
+                                  mesh=mesh))
+    req = RerankRequest(
+        scores=jnp.asarray(scores, jnp.float32), feats=jnp.asarray(feats),
+        mask=None if mask is None else jnp.asarray(mask))
+    return rr, req
+
+
+def _drive(path):
+    """``CALLS`` rerank calls down ``path`` in a fresh obs session: the
+    front door's spans as ``[name, start_us, end_us]`` and the series of
+    ``serving_rerank_calls_total``."""
+    rr, req = _phase_request(path)
+    obs.disable()
+    s = obs.enable(ObsConfig(enabled=True))
+    try:
+        for _ in range(CALLS):
+            np.asarray(rr.rerank(req)[0])
+        spans = [[sp["name"], sp["start_us"], sp["start_us"] + sp["dur_us"]]
+                 for sp in s.tracer.finished()
+                 if sp["name"].startswith("serving.rerank")]
+        calls = s.registry.snapshot()["counters"].get(
+            "serving_rerank_calls_total", {})
+        return {"spans": spans, "calls": calls}
+    finally:
+        obs.disable()
+
+
+def _drive_on_four_devices(path):
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=4")
+        import json
+        import jax
+        assert jax.device_count() == 4, jax.devices()
+        from tests.test_obs import _drive
+        print(json.dumps(_drive({path!r})))
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600, env=env, cwd=repo)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("path", ["single", "batched", "masked", "sharded"])
+def test_rerank_records_phase_spans_and_counts_calls(path):
+    """Each call records ``serving.rerank`` holding exactly its two
+    phases, shortlist then greedy, and counts itself once under its
+    path; the sharded path on a mesh of 4 devices."""
+    out = (_drive_on_four_devices(path) if path == "sharded"
+           else _drive(path))
+    spans = sorted(out["spans"], key=lambda sp: sp[1])
+    outer = [sp for sp in spans if sp[0] == "serving.rerank"]
+    assert len(outer) == CALLS and len(spans) == 3 * CALLS
+    for _, start, end in outer:
+        inner = [sp[0] for sp in spans if sp[0] != "serving.rerank"
+                 and start <= sp[1] and sp[2] <= end]
+        assert inner == PHASES
+    label = {"masked": "batched"}.get(path, path)
+    assert out["calls"] == {f"path={label}": CALLS}
+
+
+@pytest.mark.parametrize("path", ["single", "batched", "masked"])
+def test_rerank_with_obs_off_records_nothing(no_obs, monkeypatch, path):
+    """Off, every span the front door asks for is the shared no-op (no
+    ``Span`` is built) and no registry exists to count into."""
+    from repro.obs import trace
+
+    rr, req = _phase_request(path)
+    handed = []
+    real_span = obs.span
+
+    def spy(name, **attrs):
+        handed.append(real_span(name, **attrs))
+        return handed[-1]
+
+    def no_span(*a, **k):
+        raise AssertionError("a Span was allocated with obs off")
+
+    monkeypatch.setattr(obs, "span", spy)
+    monkeypatch.setattr(trace.Span, "__init__", no_span)
+    for _ in range(CALLS):
+        np.asarray(rr.rerank(req)[0])
+    assert len(handed) == 3 * CALLS
+    assert all(sp is NULL_SPAN for sp in handed)
+    assert obs.registry() is None and obs.tracer() is None
+
+
+# ---------------------------------------------------------------------------
 # Router integration: spans, stats view, hook guard, recompile ledger
 # ---------------------------------------------------------------------------
 

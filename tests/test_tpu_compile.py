@@ -6,6 +6,9 @@ working set over the scoped-VMEM limit.  These tests compile each
 ``dpp_greedy`` kernel family at the served widths for one chip of a
 described ``v5e:2x2`` topology — nothing runs, no chip is needed — and
 the geometries ``TilePolicy`` picks at the edge of its VMEM budget.
+Each compile also checks that the program names its kernel by the
+family's ``pallas_call`` name (``dpp_{resident,step,chunk}_{exact,
+windowed}``), which the benchmark's trace readers match.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU compiler library, and every
@@ -15,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,18 +82,37 @@ def _compile(fn, *operands):
     return jax.jit(fn).lower(*operands).compile()
 
 
+def _kernel_names(compiled):
+    """The instruction names of the Pallas kernels in a compiled
+    program, without their numbers (``%dpp_step_exact.7 = ...``)."""
+    return set(re.findall(
+        r'%([\w-]+)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text(),
+    ))
+
+
+def _family(kind, windowed):
+    return f"dpp_{kind}_{'windowed' if windowed else 'exact'}"
+
+
 def _resident(shape, k, window, Bn, Dn, Mn):
     fn = functools.partial(
         dpp_greedy_kernel, k=k, window=window, interpret=False
     )
-    return _compile(fn, shape((Bn, Dn, Mn)), shape((Bn, Mn)))
+    compiled = _compile(fn, shape((Bn, Dn, Mn)), shape((Bn, Mn)))
+    windowed = window is not None and window < k
+    assert _kernel_names(compiled) == {_family("resident", windowed)}
+    return compiled
 
 
 def _tiled(shape, k, window, tile, Dn, Mn):
     fn = functools.partial(
         dpp_greedy_tiled, k=k, window=window, tile_m=tile, interpret=False
     )
-    return _compile(fn, shape((1, Dn, Mn)), shape((1, Mn)))
+    compiled = _compile(fn, shape((1, Dn, Mn)), shape((1, Mn)))
+    windowed = window is not None and window < k
+    assert _kernel_names(compiled) == {_family("step", windowed)}
+    return compiled
 
 
 def _chunk(shape, R, windowed, Dn, tile):
@@ -105,9 +128,11 @@ def _chunk(shape, R, windowed, Dn, tile):
             fused_chunk_exact, t0=0, chunk=CHUNK, eps=1e-3, tile_m=tile,
             interpret=False,
         )
-    return _compile(
+    compiled = _compile(
         lambda *a: fn(*a, stopped=jnp.zeros((1,), bool)), *ops
     )
+    assert _kernel_names(compiled) == {_family("chunk", windowed)}
+    return compiled
 
 
 def _policy_tile(Dn, R, windowed, chunked=False):
