@@ -148,6 +148,19 @@ def record_rerank_call(path: str) -> None:
     ).inc(path=path)
 
 
+def record_shortlist(path: str) -> None:
+    """One shortlist build down ``path``: ``whole_pool`` (the shortlist
+    covers the pool, so V is built in id order with no sort or gather)
+    or ``top_k`` (sort, then gather the shortlisted rows).  Counted in
+    Python, so under an outer ``jit`` it counts traces, not calls."""
+    reg = _obs.registry()
+    if reg is None:
+        return
+    reg.counter(
+        "serving_shortlist_total", "shortlist builds by path"
+    ).inc(path=path)
+
+
 def record_kernel_dispatch(
     mode: str,
     *,

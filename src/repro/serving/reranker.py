@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from repro.core.dispatch import GreedySpec
 from repro.core.kernel_matrix import map_relevance
 from repro.obs import ObsConfig
+from repro.obs.dispatch import record_shortlist
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,17 +159,32 @@ def _shortlist_kernel(scores, feats, cfg, mask):
     whole-slate ``Reranker.rerank`` and the chunk-emitting
     ``Reranker.stream`` so the two paths diversify the identical V.
     Returns
-    ``(V (D, C), shortlist mask or None, top_i (C,) global ids)``."""
-    C = min(cfg.shortlist, scores.shape[0])
-    s = scores if mask is None else jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-    top_s, top_i = jax.lax.top_k(s, C)
-    f = feats[top_i]  # (C, D)
-    rel = map_relevance(top_s.astype(jnp.float32), cfg.alpha)
-    m_top = None if mask is None else mask[top_i]
+    ``(V (D, C), shortlist mask or None, top_i (C,) global ids)``.
+
+    A shortlist that covers the whole pool (C == M) ranks and permutes
+    nothing: V is built over the pool in id order, as the sharded path
+    builds it, and ``top_i`` is the identity.  The greedy's picks do not
+    depend on column order, so the slate is the one the sorted shortlist
+    gives, except that an exact tie between two gains goes to the lower
+    global id (the sharded path's rule) instead of the higher score."""
+    M = scores.shape[0]
+    C = min(cfg.shortlist, M)
+    if C == M:
+        record_shortlist("whole_pool")
+        s, f, m_top = scores, feats, mask
+        top_i = jnp.arange(M, dtype=jnp.int32)
+    else:
+        record_shortlist("top_k")
+        s = scores if mask is None else jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
+        s, top_i = jax.lax.top_k(s, C)
+        f = feats[top_i]  # (C, D)
+        m_top = None if mask is None else mask[top_i]
+    rel = map_relevance(s.astype(jnp.float32), cfg.alpha)
     if m_top is not None:
-        # the sentinel score only exists to rank masked items last; keep
-        # it out of the kernel (alpha < 1 maps it to inf) — masked
-        # columns are zeroed and excluded from selection by the mask
+        # neither the sentinel score (which only ranks masked items
+        # last; alpha < 1 maps it to inf) nor a masked item's own score
+        # may reach the kernel — masked columns are zeroed and excluded
+        # from selection by the mask
         rel = jnp.where(m_top, rel, 0.0)
     V = (f * rel[:, None]).T  # (D, C)
     return V, m_top, top_i

@@ -32,9 +32,11 @@ The returned indices are global ids into the original M, identical to
 what the single-device ``Reranker.rerank`` (or a ``vmap`` of it) would
 select on the same inputs (same argmax sequence; see ``repro.core.sharded``) —
 up to argmax ties between *exactly* float-equal marginal gains of
-distinct items, where the single-device path breaks by score-sorted
-shortlist position and this path by lowest global index (measure-zero
-on continuous scores).
+distinct items, where a single-device shortlist narrower than the pool
+breaks by score-sorted shortlist position and this path by lowest
+global index (measure-zero on continuous scores).  A single-device
+whole-pool shortlist keeps id order too, so it breaks ties as this
+path does.
 """
 from __future__ import annotations
 

@@ -219,3 +219,114 @@ def test_legacy_shims_are_removed():
     # the internal builders the session API dispatches through remain
     assert hasattr(reranker, "_shortlist_kernel")
     assert hasattr(sharded, "_sharded_kernel")
+
+
+# ---------------------------------------------------------------------------
+# Whole-pool shortlist: V in id order, no sort, no row gather
+# ---------------------------------------------------------------------------
+
+WHOLE_M, WHOLE_B = 40, 3
+
+
+def _sorted_reference(s, f, cfg, mask):
+    """``greedy_map`` over the pool sorted by score and gathered (the
+    shortlist as a top-k builds it), ids mapped back through the sort."""
+    from repro.core import greedy_map, map_relevance
+
+    s, f = np.asarray(s), np.asarray(f)
+    key = s if mask is None else np.where(mask, s, -np.inf)
+    order = np.argsort(-key, kind="stable")
+    rel = np.asarray(map_relevance(jnp.asarray(s[order]), cfg.alpha))
+    m = None if mask is None else np.asarray(mask)[order]
+    if m is not None:
+        rel = np.where(m, rel, 0.0).astype(np.float32)
+    V = jnp.asarray((f[order] * rel[:, None]).T)
+    res = greedy_map(cfg.greedy_spec(), V=V,
+                     mask=None if m is None else jnp.asarray(m))
+    sel = np.asarray(res.indices)
+    ids = np.where(sel >= 0, order[np.clip(sel, 0, None)], -1)
+    return ids, np.asarray(res.d_hist)
+
+
+def _whole_pool_case(case):
+    """``(served, reference)``, each ``(ids, d_hist)``, for one way of
+    reaching the shortlist with ``shortlist >= M``."""
+    from repro.serving import RouterConfig
+
+    rng = np.random.default_rng(14)
+    batched = case.startswith("batched") or case == "masked-batched"
+    s, f = _problem(WHOLE_M, seed=14, batch=WHOLE_B if batched else None)
+    if case == "batched-shared":
+        f = f[0]
+    mask = None
+    if case.startswith("masked"):
+        mask = jnp.asarray(rng.uniform(size=s.shape) > 0.25)
+    cfg = DPPRerankConfig(slate_size=6, shortlist=64, alpha=3.0,
+                          chunk_size=4, use_kernel=case == "single-kernel",
+                          window=3 if case == "session" else None)
+    rr = Reranker(cfg, router_config=RouterConfig(
+        slots=2, chunk_size=4, max_candidates=64))
+    req = RerankRequest(scores=s, feats=f, mask=mask)
+    if case == "stream":
+        parts = list(rr.stream(req))
+        served = tuple(np.concatenate([np.asarray(p[i]) for p in parts])
+                       for i in (0, 1))
+    elif case == "router":
+        served = rr.submit(req).result()
+    elif case == "session":
+        served = rr.session(req).next_chunk(4)
+    else:
+        served = tuple(np.asarray(x) for x in rr.rerank(req))
+    if not batched:
+        ref = _sorted_reference(s, f, cfg, mask)
+    else:
+        users = [_sorted_reference(
+            s[b], f if f.ndim == 2 else f[b], cfg,
+            None if mask is None else mask[b]) for b in range(WHOLE_B)]
+        ref = tuple(np.stack([u[i] for u in users]) for i in (0, 1))
+    if case == "session":
+        ref = tuple(r[:4] for r in ref)
+    return served, ref
+
+
+@pytest.mark.parametrize("case", [
+    "single", "single-kernel", "batched-shared", "batched-per-user",
+    "masked", "masked-batched", "stream", "router", "session",
+])
+def test_whole_pool_shortlist_matches_sorted_gather(case):
+    """With ``shortlist >= M`` V is built in id order, not sorted and
+    gathered; on continuous scores (no exact ties) every path serves the
+    slate of the sorted, gathered V, ids mapped back through the sort."""
+    (ids, dh), (ref_ids, ref_dh) = _whole_pool_case(case)
+    np.testing.assert_array_equal(np.asarray(ids), ref_ids)
+    np.testing.assert_allclose(np.asarray(dh), ref_dh, rtol=1e-5)
+
+
+def _primitives(jaxpr):
+    """Every primitive name in ``jaxpr``, nested jaxprs included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_whole_pool_shortlist_has_no_sort_or_gather(masked):
+    """C == M lowers with no ``top_k`` and no ``gather``; C < M (the
+    feed path) still sorts and gathers, unchanged."""
+    from repro.serving.reranker import _shortlist_kernel
+
+    s, f = _problem(WHOLE_M, seed=15)
+    mask = jnp.asarray(np.arange(WHOLE_M) % 3 != 0) if masked else None
+    for shortlist, whole in ((WHOLE_M, True), (64, True), (16, False)):
+        cfg = DPPRerankConfig(slate_size=4, shortlist=shortlist, alpha=3.0)
+        jaxpr = jax.make_jaxpr(
+            lambda s, f, m: _shortlist_kernel(s, f, cfg, m)
+        )(s, f, mask).jaxpr
+        prims = _primitives(jaxpr)
+        assert ("top_k" in prims) is not whole, (shortlist, prims)
+        assert ("gather" in prims) is not whole, (shortlist, prims)

@@ -36,7 +36,11 @@ from repro.obs import (
     SpanTracer,
     validate_chrome_trace,
 )
-from repro.obs.dispatch import record_chunk, record_kernel_dispatch
+from repro.obs.dispatch import (
+    record_chunk,
+    record_kernel_dispatch,
+    record_shortlist,
+)
 
 from tests.test_router import make_request, session
 
@@ -203,6 +207,9 @@ def test_record_hooks_are_noops_without_a_session(no_obs):
     record_kernel_dispatch("tiled", D=8, M=256, state_rows=8,
                            windowed=False, tile_m=128, vmem_bytes=1 << 20)
     record_chunk("jnp", B=2, chunk=4, M=64)  # must not raise
+    record_shortlist("whole_pool")
+    record_shortlist("top_k")
+    assert obs.registry() is None
 
 
 def test_record_kernel_dispatch_counts_modes(fresh_obs):
@@ -354,6 +361,33 @@ def test_rerank_with_obs_off_records_nothing(no_obs, monkeypatch, path):
     assert len(handed) == 3 * CALLS
     assert all(sp is NULL_SPAN for sp in handed)
     assert obs.registry() is None and obs.tracer() is None
+
+
+def test_shortlist_counter_counts_each_path(fresh_obs):
+    """``serving_shortlist_total`` counts one ``whole_pool`` per build
+    that covers the pool and one ``top_k`` per build that sorts."""
+    from repro.serving import DPPRerankConfig, Reranker, RerankRequest
+
+    rng = np.random.default_rng(5)
+    M = 48
+    feats = jnp.asarray(rng.normal(size=(M, 8)), jnp.float32)
+    scores = jnp.asarray(rng.uniform(0.1, 1.0, size=M), jnp.float32)
+    req = RerankRequest(scores=scores, feats=feats)
+    whole = Reranker(DPPRerankConfig(slate_size=4, shortlist=M, alpha=3.0))
+    top = Reranker(DPPRerankConfig(slate_size=4, shortlist=16, alpha=3.0))
+    for _ in range(3):
+        np.asarray(whole.rerank(req)[0])
+    for _ in range(2):
+        np.asarray(top.rerank(req)[0])
+    # a batched call builds its users' shortlists in one vmapped trace
+    batch = RerankRequest(scores=jnp.stack([scores, scores[::-1]]),
+                          feats=feats)
+    np.asarray(whole.rerank(batch)[0])
+    snap = fresh_obs.registry.snapshot()["counters"]
+    assert snap["serving_shortlist_total"] == {
+        "path=whole_pool": 4, "path=top_k": 2}
+    assert snap["serving_rerank_calls_total"] == {
+        "path=single": 5, "path=batched": 1}
 
 
 # ---------------------------------------------------------------------------
