@@ -28,7 +28,8 @@ Dispatch rules:
   ``TilePolicy`` keeps the whole-working-set resident kernels while
   they fit VMEM and tiles past that); on the sharded backend each
   device's local per-step update reuses the same tiled kernel on its
-  (D, M/P) shard.
+  (D, M/P) shard, and unset, the tile comes from the shard's shape on
+  a TPU (``repro.core.sharded``).
 * ``spec.chunk_size`` — greedy steps per resumable chunk.  On the
   pallas backend ``greedy_map`` then runs the slate as fused multi-step
   chunk kernels (one pallas_call — one HBM C/d2 round-trip — per
@@ -126,8 +127,9 @@ class GreedySpec:
                     'tile_m="auto" consults the measured autotune cache, '
                     "which only the single-device Pallas dispatch does "
                     "(backend='pallas') — the jnp backend ignores tile_m "
-                    "entirely and the sharded per-device update needs an "
-                    "explicit LANE multiple"
+                    "entirely and the sharded per-device update takes an "
+                    "explicit LANE multiple or, unset, works its tile out "
+                    "from the shard's shape"
                 )
             if self.backend == "jnp" or (
                 self.backend == "auto" and self.mesh is None

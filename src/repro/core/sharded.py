@@ -22,11 +22,13 @@ collective instead of ``B`` sequential ones.
 Per greedy step, inside one ``shard_map``:
 
 1. **local update** — each device updates its candidate shard
-   (O(D M / P) exact, O(w M / P) windowed); with ``tile_m=`` set it
-   runs through the same tiled, double-buffered Pallas pass as the
-   single-device streaming kernel (``repro.kernels.dpp_greedy.tiled``),
-   so M/P shards past the VMEM budget stream in tiles instead of
-   lowering through unfused jnp;
+   (O(D M / P) exact, O(w M / P) windowed) through the same tiled,
+   double-buffered Pallas pass as the single-device streaming kernel
+   (``repro.kernels.dpp_greedy.tiled``).  The per-device tile is an
+   explicit ``tile_m=``, or else worked out from the platform and the
+   shard's shape as on one chip (``_mesh_tile``); where Pallas runs
+   interpreted (off a TPU) the update lowers through jnp step bodies
+   whose products of V run at ``Precision.HIGHEST``;
 2. **global argmax** — an all-gather allreduce of per-device
    ``(d2_max, global_index)`` pairs (P tiny pairs), first-occurrence
    tie-breaking identical to a single-device ``argmax``;
@@ -60,6 +62,23 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.greedy_chol import NEG_INF, GreedyResult
+from repro.kernels.dpp_greedy.tiling import (
+    LANE,
+    TilePolicy,
+    round_up,
+    tile_vmem_bytes,
+    validate_tile_m,
+)
+from repro.kernels.platform import resolve_interpret
+from repro.obs.dispatch import (
+    record_kernel_dispatch,
+    record_sharded_merges,
+    record_tile_resolution,
+)
+
+# every product of V in the jnp step bodies: a TPU's default float32
+# matmul rounds its operands through bfloat16
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _mesh_axis_size(mesh, axis_name: str) -> int:
@@ -141,7 +160,8 @@ def _exact_step_fn(
         z = _bcast_from_owner((Vl[:, jl], C[jl, :]), owner, axis_name)
         vj, cj = z[:D], z[D:]
         # local shard of the update (eqs. 16-18): e = (L_j - c c_j) / d_j
-        e = (vj @ Vl - C @ cj) / dj
+        e = (jnp.matmul(vj, Vl, precision=_HI)
+             - jnp.matmul(C, cj, precision=_HI)) / dj
         e = jnp.where(stopped, jnp.zeros_like(e), e)
         C = C.at[:, t].set(e)
         d2_next = d2 - e * e
@@ -359,7 +379,8 @@ def _windowed_step_fn(
         )
         vj, cj, d2j = z[:D], z[D : D + w], z[D + w]
         djp = jnp.sqrt(jnp.maximum(d2j, eps2))
-        e = (vj @ Vl - cj @ C) / djp
+        e = (jnp.matmul(vj, Vl, precision=_HI)
+             - jnp.matmul(cj, C, precision=_HI)) / djp
         pos = jnp.minimum(t, w - 1)
         C_next = jax.lax.dynamic_update_slice(C, e[None], (pos, 0))
         d2_next = d2 - e * e
@@ -533,18 +554,49 @@ def _stream_chunk_fn(
     )
 
 
-def _record_dispatch(V, M, k, window, tile_m):
-    """Count the per-device update path: the tiled Pallas pass with
-    ``tile_m`` set, the jnp step bodies without."""
-    from repro.kernels.platform import resolve_interpret
-    from repro.obs.dispatch import record_kernel_dispatch
+def _mesh_tile(tile_m, D, Mloc, state_rows, windowed):
+    """The per-device tile of the step bodies on a shard of ``Mloc``
+    candidates, and how it was chosen: ``(tile or None, source)``; a
+    None tile runs the jnp step bodies.
 
+    An explicit ``tile_m`` wins.  Unset, the platform and the shard's
+    shape decide, as on one chip (``TilePolicy.decide``).  Where Pallas
+    runs interpreted (off a TPU) the jnp bodies run.  Where it runs
+    compiled, the model's ``tiled`` answer gives its tile; ``resident``
+    (the shard fits on-core, and the mesh has no resident kernel) one
+    tile over the whole shard, or the widest the per-tile budget admits;
+    ``jnp`` (not even one lane-width tile fits) the jnp bodies."""
+    if tile_m is not None:
+        return tile_m, "explicit"
+    if resolve_interpret():
+        return None, None
+    policy = TilePolicy()
+    mode, tm = policy.decide(D, Mloc, state_rows, windowed)
+    if mode == "resident":
+        tm = min(round_up(Mloc, LANE),
+                 policy.auto_tile(D, state_rows, windowed))
+    return tm or None, "model"
+
+
+def padded_candidates(M: int, nshards: int, tile_m: Optional[int]) -> int:
+    """The candidate axis the mesh runs: ``M`` zero-padded up to a
+    multiple of ``nshards`` times the per-device tile."""
+    quantum = nshards * (tile_m or 1)
+    return -(-M // quantum) * quantum
+
+
+def _record_dispatch(D, M, state_rows, windowed, tile_m, source):
+    """Count the per-device update path that runs (the tiled Pallas
+    pass, or the jnp step bodies) and how its tile was chosen."""
     record_kernel_dispatch(
-        "tiled" if tile_m is not None else "jnp", D=V.shape[-2], M=M,
-        state_rows=k if window is None else min(window, k),
-        windowed=window is not None, tile_m=tile_m,
+        "tiled" if tile_m is not None else "jnp", D=D, M=M,
+        state_rows=state_rows, windowed=windowed, tile_m=tile_m,
         interpret=resolve_interpret(),
+        vmem_bytes=(None if tile_m is None
+                    else tile_vmem_bytes(D, tile_m, state_rows, windowed)),
     )
+    if source is not None:
+        record_tile_resolution(source)
 
 
 def _stream_pad(V, Mp):
@@ -575,7 +627,6 @@ def dpp_greedy_sharded_stream_init(
     rows, windowed ``(w, M)`` ring).
     """
     from repro.core.streaming import GreedyState
-    from repro.kernels.dpp_greedy.tiling import validate_tile_m
 
     if V.ndim not in (2, 3):
         raise ValueError(
@@ -597,9 +648,11 @@ def dpp_greedy_sharded_stream_init(
     elif mask.shape != mask_shape:
         mask = jnp.broadcast_to(mask, mask_shape)
     windowed = window is not None and window < k
-    _record_dispatch(V, M, k, window if windowed else None, tile_m)
-    quantum = nshards * (tile_m or 1)
-    Mp = -(-M // quantum) * quantum
+    R = min(window, k) if windowed else k
+    tile_m, source = _mesh_tile(tile_m, V.shape[-2], -(-M // nshards), R,
+                                windowed)
+    _record_dispatch(V.shape[-2], M, R, windowed, tile_m, source)
+    Mp = padded_candidates(M, nshards, tile_m)
     V = _stream_pad(V, Mp)
     if Mp != M:
         mask = jnp.pad(
@@ -610,9 +663,8 @@ def dpp_greedy_sharded_stream_init(
     dtype = V.dtype
     lead = (B,) if batched else ()
     if windowed:
-        w = min(window, k)
-        C = jnp.zeros(lead + (w, Mp), dtype)
-        win = jnp.full(lead + (w,), -1, jnp.int32)
+        C = jnp.zeros(lead + (R, Mp), dtype)
+        win = jnp.full(lead + (R,), -1, jnp.int32)
     else:
         shape = (k, Mp) if tile_m is not None else (Mp, k)
         C = jnp.zeros(lead + shape, dtype)
@@ -647,9 +699,17 @@ def dpp_greedy_sharded_stream_chunk(
     ``t`` per lane either way.
     """
     batched = V.ndim == 3
-    V = _stream_pad(V, state.d2.shape[-1])
+    Mp = state.d2.shape[-1]
+    V = _stream_pad(V, Mp)
     windowed = state.win.shape[-1] > 0
     w = state.win.shape[-1] if windowed else None
+    # the state's rows: the ring's w, or k in either exact layout (rows
+    # (k, Mp) on the tiled pass, columns (Mp, k) on the jnp bodies)
+    R = w if windowed else (
+        state.C.shape[-2] if state.C.shape[-1] == Mp else state.C.shape[-1])
+    nshards = _mesh_axis_size(mesh, axis_name)
+    tile_m, _ = _mesh_tile(tile_m, V.shape[-2], Mp // nshards, R, windowed)
+    record_sharded_merges(chunk, windowed=windowed)
     t_batched = batched and jnp.ndim(state.t) == 1
     fn = _stream_chunk_fn(
         mesh, axis_name, chunk, w, float(eps), batched, tile_m, t_batched,
@@ -689,8 +749,8 @@ def dpp_greedy_sharded(
     their ``_batch`` vmap variants, on the gathered ``V``; but each
     device's compute only touches its ``(D, M/P)`` (or ``(B, D, M/P)``)
     shard where ``P = mesh.shape[axis_name]``.  ``M`` is zero-padded
-    (mask False) up to a multiple of ``P``; padding can never be
-    selected.
+    (mask False) up to a multiple of ``P`` times the tile; padding can
+    never be selected.
 
     The index-for-index match holds while marginal gains sit above the
     float32 cancellation-noise floor; past the kernel's numerical rank
@@ -698,13 +758,14 @@ def dpp_greedy_sharded(
     on any backend — set ``eps`` to stop there (paper eq. 20), as the
     single-device paths also should.
 
-    ``tile_m`` routes each device's local per-step update through the
-    tiled streaming Pallas pass (``repro.kernels.dpp_greedy.tiled``) in
-    ``tile_m``-column blocks — the same kernel the single-device tiled
-    path runs — so shards whose (D, M/P) working set exceeds the VMEM
-    budget stream through it instead of lowering through unfused jnp.
-    ``M`` is padded up to a multiple of ``P * tile_m``; those Pallas
-    calls run compiled on a TPU mesh and interpreted on a CPU one.
+    Each device's local per-step update runs the tiled streaming
+    Pallas pass (``repro.kernels.dpp_greedy.tiled``), the kernel the
+    single-device tiled path runs, in tile-column blocks.  ``tile_m``
+    sets the tile; unset, it is worked out from the platform and the
+    shard's shape (``_mesh_tile``): on a TPU the VMEM model's tile, off
+    one the jnp step bodies (the Pallas interpreter would only be
+    slower).  ``M`` is padded up to a multiple of ``P * tile``, so a
+    wide tile adds padded columns.
     """
     if V.ndim not in (2, 3):
         raise ValueError(
@@ -715,8 +776,6 @@ def dpp_greedy_sharded(
         raise ValueError(f"k must be >= 1, got {k}")
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    from repro.kernels.dpp_greedy.tiling import validate_tile_m
-
     validate_tile_m(tile_m)
     batched = V.ndim == 3
     nshards = _mesh_axis_size(mesh, axis_name)
@@ -726,14 +785,18 @@ def dpp_greedy_sharded(
         mask = jnp.ones(mask_shape, bool)
     elif mask.shape != mask_shape:
         mask = jnp.broadcast_to(mask, mask_shape)
-    quantum = nshards * (tile_m or 1)
-    Mp = -(-M // quantum) * quantum
+    window_eff = window if (window is not None and window < k) else None
+    R = k if window_eff is None else window_eff
+    tile_m, source = _mesh_tile(tile_m, V.shape[-2], -(-M // nshards), R,
+                                window_eff is not None)
+    Mp = padded_candidates(M, nshards, tile_m)
     if Mp != M:
         pad = [(0, 0)] * (V.ndim - 1) + [(0, Mp - M)]
         V = jnp.pad(V, pad)
         mask = jnp.pad(mask, pad[1:], constant_values=False)
-    window_eff = window if (window is not None and window < k) else None
-    _record_dispatch(V, M, k, window_eff, tile_m)
+    _record_dispatch(V.shape[-2], M, R, window_eff is not None, tile_m,
+                     source)
+    record_sharded_merges(k, windowed=window_eff is not None)
     fn = _greedy_fn(
         mesh, axis_name, k, window_eff, float(eps), batched, tile_m,
     )

@@ -13,7 +13,12 @@ devices, synthesizes scores/features for M candidates,
 and runs the full sharded pipeline end to end: sharded top-k shortlist
 mask -> candidate-sharded greedy MAP (exact or sliding-window).  Each
 device only ever holds a (D, M/P) column shard of the scaled feature
-matrix plus its slice of the greedy state.
+matrix plus its slice of the greedy state.  On a TPU each device's
+per-step update runs the tiled Pallas step kernel, its tile worked out
+from the shard's shape; on the CPU it runs jnp step bodies.  The
+report's ``per_device`` says which path ran, read from the dispatch
+counters: the modes and how many ran interpreted, the tile and how it
+was chosen, and the padded candidate axis the tile implies.
 
 ``--batch B`` serves a request batch of B users through the same mesh
 in one ``Reranker.rerank`` call (per-user scores over shared features):
@@ -33,7 +38,10 @@ concatenated chunks equal the whole-slate slate index for index.
 ``--check`` additionally runs the single-device ``rerank`` (vmapped
 when ``--batch > 1``) on the same inputs and asserts the slates are
 identical (the sharded path's bit-exactness guarantee); keep M modest
-when checking.
+when checking on the CPU.  The single-device run takes the Pallas path
+(``use_kernel``), whose float32 arithmetic the mesh's kernel shares:
+the single-device jnp path rounds its products through bfloat16 at a
+TPU's default precision.
 """
 from __future__ import annotations
 
@@ -80,7 +88,10 @@ def main(argv=None):
     import numpy as np
     from jax.sharding import AxisType
 
+    from repro import obs
+    from repro.core.sharded import padded_candidates
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.obs import ObsConfig
     from repro.serving import DPPRerankConfig, Reranker, RerankRequest
 
     enable_compile_cache()
@@ -105,7 +116,9 @@ def main(argv=None):
         eps=1e-6,
         window=args.window or None,
         mesh=mesh,
+        obs=ObsConfig(enabled=True, trace=False),
     )
+
     def serve(s, f, c):
         # one mesh call for the whole user batch; a single request drops
         # the batch axis so the (M,) fast path serves it
@@ -151,6 +164,7 @@ def main(argv=None):
             ), "streamed chunks diverged from the whole-slate slate"
             stream_stats["check"] = "ok (chunks concatenate to the slate)"
 
+    per_device = _per_device(obs.registry(), M, ndev, padded_candidates)
     slate_np = np.asarray(slate)
     n_sel = int((slate_np >= 0).sum())
     out = {
@@ -167,6 +181,7 @@ def main(argv=None):
         "steady_call_s": round(t_steady, 3),
         "us_per_step": round(t_steady / max(N, 1) * 1e6, 1),
         "us_per_user_slate": round(t_steady / max(B, 1) * 1e6, 1),
+        "per_device": per_device,
     }
     if stream_stats is not None:
         out["stream"] = stream_stats
@@ -174,7 +189,7 @@ def main(argv=None):
     if args.check:
         ref_cfg = DPPRerankConfig(
             slate_size=N, shortlist=args.shortlist or M, alpha=args.alpha,
-            eps=1e-6, window=args.window or None,
+            eps=1e-6, window=args.window or None, use_kernel=True,
         )
         ref, _ = serve(scores, feats, ref_cfg)
         assert np.array_equal(np.asarray(ref), slate_np), (
@@ -187,6 +202,33 @@ def main(argv=None):
         with open(args.metrics_out, "w") as f:
             json.dump(out, f)
     return out
+
+
+def _per_device(registry, M, ndev, padded_candidates):
+    """The per-device path the mesh calls ran, from the dispatch
+    counters: modes, interpreted dispatches, the last tile and how
+    tiles were chosen, the candidate axis that tile pads M to, and the
+    cross-chip merges."""
+    snap = registry.snapshot()
+    counters, gauges = snap["counters"], snap["gauges"]
+
+    def by(name, label):
+        out = {}
+        for key, n in counters.get(name, {}).items():
+            v = dict(kv.split("=", 1) for kv in key.split(","))[label]
+            out[v] = out.get(v, 0) + int(n)
+        return out
+
+    tile = int(next(iter(gauges.get("dpp_tile_m", {}).values()), 0)) or None
+    return {
+        "modes": by("dpp_kernel_dispatch_total", "mode"),
+        "interpreted": int(sum(
+            counters.get("dpp_kernel_interpreted_total", {}).values())),
+        "tile_m": tile,
+        "tile_source": by("dpp_tile_source_total", "source"),
+        "padded_candidates": padded_candidates(M, ndev, tile),
+        "merges": by("sharded_merges_total", "kind"),
+    }
 
 
 if __name__ == "__main__":

@@ -132,7 +132,8 @@ class CompileMonitor:
 
 
 # ---------------------------------------------------------------------------
-# Dispatch recording (called by core/dispatch, core/streaming, kernel ops)
+# Dispatch recording (called by core/dispatch, core/streaming, core/sharded,
+# kernel ops)
 # ---------------------------------------------------------------------------
 
 
@@ -199,10 +200,30 @@ def record_kernel_dispatch(
         ).set(vmem_bytes)
 
 
+def record_sharded_merges(steps: int, *, windowed: bool) -> None:
+    """The cross-chip merges of one mesh dispatch of ``steps`` greedy
+    steps: per step one ``argmax`` (the all-gather of every shard's
+    best), one ``winner`` (the psum that broadcasts the winner's
+    column) and, windowed, one ``window`` (the psum that gathers the
+    window factor).  A merge moves every user of a batch at once, so a
+    batch counts once."""
+    reg = _obs.registry()
+    if reg is None:
+        return
+    c = reg.counter(
+        "sharded_merges_total", "cross-chip merges of the mesh greedy by kind"
+    )
+    kinds = ("argmax", "winner", "window") if windowed else ("argmax", "winner")
+    for kind in kinds:
+        c.inc(steps, kind=kind)
+
+
 def record_tile_resolution(source: str) -> None:
     """Which source won one tile_m precedence resolution in ``ops.py``
     (``env`` > ``explicit`` int > requested ``auto`` > ``model``;
-    ``policy`` = an explicit TilePolicy object bypassed the ladder)."""
+    ``policy`` = an explicit TilePolicy object bypassed the ladder), or
+    chose a mesh's per-device tile in ``core/sharded.py`` (``explicit``
+    or ``model``)."""
     reg = _obs.registry()
     if reg is None:
         return

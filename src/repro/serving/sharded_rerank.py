@@ -11,9 +11,10 @@ candidate axis M is sharded over ``cfg.mesh``'s ``cfg.axis_name``
 * greedy MAP runs through ``repro.core.sharded.dpp_greedy_sharded``:
   each device computes on only its (D, M/P) column shard of the scaled
   feature matrix ``V`` and its slice of the Cholesky ring state, with
-  one tiny argmax-allreduce + winner-broadcast per step; with
-  ``cfg.tile_m`` set the per-device update streams through the tiled
-  Pallas pass (``repro.kernels.dpp_greedy.tiled``), so even M/P shards
+  one tiny argmax-allreduce + winner-broadcast per step; on a TPU the
+  per-device update streams through the tiled Pallas pass
+  (``repro.kernels.dpp_greedy.tiled``) at ``cfg.tile_m``, or at the
+  tile the shard's shape gives when it is unset, so even M/P shards
   past the VMEM budget stay on the kernel path.
 
 A request batch of B users shares the mesh: ``scores (B, M)`` (features

@@ -39,6 +39,7 @@ from repro.obs import (
 from repro.obs.dispatch import (
     record_chunk,
     record_kernel_dispatch,
+    record_sharded_merges,
     record_shortlist,
 )
 
@@ -209,6 +210,7 @@ def test_record_hooks_are_noops_without_a_session(no_obs):
     record_chunk("jnp", B=2, chunk=4, M=64)  # must not raise
     record_shortlist("whole_pool")
     record_shortlist("top_k")
+    record_sharded_merges(20, windowed=True)
     assert obs.registry() is None
 
 
@@ -388,6 +390,48 @@ def test_shortlist_counter_counts_each_path(fresh_obs):
         "path=whole_pool": 4, "path=top_k": 2}
     assert snap["serving_rerank_calls_total"] == {
         "path=single": 5, "path=batched": 1}
+
+
+def test_sharded_merges_count_k_per_kind_per_mesh_call(fresh_obs):
+    """``sharded_merges_total`` counts k merges of each kind per mesh
+    call (a batch of users once), ``chunk`` per streamed chunk,
+    ``window`` only windowed, and nothing off the mesh."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.serving import DPPRerankConfig, Reranker, RerankRequest
+
+    rng = np.random.default_rng(9)
+    M, k = 48, 6
+    feats = jnp.asarray(rng.normal(size=(M, 8)), jnp.float32)
+    scores = jnp.asarray(rng.uniform(0.1, 1.0, size=M), jnp.float32)
+    req = RerankRequest(scores=scores, feats=feats)
+    batch = RerankRequest(scores=jnp.stack([scores, scores[::-1]]),
+                          feats=feats)
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:1])
+
+    def merges():
+        snap = fresh_obs.registry.snapshot()["counters"]
+        return snap.get("sharded_merges_total", {})
+
+    one_chip = Reranker(DPPRerankConfig(slate_size=k, shortlist=M))
+    np.asarray(one_chip.rerank(req)[0])
+    np.asarray(one_chip.rerank(batch)[0])
+    assert merges() == {}
+    exact = Reranker(DPPRerankConfig(slate_size=k, shortlist=M, mesh=mesh,
+                                     chunk_size=4))
+    for r in (req, req, batch):
+        np.asarray(exact.rerank(r)[0])
+    assert merges() == {"kind=argmax": 3 * k, "kind=winner": 3 * k}
+    for c, _ in exact.stream(req):  # chunks of 4 and 2
+        np.asarray(c)
+    assert merges() == {"kind=argmax": 4 * k, "kind=winner": 4 * k}
+    windowed = Reranker(DPPRerankConfig(slate_size=k, shortlist=M,
+                                        mesh=mesh, window=2))
+    np.asarray(windowed.rerank(req)[0])
+    assert merges() == {"kind=argmax": 5 * k, "kind=winner": 5 * k,
+                        "kind=window": k}
 
 
 # ---------------------------------------------------------------------------

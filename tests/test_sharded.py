@@ -13,6 +13,7 @@ sharded greedy — exact and windowed, padded and masked — selects the
 bit-identical slate and d_hist as the single-device low-rank path on
 the gathered V.
 """
+import json
 import os
 import subprocess
 import sys
@@ -453,6 +454,145 @@ def test_rerank_batch_mask():
         for i in np.asarray(slates[b]):
             if i >= 0:
                 assert bool(mask[b, i])
+
+
+# ---------------------------------------------------------------------------
+# The per-device tile and precision
+# ---------------------------------------------------------------------------
+
+
+def _compiled_platform(interpret=None):
+    return False if interpret is None else bool(interpret)
+
+
+@pytest.mark.parametrize("D,Mloc,R,windowed", [
+    (32, 250_000, 20, False),  # pool1m's shard on four chips
+    (32, 250_000, 8, True),
+    (64, 262_144, 50, False),
+    (16, 500, 12, False),  # a shard that fits on-core
+])
+def test_mesh_tile_on_a_compiled_platform(monkeypatch, D, Mloc, R, windowed):
+    """Unset, the mesh's tile comes from the VMEM model at the shard's
+    shape where Pallas runs compiled: a LANE multiple within the
+    per-tile budget, no wider than the shard; the model's tile where the
+    shard is past the budget, one tile over a shard that fits."""
+    from repro.core import sharded
+    from repro.kernels.dpp_greedy.tiling import (
+        LANE, VMEM_BUDGET_BYTES, TilePolicy, round_up, tile_vmem_bytes,
+    )
+
+    monkeypatch.setattr(sharded, "resolve_interpret", _compiled_platform)
+    tile, source = sharded._mesh_tile(None, D, Mloc, R, windowed)
+    assert source == "model"
+    assert tile % LANE == 0 and LANE <= tile <= round_up(Mloc, LANE)
+    assert tile_vmem_bytes(D, tile, R, windowed) <= VMEM_BUDGET_BYTES
+    mode, tm = TilePolicy().decide(D, Mloc, R, windowed)
+    assert tile == (tm if mode == "tiled" else round_up(Mloc, LANE))
+    # an explicit tile wins on any platform
+    assert sharded._mesh_tile(2048, D, Mloc, R, windowed) == (2048, "explicit")
+
+
+def test_mesh_tile_off_a_tpu_keeps_the_jnp_bodies():
+    from repro.core import sharded
+
+    assert jax.default_backend() != "tpu"
+    assert sharded._mesh_tile(None, 32, 250_000, 20, False) == (None, None)
+
+
+def _dot_precisions(jaxpr):
+    """The ``precision`` of every ``dot_general`` in ``jaxpr`` and the
+    jaxprs nested in it."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += _dot_precisions(inner)
+    return out
+
+
+@pytest.mark.parametrize("window", [None, 3], ids=["exact", "windowed"])
+def test_jnp_step_bodies_multiply_V_at_highest_precision(window):
+    """Every product of V in the mesh's jnp step bodies asks for
+    ``Precision.HIGHEST``, whole slate and streamed chunk alike: a TPU's
+    default float32 matmul rounds its operands through bfloat16."""
+    from repro.core.sharded import (
+        dpp_greedy_sharded_stream_chunk,
+        dpp_greedy_sharded_stream_init,
+    )
+
+    V = _problem(5)
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
+    hi = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    whole = jax.make_jaxpr(
+        lambda V: dpp_greedy_sharded(V, 6, mesh=mesh, window=window))(V)
+    state = dpp_greedy_sharded_stream_init(V, 6, mesh=mesh, window=window)
+    chunk = jax.make_jaxpr(
+        lambda V, st: dpp_greedy_sharded_stream_chunk(V, st, 3, mesh=mesh)
+    )(V, state)
+    for jaxpr in (whole.jaxpr, chunk.jaxpr):
+        precisions = _dot_precisions(jaxpr)
+        assert precisions and all(p == hi for p in precisions), precisions
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tile_m", [None, 128], ids=["tile-unset", "tile-128"])
+@pytest.mark.parametrize("window", [None, 4], ids=["exact", "windowed"])
+def test_mesh_rerank_matches_float64_reference_on_four_devices(window, tile_m):
+    """``Reranker.rerank`` on a 4-device mesh, whole slate and streamed,
+    selects the float64 reference's slate (``bench/reference.py``) index
+    for index, with the tile unset (the jnp step bodies, off a TPU) and
+    set (the Pallas step kernel, interpreted); the dispatch counters say
+    which ran and count k merges of each kind per call."""
+    out = run_subprocess(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import json
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import AxisType
+        from bench import reference
+        from repro import obs
+        from repro.obs import ObsConfig
+        from repro.serving import DPPRerankConfig, Reranker, RerankRequest
+        assert jax.device_count() == 4
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
+        rng = np.random.default_rng(11)
+        M, D, k, alpha = 1001, 16, 10, 3.0  # M pads to the mesh quantum
+        feats = rng.normal(size=(M, D)).astype(np.float32)
+        feats /= np.linalg.norm(feats, axis=1, keepdims=True)
+        scores = rng.uniform(size=M).astype(np.float32)
+        V = reference.kernel_columns(feats, scores, alpha)
+        ref, _ = reference.ref_greedy(V, k, 1e-6, window={window!r})
+        session = obs.enable(ObsConfig(enabled=True, trace=False))
+        cfg = DPPRerankConfig(slate_size=k, shortlist=M, alpha=alpha,
+                              eps=1e-6, window={window!r}, mesh=mesh,
+                              tile_m={tile_m!r}, chunk_size=4)
+        rr = Reranker(cfg)
+        req = RerankRequest(scores=jnp.asarray(scores),
+                            feats=jnp.asarray(feats))
+        ids, _ = rr.rerank(req)
+        streamed = np.concatenate([np.asarray(c) for c, _ in rr.stream(req)])
+        snap = session.registry.snapshot()["counters"]
+        print(json.dumps({{
+            "ref": [int(i) for i in ref], "ids": np.asarray(ids).tolist(),
+            "streamed": streamed.tolist(),
+            "modes": snap["dpp_kernel_dispatch_total"],
+            "merges": snap["sharded_merges_total"]}}))
+    """)
+    got = json.loads(out.strip().splitlines()[-1])
+    assert len(got["ref"]) == 10
+    assert got["ids"] == got["ref"]
+    assert got["streamed"] == got["ref"]
+    mode = "jnp" if tile_m is None else "tiled"
+    windowed = str(window is not None)
+    # one whole-slate call and one stream init
+    assert got["modes"] == {f"mode={mode},windowed={windowed}": 2}
+    kinds = ["argmax", "winner"] + (["window"] if window else [])
+    # k merges of each kind for the call, k more over the stream's chunks
+    assert got["merges"] == {f"kind={kind}": 20 for kind in kinds}
 
 
 # ---------------------------------------------------------------------------

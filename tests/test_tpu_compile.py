@@ -5,7 +5,8 @@ compiler refuses: lane-axis dynamic slices, scalar stores to VMEM, a
 working set over the scoped-VMEM limit.  These tests compile each
 ``dpp_greedy`` kernel family at the served widths for one chip of a
 described ``v5e:2x2`` topology — nothing runs, no chip is needed — and
-the geometries ``TilePolicy`` picks at the edge of its VMEM budget.
+the geometries ``TilePolicy`` picks at the edge of its VMEM budget; and
+the candidate-sharded greedy over all four chips of that topology.
 Each compile also checks that the program names its kernel by the
 family's ``pallas_call`` name (``dpp_{resident,step,chunk}_{exact,
 windowed}``), which the benchmark's trace readers match.
@@ -202,3 +203,34 @@ def test_budget_edge_geometries_compile(shape, Dn, R, windowed):
     tile = _policy_tile(Dn, R, windowed)
     _tiled(shape, k, w, tile, Dn, 2 * tile)
     _chunk(shape, R, windowed, Dn, _policy_tile(Dn, R, windowed, True))
+
+
+@pytest.mark.parametrize("window", [None, 8], ids=["exact", "windowed"])
+def test_sharded_default_tile_compiles_the_step_kernel(shape, topo,
+                                                       monkeypatch, window):
+    """``dpp_greedy_sharded`` with no ``tile_m``, over the four chips of
+    the described topology at the ``pool1m`` cell's width (D=32, k=20)
+    and a reduced pool: each chip runs the Pallas step kernel, and no
+    jnp step body (its products are ``dot_general``s) is left.
+
+    Code that asks the platform sees this process's CPU, so the test
+    steers it to the compiled platform the topology describes."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import sharded
+    from repro.kernels.dpp_greedy import tiled
+
+    def compiled_platform(interpret=None):
+        return False if interpret is None else bool(interpret)
+
+    monkeypatch.setattr(sharded, "resolve_interpret", compiled_platform)
+    monkeypatch.setattr(tiled, "resolve_interpret", compiled_platform)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    V = jax.ShapeDtypeStruct((32, 4 * 65536), jnp.float32,
+                             sharding=NamedSharding(mesh, P(None, "data")))
+    compiled = _compile(
+        lambda V: sharded.dpp_greedy_sharded(V, 20, mesh=mesh,
+                                             window=window), V)
+    assert _kernel_names(compiled) == {_family("step", window is not None)}
+    assert "dot_general" not in compiled.as_text()
