@@ -70,7 +70,7 @@ from repro.core.windowed import (
     dpp_greedy_windowed_lowrank,
     dpp_greedy_windowed_lowrank_batch,
 )
-from repro.obs.dispatch import record_greedy_map
+from repro.obs.dispatch import record_chunk, record_greedy_map
 
 _BACKENDS = ("auto", "jnp", "pallas", "sharded")
 
@@ -275,6 +275,38 @@ def greedy_map(
         return fn(V, spec.k, spec.window, spec.eps, mask)
     fn = dpp_greedy_lowrank_batch if batched else dpp_greedy_lowrank
     return fn(V, spec.k, spec.eps, mask)
+
+
+def record_greedy_call(spec: GreedySpec, *, B: int, D: int, M: int):
+    """Record on the host what ``greedy_map(spec, V=(B, D, M))`` records
+    for one call, and return the kernel it resolves: ``(mode, tile_m)``
+    on the pallas backend, ``None`` on jnp.
+
+    For a caller that runs ``greedy_map`` inside a compiled program:
+    the program's body runs its Python, hooks included, only while it
+    is traced, so the caller mutes the hooks there (``obs.muted``) and
+    calls this once per call instead.  The resolution reads the
+    ``DPP_TILE_M`` override at each call, so keying the program by it
+    lets an override take effect.  Single-device backends only."""
+    chunked = spec.chunk_size is not None
+    pallas = spec.backend == "pallas"
+    record_greedy_map("pallas" if pallas else "jnp", B=B, k=spec.k, M=M,
+                      chunked=chunked)
+    if not pallas:
+        return None
+    from repro.kernels.dpp_greedy.ops import (
+        dpp_greedy_plan,
+        dpp_greedy_stream_plan,
+    )
+
+    if not chunked:
+        return dpp_greedy_plan(D, M, spec.k, spec.window, spec.tile_m)[:2]
+    R = min(spec.window, spec.k) if spec.windowed() else spec.k
+    tile, Mp = dpp_greedy_stream_plan(D, M, R, spec.windowed(), spec.tile_m)
+    for done in range(0, spec.k, spec.chunk_size):
+        record_chunk("pallas", B=B, chunk=min(spec.chunk_size, spec.k - done),
+                     M=Mp)
+    return "fused_chunk", tile
 
 
 def greedy_map_chunks(

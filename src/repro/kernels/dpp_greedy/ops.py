@@ -114,6 +114,39 @@ def _resolve_tile_policy(
     return TilePolicy(tile_m=tile_m)
 
 
+def dpp_greedy_plan(
+    D: int,
+    M: int,
+    k: int,
+    window: int | None = None,
+    tile_m: _TileM = None,
+    tile_policy: Optional[TilePolicy] = None,
+    interpret: Optional[bool] = None,
+):
+    """The kernel ``dpp_greedy`` runs for ``V (B, D, M)``, resolved
+    through the tile_m precedence ladder and ``TilePolicy`` and recorded
+    as one dispatch: ``(mode, tile_m, interpret)``.
+
+    ``dpp_greedy`` calls it on every dispatch.  A caller that runs
+    ``dpp_greedy`` inside a compiled program, whose body records only
+    while it is traced, calls it on the host once per call instead."""
+    state_rows = k if window is None else min(window, k)
+    windowed = window is not None and window < k
+    policy = _resolve_tile_policy(tile_m, tile_policy)
+    mode, tm = policy.decide(D, M, state_rows, windowed)
+    interpret = resolve_interpret(interpret)
+    record_kernel_dispatch(
+        mode, D=D, M=M, state_rows=state_rows, windowed=windowed, tile_m=tm,
+        interpret=interpret,
+        vmem_bytes=(
+            untiled_vmem_bytes(D, M, state_rows) if mode == "resident"
+            else tile_vmem_bytes(D, tm, state_rows, windowed)
+            if mode == "tiled" else None
+        ),
+    )
+    return mode, tm, interpret
+
+
 def dpp_greedy(
     V: jnp.ndarray,
     k: int,
@@ -147,25 +180,15 @@ def dpp_greedy(
         raise ValueError(f"window must be >= 1, got {window}")
     if mask is None:
         mask = jnp.ones((B, M), bool)
-    state_rows = k if window is None else min(window, k)
-    windowed = window is not None and window < k
     if force_jnp:
         record_kernel_dispatch(
-            "jnp", D=D, M=M, state_rows=state_rows, windowed=windowed
+            "jnp", D=D, M=M, state_rows=k if window is None else min(window, k),
+            windowed=window is not None and window < k,
         )
         return dpp_greedy_ref(V, mask, k, eps, window=window)
 
-    policy = _resolve_tile_policy(tile_m, tile_policy)
-    mode, tm = policy.decide(D, M, state_rows, windowed)
-    interpret = resolve_interpret(interpret)
-    record_kernel_dispatch(
-        mode, D=D, M=M, state_rows=state_rows, windowed=windowed, tile_m=tm,
-        interpret=interpret,
-        vmem_bytes=(
-            untiled_vmem_bytes(D, M, state_rows) if mode == "resident"
-            else tile_vmem_bytes(D, tm, state_rows, windowed)
-            if mode == "tiled" else None
-        ),
+    mode, tm, interpret = dpp_greedy_plan(
+        D, M, k, window, tile_m, tile_policy, interpret
     )
     if mode == "jnp":  # even a single lane-width tile exceeds the budget
         return dpp_greedy_ref(V, mask, k, eps, window=window)
@@ -216,6 +239,23 @@ def _stream_tile(D: int, M: int, state_rows: int, windowed: bool,
     return min(tm, Mp), Mp
 
 
+def dpp_greedy_stream_plan(D: int, M: int, state_rows: int, windowed: bool,
+                           tile_m: _TileM = None,
+                           tile_policy: Optional[TilePolicy] = None):
+    """The fused chunk kernel a streaming state of ``(D, M)`` runs,
+    recorded as one dispatch: ``(tile, Mp)`` (see ``_stream_tile``).
+    ``dpp_greedy_stream_init`` calls it; so does a caller that runs the
+    stream inside a compiled program, on the host once per call."""
+    tile, Mp = _stream_tile(D, M, state_rows, windowed, tile_m, tile_policy)
+    record_kernel_dispatch(
+        "fused_chunk", D=D, M=M, state_rows=state_rows, windowed=windowed,
+        tile_m=tile, interpret=resolve_interpret(),
+        vmem_bytes=tile_vmem_bytes(D, tile, state_rows, windowed,
+                                   chunked=True),
+    )
+    return tile, Mp
+
+
 def dpp_greedy_stream_init(
     V: jnp.ndarray,
     k: int,
@@ -241,12 +281,7 @@ def dpp_greedy_stream_init(
     B, D, M = Vb.shape
     windowed = window is not None and window < k
     R = min(window, k) if windowed else k
-    tile, Mp = _stream_tile(D, M, R, windowed, tile_m, tile_policy)
-    record_kernel_dispatch(
-        "fused_chunk", D=D, M=M, state_rows=R, windowed=windowed,
-        tile_m=tile, interpret=resolve_interpret(),
-        vmem_bytes=tile_vmem_bytes(D, tile, R, windowed, chunked=True),
-    )
+    tile, Mp = dpp_greedy_stream_plan(D, M, R, windowed, tile_m, tile_policy)
     if mask is None:
         mask = jnp.ones((B, M), bool)
     elif mask.ndim == 1:

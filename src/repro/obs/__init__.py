@@ -33,6 +33,7 @@ metrics.json`` surfaces both exports from the CLI.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import dataclasses
 from typing import Optional
 
@@ -87,6 +88,8 @@ class Obs:
 
 
 _ACTIVE: Optional[Obs] = None
+# set while a compiled program's body is traced (``muted``); per thread
+_MUTED = contextvars.ContextVar("repro_obs_muted", default=False)
 
 
 class _NullSpan:
@@ -149,7 +152,22 @@ def tracer() -> Optional[SpanTracer]:
 
 def registry() -> Optional[MetricsRegistry]:
     a = _ACTIVE
-    return a.registry if a is not None else None
+    if a is None or _MUTED.get():
+        return None
+    return a.registry
+
+
+@contextlib.contextmanager
+def muted():
+    """Hide the registry from the metric hooks for the block, in this
+    thread.  A compiled program's body runs its Python once, while it is
+    traced, so the hooks inside would count traces; its caller mutes
+    them there and records each call on the host instead."""
+    token = _MUTED.set(True)
+    try:
+        yield
+    finally:
+        _MUTED.reset(token)
 
 
 def compile_monitor():
@@ -220,6 +238,7 @@ __all__ = [
     "enabled",
     "gauge_set",
     "inc",
+    "muted",
     "observe",
     "registry",
     "session",

@@ -149,11 +149,26 @@ def record_rerank_call(path: str) -> None:
     ).inc(path=path)
 
 
+def record_rerank_trace(path: str, phase: str) -> None:
+    """One trace of ``Reranker.rerank``'s compiled ``phase`` program
+    (``shortlist`` / ``greedy``) down ``path`` (``single`` /
+    ``batched``).  Called inside the program's body, so it counts cache
+    misses: ``serving_rerank_calls_total`` less this is how often a
+    cached program served the call."""
+    reg = _obs.registry()
+    if reg is None:
+        return
+    reg.counter(
+        "serving_rerank_traces_total",
+        "traces of Reranker.rerank's compiled phase programs",
+    ).inc(path=path, phase=phase)
+
+
 def record_shortlist(path: str) -> None:
     """One shortlist build down ``path``: ``whole_pool`` (the shortlist
     covers the pool, so V is built in id order with no sort or gather)
     or ``top_k`` (sort, then gather the shortlisted rows).  Counted in
-    Python, so under an outer ``jit`` it counts traces, not calls."""
+    Python, on the host, once per build."""
     reg = _obs.registry()
     if reg is None:
         return

@@ -39,15 +39,21 @@ shims and are now removed — this module is the only serving surface.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.core.dispatch import greedy_map
-from repro.obs.dispatch import record_rerank_call
-from repro.serving.reranker import DPPRerankConfig, _shortlist_kernel
+from repro.core.dispatch import greedy_map, record_greedy_call
+from repro.obs.dispatch import record_rerank_call, record_rerank_trace
+from repro.serving.reranker import (
+    DPPRerankConfig,
+    _build_shortlist,
+    _shortlist_kernel,
+    _shortlist_width,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -370,34 +376,74 @@ class Reranker:
 
 
 def _rerank_impl(scores, feats, cfg, mask):
-    if jnp.ndim(scores) != 1:
-        raise ValueError(
-            f"rerank takes a single request (scores (M,)), got "
-            f"ndim={jnp.ndim(scores)}; batched scores dispatch through "
-            f"Reranker.rerank"
-        )
-    # the phase spans run once per call on the batched path too: vmap
-    # traces this body once, dispatching each op as it goes
+    """``Reranker.rerank`` off the mesh, for one request (scores
+    ``(M,)``) or a user batch (``(B, M)``): two cached compiled
+    programs, the shortlist's and the greedy's, each dispatched inside
+    its phase span.
+
+    A program is keyed by the config's fields that shape it and the
+    request's shapes, so a call after the first of its kind traces
+    nothing.  Its body records only while it is traced, so the counters
+    are recorded here, on the host, once per call, from static shapes;
+    among them the kernel's mode and tile, resolved per call (a
+    ``DPP_TILE_M`` override takes effect at the next call)."""
+    shape = jnp.shape(scores)
+    spec = cfg.greedy_spec()
+    C = _shortlist_width(cfg, shape[-1])
+    kernel = record_greedy_call(spec, B=shape[0] if len(shape) == 2 else 1,
+                                D=jnp.shape(feats)[-1], M=C)
     with obs.span("serving.rerank.shortlist"):
-        V, m_top, top_i = _shortlist_kernel(scores, feats, cfg, mask)
+        V, m_top, top_i = _shortlist_program(
+            scores, feats, mask, C=C, alpha=cfg.alpha
+        )
     with obs.span("serving.rerank.greedy"):
-        res = greedy_map(cfg.greedy_spec(), V=V, mask=m_top)
-        sel, dh = res.indices, res.d_hist
-        out = jnp.where(sel >= 0, top_i[jnp.clip(sel, 0)], -1)
-        return out.astype(jnp.int32), dh
+        return _greedy_program(V, m_top, top_i, spec=spec, kernel=kernel)
 
 
 def _rerank_batch_impl(scores, feats, cfg, mask):
+    """The batched path: ``_rerank_impl`` over a user batch, under a
+    name of its own so a test can stand in for the batched path alone."""
+    return _rerank_impl(scores, feats, cfg, mask)
+
+
+@functools.partial(jax.jit, static_argnames=("C", "alpha"))
+def _shortlist_program(scores, feats, mask, *, C, alpha):
+    """V, the shortlist mask and the shortlist's global ids; a user
+    batch maps the single request's build over its users, with shared
+    (M, D) or per-user (B, M, D) features and a shared (M,) or per-user
+    (B, M) mask."""
+    batched = scores.ndim == 2
+    record_rerank_trace("batched" if batched else "single", "shortlist")
+    build = functools.partial(_build_shortlist, C=C, alpha=alpha)
+    if not batched:
+        return build(scores, feats, mask)
     if mask is not None and mask.ndim == 1:
         mask = jnp.broadcast_to(mask, scores.shape)
-    f_ax = 0 if feats.ndim == 3 else None
-    if mask is None:  # keep the unmasked hot path free of mask plumbing
-        return jax.vmap(
-            lambda s, f: _rerank_impl(s, f, cfg, None), in_axes=(0, f_ax)
-        )(scores, feats)
-    return jax.vmap(
-        lambda s, f, m: _rerank_impl(s, f, cfg, m), in_axes=(0, f_ax, 0)
-    )(scores, feats, mask)
+    return jax.vmap(build, in_axes=(0, 0 if feats.ndim == 3 else None, 0))(
+        scores, feats, mask
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "kernel"))
+def _greedy_program(V, mask, top_i, *, spec, kernel):
+    """``greedy_map`` over the shortlist's V and the picks mapped to
+    global ids (-1 after an eps-stop), per user of a batch.  ``kernel``,
+    the host's resolution of the kernel for this call, keys the program:
+    traced right after it, the body resolves the same."""
+    batched = V.ndim == 3
+    record_rerank_trace("batched" if batched else "single", "greedy")
+    pick = functools.partial(_pick, spec)
+    with obs.muted():  # the host records this call's dispatch
+        if batched:
+            return jax.vmap(pick)(V, mask, top_i)
+        return pick(V, mask, top_i)
+
+
+def _pick(spec, V, mask, top_i):
+    res = greedy_map(spec, V=V, mask=mask)
+    sel = res.indices
+    out = jnp.where(sel >= 0, top_i[jnp.clip(sel, 0)], -1)
+    return out.astype(jnp.int32), res.d_hist
 
 
 def _sharded_rerank_impl(scores, feats, cfg, mask, sharded_kernel):

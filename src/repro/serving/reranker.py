@@ -155,11 +155,27 @@ class DPPRerankConfig:
 
 
 def _shortlist_kernel(scores, feats, cfg, mask):
-    """The top-C shortlist and its implicit DPP kernel — shared by the
-    whole-slate ``Reranker.rerank`` and the chunk-emitting
-    ``Reranker.stream`` so the two paths diversify the identical V.
-    Returns
-    ``(V (D, C), shortlist mask or None, top_i (C,) global ids)``.
+    """The top-C shortlist and its implicit DPP kernel, for the
+    chunk-emitting ``Reranker.stream``, the router's admission and
+    sessions; ``Reranker.rerank``'s compiled shortlist program traces
+    the same ``_build_shortlist``, so every path diversifies the
+    identical V.  Returns
+    ``(V (D, C), shortlist mask or None, top_i (C,) global ids)``."""
+    C = _shortlist_width(cfg, scores.shape[0])
+    return _build_shortlist(scores, feats, mask, C=C, alpha=cfg.alpha)
+
+
+def _shortlist_width(cfg, M):
+    """The shortlist width C for a pool of M, counting the path its
+    build takes (``serving_shortlist_total``)."""
+    C = min(cfg.shortlist, M)
+    record_shortlist("whole_pool" if C == M else "top_k")
+    return C
+
+
+def _build_shortlist(scores, feats, mask, *, C, alpha):
+    """``_shortlist_kernel``'s arithmetic for one request, recording
+    nothing, so a compiled program can trace it.
 
     A shortlist that covers the whole pool (C == M) ranks and permutes
     nothing: V is built over the pool in id order, as the sharded path
@@ -168,18 +184,15 @@ def _shortlist_kernel(scores, feats, cfg, mask):
     gives, except that an exact tie between two gains goes to the lower
     global id (the sharded path's rule) instead of the higher score."""
     M = scores.shape[0]
-    C = min(cfg.shortlist, M)
     if C == M:
-        record_shortlist("whole_pool")
         s, f, m_top = scores, feats, mask
         top_i = jnp.arange(M, dtype=jnp.int32)
     else:
-        record_shortlist("top_k")
         s = scores if mask is None else jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
         s, top_i = jax.lax.top_k(s, C)
         f = feats[top_i]  # (C, D)
         m_top = None if mask is None else mask[top_i]
-    rel = map_relevance(s.astype(jnp.float32), cfg.alpha)
+    rel = map_relevance(s.astype(jnp.float32), alpha)
     if m_top is not None:
         # neither the sentinel score (which only ranks masked items
         # last; alpha < 1 maps it to inf) nor a masked item's own score

@@ -180,6 +180,113 @@ def test_sharded_dispatch_one_device():
 
 
 # ---------------------------------------------------------------------------
+# The compiled front door: one cached program per phase
+# ---------------------------------------------------------------------------
+
+KERNEL_CFG = DPPRerankConfig(slate_size=5, shortlist=32, alpha=3.0,
+                             use_kernel=True)
+
+
+def _fresh_programs():
+    """Forget every compiled front-door program, so traces count from 0."""
+    import repro.serving.api as api
+
+    api._shortlist_program.clear_cache()
+    api._greedy_program.clear_cache()
+
+
+def _counters(snap, name):
+    return snap["counters"].get(name, {})
+
+
+@pytest.mark.parametrize("kind", [
+    "single", "single-masked", "batched", "batched-masked",
+    "batched-shared-mask", "batched-per-user-feats", "single-chunked",
+])
+def test_front_door_traces_each_phase_once_per_kind(kind):
+    """N calls of one kind trace each phase's program once; the per-call
+    counters count all N, and the slates are the eager body's, index
+    for index, with gains to float32 rounding (XLA may fuse the
+    relevance map's elementwise ops in the compiled program)."""
+    from repro import obs
+
+    N, B, M = 3, 3, 56
+    batched = kind.startswith("batched")
+    s, f = _problem(M, seed=21, batch=B if batched else None)
+    if kind != "batched-per-user-feats" and f.ndim == 3:
+        f = f[0]
+    rng = np.random.default_rng(21)
+    mask = None
+    if kind.endswith("masked"):
+        mask = jnp.asarray(rng.uniform(size=s.shape) > 0.25)
+    elif kind == "batched-shared-mask":
+        mask = jnp.asarray(rng.uniform(size=M) > 0.25)
+    chunked = kind.endswith("chunked")
+    cfg = dataclasses.replace(KERNEL_CFG, chunk_size=2 if chunked else None)
+    with jax.disable_jit():
+        ref = _rerank_impl(s, f, cfg, mask)
+    _fresh_programs()
+    rr = Reranker(cfg)
+    req = RerankRequest(scores=s, feats=f, mask=mask)
+    obs.disable()
+    with obs.session(obs.ObsConfig(enabled=True)) as sess:
+        for _ in range(N):
+            ids, dh = rr.rerank(req)
+            np.testing.assert_array_equal(np.asarray(ids),
+                                          np.asarray(ref[0]))
+            np.testing.assert_allclose(np.asarray(dh), np.asarray(ref[1]),
+                                       rtol=1e-6)
+        snap = sess.registry.snapshot()
+    path = "batched" if batched else "single"
+    assert _counters(snap, "serving_rerank_traces_total") == {
+        f"path={path},phase=shortlist": 1, f"path={path},phase=greedy": 1}
+    assert _counters(snap, "serving_rerank_calls_total") == {
+        f"path={path}": N}
+    assert _counters(snap, "serving_shortlist_total") == {"path=top_k": N}
+    assert _counters(snap, "greedy_dispatch_total") == {
+        f"backend=pallas,chunked={chunked}": N}
+    mode = "fused_chunk" if chunked else "resident"
+    assert _counters(snap, "dpp_kernel_dispatch_total") == {
+        f"mode={mode},windowed=False": N}
+    assert _counters(snap, "greedy_steps_total") == {
+        "backend=pallas": N * (B if batched else 1) * cfg.slate_size}
+    if chunked:
+        assert _counters(snap, "greedy_chunks_total") == {
+            "backend=pallas": N * -(-cfg.slate_size // cfg.chunk_size)}
+
+
+def test_front_door_resolves_the_tile_per_call(monkeypatch):
+    """A ``DPP_TILE_M`` override set between two calls takes effect at
+    the next: the greedy program is traced again with the tiled kernel,
+    and dropping the override serves the resident program cached
+    before it."""
+    from repro import obs
+
+    s, f = _problem(56, seed=22)
+    rr = Reranker(KERNEL_CFG)
+    req = RerankRequest(scores=s, feats=f)
+    monkeypatch.delenv("DPP_TILE_M", raising=False)
+    _fresh_programs()
+    obs.disable()
+    with obs.session(obs.ObsConfig(enabled=True)) as sess:
+        resident = rr.rerank(req)
+        monkeypatch.setenv("DPP_TILE_M", "128")
+        tiled = rr.rerank(req)
+        tm = sess.registry.get("dpp_tile_m").value()
+        monkeypatch.delenv("DPP_TILE_M")
+        again = rr.rerank(req)
+        snap = sess.registry.snapshot()
+    assert tm == 128
+    assert _counters(snap, "dpp_kernel_dispatch_total") == {
+        "mode=resident,windowed=False": 2, "mode=tiled,windowed=False": 1}
+    assert _counters(snap, "serving_rerank_traces_total") == {
+        "path=single,phase=shortlist": 1, "path=single,phase=greedy": 2}
+    for out in (tiled, again):
+        np.testing.assert_array_equal(np.asarray(out[0]),
+                                      np.asarray(resident[0]))
+
+
+# ---------------------------------------------------------------------------
 # The removal pin (ISSUE 8: the PR-6 shims' grace period has elapsed)
 # ---------------------------------------------------------------------------
 

@@ -39,6 +39,7 @@ from repro.obs import (
 from repro.obs.dispatch import (
     record_chunk,
     record_kernel_dispatch,
+    record_rerank_trace,
     record_sharded_merges,
     record_shortlist,
 )
@@ -210,6 +211,7 @@ def test_record_hooks_are_noops_without_a_session(no_obs):
     record_chunk("jnp", B=2, chunk=4, M=64)  # must not raise
     record_shortlist("whole_pool")
     record_shortlist("top_k")
+    record_rerank_trace("batched", "greedy")
     record_sharded_merges(20, windowed=True)
     assert obs.registry() is None
 

@@ -234,3 +234,41 @@ def test_sharded_default_tile_compiles_the_step_kernel(shape, topo,
                                              window=window), V)
     assert _kernel_names(compiled) == {_family("step", window is not None)}
     assert "dot_general" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["unmasked", "masked"])
+def test_batched_front_door_greedy_program_compiles(shape, monkeypatch,
+                                                    masked):
+    """``Reranker.rerank``'s compiled greedy program for a user batch at
+    the feed's served widths (8 users, a shortlist of 1000 at D=100,
+    k=50): the users map onto the resident kernel in one program, and
+    no jnp step body (its products are ``dot_general``s) is left.
+
+    Code that asks the platform sees this process's CPU, so the test
+    steers the kernel dispatch to the compiled platform the topology
+    describes, and drops the program it traced either way."""
+    from repro.kernels.dpp_greedy import ops
+    from repro.serving import DPPRerankConfig, api
+
+    def compiled_platform(interpret=None):
+        return False if interpret is None else bool(interpret)
+
+    monkeypatch.setattr(ops, "resolve_interpret", compiled_platform)
+    Dn, C = 100, 1000
+    spec = DPPRerankConfig(slate_size=K, shortlist=C, alpha=3.0, eps=1e-3,
+                           use_kernel=True).greedy_spec()
+    kernel = ops.dpp_greedy_plan(Dn, C, K)[:2]
+    assert kernel == ("resident", None)
+    api._greedy_program.clear_cache()
+    try:
+        compiled = _compile(
+            lambda V, m, ids: api._greedy_program(V, m, ids, spec=spec,
+                                                  kernel=kernel),
+            shape((B, Dn, C)), shape((B, C), jnp.bool_) if masked else None,
+            shape((B, C), jnp.int32),
+        )
+    finally:
+        api._greedy_program.clear_cache()
+    assert _kernel_names(compiled) == {_family("resident", False)}
+    assert "dot_general" not in compiled.as_text()
