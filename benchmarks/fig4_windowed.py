@@ -13,7 +13,7 @@ flat in N (within noise; ``win_step_vs_N<w>`` stays ~1x).
 Rows with ``past_gate=1`` are configs where
 ``untiled_vmem_bytes(D, M, w) > VMEM_BUDGET_BYTES``: before the tiled
 kernels these silently degraded to the pure-jnp path; now the
-``TilePolicy`` auto-tiles the candidate axis (``tile_m`` in the derived
+``plan_tile`` auto-tiles the candidate axis (``tile_m`` in the derived
 column) and the Pallas path keeps running.  Each row cross-checks the
 kernel slate against the jnp oracle (``parity=ok``) and reports
 ``kernel_vs_jnp`` wall-clock (interpret mode on CPU measures structure,
@@ -70,8 +70,8 @@ def run_gate(cells, k, trials):
     """cells: (M, D, w) triples; returns CSV-ready gate-sweep rows."""
     from repro.kernels.dpp_greedy import (
         VMEM_BUDGET_BYTES,
-        TilePolicy,
         dpp_greedy,
+        plan_tile,
         untiled_vmem_bytes,
     )
 
@@ -79,7 +79,7 @@ def run_gate(cells, k, trials):
     for M, D, w in cells:
         V = setup(M, D)[None]  # (1, D, M)
         past = int(untiled_vmem_bytes(D, M, w) > VMEM_BUDGET_BYTES)
-        mode, tm = TilePolicy().decide(D, M, w, windowed=True)
+        mode, tm = plan_tile(D, M, w, windowed=True)
 
         def timed(fn):
             sel, _ = fn()

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import hashlib
 import io
 import json
 import os
@@ -33,16 +32,13 @@ from repro.obs import ObsConfig
 
 def bench_meta():
     """Device/provenance stamp for every BENCH_<fig>.json: which
-    accelerator and jax produced the numbers, plus the tile overrides in
-    effect (``DPP_TILE_M`` and the autotune cache file + content hash) —
-    enough to tell two artifacts apart without re-running anything.
-    Purely best-effort: a field that cannot be determined reads
-    "unknown" rather than failing the benchmark that produced it."""
+    accelerator and jax produced the numbers — enough to tell two
+    artifacts apart without re-running anything.  Purely best-effort: a
+    field that cannot be determined reads "unknown" rather than failing
+    the benchmark that produced it."""
     meta = {
         "device_kind": "unknown", "platform": "unknown",
         "backend": "unknown", "jax": "unknown", "jaxlib": "unknown",
-        "dpp_tile_m": os.environ.get("DPP_TILE_M"),
-        "autotune_cache": None, "autotune_cache_sha256": None,
     }
     try:
         import jax
@@ -54,20 +50,12 @@ def bench_meta():
             meta["jaxlib"] = jaxlib.__version__
         except Exception:
             pass
-        from repro.kernels.dpp_greedy.autotune import (
-            active_cache_path,
-            device_fingerprint,
+        dev = jax.devices()[0]
+        meta.update(
+            device_kind=getattr(dev, "device_kind", "unknown"),
+            platform=getattr(dev, "platform", "unknown"),
+            backend=jax.default_backend(),
         )
-
-        dk, plat, backend = device_fingerprint()
-        meta.update(device_kind=dk, platform=plat, backend=backend)
-        path = active_cache_path()
-        meta["autotune_cache"] = path
-        if os.path.exists(path):
-            with open(path, "rb") as f:
-                meta["autotune_cache_sha256"] = hashlib.sha256(
-                    f.read()
-                ).hexdigest()
     except Exception:
         pass
     return meta
@@ -162,7 +150,6 @@ def main() -> None:
         fig6_streaming,
         fig7_serving,
         fig8_observability,
-        fig9_autotune,
         fig10_session,
     )
 
@@ -183,8 +170,6 @@ def main() -> None:
          "percentiles", fig7_serving.main),
         ("fig8", "Figure 8: observability — pump breakdown and the "
          "recompile ledger", fig8_observability.main),
-        ("fig9", "Figure 9: measured autotune cache vs the analytical "
-         "VMEM model", fig9_autotune.main),
         ("fig10", "Figure 10: session delta-resume vs full re-rerank "
          "(latency, parity)", fig10_session.main),
     ]

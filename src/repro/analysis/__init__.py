@@ -4,7 +4,7 @@ Three checker families over one findings/suppression framework:
 
 * ``repro.analysis.kernels`` — Pallas kernel contracts: BlockSpec
   coverage/divisibility, revisit contiguity (the Mosaic hazard), and
-  the TilePolicy VMEM model checked against the specs the kernels
+  the VMEM tile model checked against the specs the kernels
   actually declare;
 * ``repro.analysis.jitgeo`` — jit boundary hygiene and the router's
   single-compiled-geometry proof;

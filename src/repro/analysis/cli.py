@@ -64,21 +64,11 @@ def run_analysis(
                     session_summaries.append(summary)
 
     kernel_summary: dict | None = None
-    autotune_summary: dict | None = None
     if kernel_checks and any(p.endswith(_KERNEL_SOURCE) for p in files):
-        from repro.analysis.kernels import (
-            check_autotune_cache,
-            check_kernel_contracts,
-        )
+        from repro.analysis.kernels import check_kernel_contracts
 
         kernel_findings, kernel_summary = check_kernel_contracts()
         findings.extend(kernel_findings)
-        # the persisted autotune cache ($DPP_AUTOTUNE_CACHE or the
-        # per-user default) is part of the kernel dispatch surface: a
-        # stale or hand-edited entry must not ship an over-budget or
-        # gap-revisiting launch
-        cache_findings, autotune_summary = check_autotune_cache()
-        findings.extend(cache_findings)
 
     findings = apply_suppressions(findings, suppressions)
     summary = {
@@ -87,7 +77,6 @@ def run_analysis(
         "router_geometry": geometry_summaries,
         "session_geometry": session_summaries,
         "kernel_contracts": kernel_summary,
-        "autotune_cache": autotune_summary,
         "findings": len(findings),
     }
     return sorted(set(findings)), summary
@@ -150,11 +139,6 @@ def main(argv: list[str] | None = None) -> int:
             kc = summary["kernel_contracts"]
             tail += (f"; kernel contracts: {kc['geometries']} geometries "
                      f"across {len(kc['families'])} families")
-        if summary.get("autotune_cache") and summary["autotune_cache"].get(
-                "present"):
-            ac = summary["autotune_cache"]
-            tail += (f"; autotune cache: {ac['checked']}/{ac['entries']} "
-                     f"entries validated ({ac['path']})")
         for geo in summary["router_geometry"]:
             if geo.get("reachable_geometries") == 1:
                 tail += (f"; {geo['class']}: 1 reachable compiled "

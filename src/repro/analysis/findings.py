@@ -83,20 +83,13 @@ RULES: dict[str, str] = {
         "guarantee its contents between visits)"
     ),
     "pallas-vmem-budget": (
-        "a TilePolicy-selectable geometry whose per-tile working set "
+        "a plan_tile-selectable geometry whose per-tile working set "
         "exceeds the VMEM budget"
     ),
     "pallas-vmem-model": (
         "tiling.tile_vmem_bytes undercounts the streams the kernel's "
-        "BlockSpecs actually declare (the policy would pick an "
+        "BlockSpecs actually declare (plan_tile would pick an "
         "overflowing tile)"
-    ),
-    "autotune-cache-invalid": (
-        "a persisted autotune cache entry that could ship a bad launch "
-        "— over the VMEM budget (model or declared BlockSpecs), a "
-        "non-LANE tile, key/fields divergence (hand-edited), a "
-        "compiled multi-tile chunk geometry (Mosaic revisit gaps), or "
-        "an unreadable/foreign-schema cache file"
     ),
     # -- framework -------------------------------------------------------
     "bad-suppression": (

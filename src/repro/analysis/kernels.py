@@ -25,12 +25,11 @@ indices out — which makes every property below decidable exactly:
   unreachable rather than latent);
 * **VMEM model faithfulness** — ``tiling.tile_vmem_bytes``'s per-lane
   slope must cover the streamed rows the BlockSpecs actually declare
-  (an undercount makes ``TilePolicy.auto_tile`` pick overflowing
-  tiles);
-* **VMEM budget** — for every geometry ``TilePolicy`` can choose, the
-  decided tile's working set (model *and* recorded-spec actuals) fits
-  ``vmem_budget_bytes``, and the non-streamed replicated cells stay
-  bounded.
+  (an undercount makes ``tiling.auto_tile`` pick overflowing tiles);
+* **VMEM budget** — for every geometry ``tiling.plan_tile`` can choose,
+  the planned tile's working set (model *and* recorded-spec actuals)
+  fits ``VMEM_BUDGET_BYTES``, and the non-streamed replicated cells
+  stay bounded.
 """
 from __future__ import annotations
 
@@ -43,7 +42,7 @@ from repro.analysis.findings import Finding
 
 LANE = 128
 SUBLANE = 8
-_BIG_M = 1 << 22  # forces decide() off the resident path
+_BIG_M = 1 << 22  # forces plan_tile() off the resident path
 _CELL_BYTES_BOUND = 1 << 20  # replicated cells must not scale
 
 
@@ -116,7 +115,7 @@ class _Recorder:
 # Drivers
 # --------------------------------------------------------------------------
 
-# the geometries TilePolicy can be asked to tile: feature dims across
+# the geometries plan_tile can be asked to tile: feature dims across
 # the sublane boundary, state rows from tiny windows to full slates
 SWEEP_D = (8, 64, 256)
 SWEEP_R = (8, 48, 128)
@@ -357,7 +356,11 @@ def _stream_accounting(rec: RecordedCall) -> tuple[int, int]:
 
 
 def check_vmem_contract(seam: DrivenSeam) -> list[Finding]:
-    from repro.kernels.dpp_greedy.tiling import TilePolicy, tile_vmem_bytes
+    from repro.kernels.dpp_greedy.tiling import (
+        VMEM_BUDGET_BYTES,
+        plan_tile,
+        tile_vmem_bytes,
+    )
 
     findings: list[Finding] = []
     D, R = seam.D, seam.state_rows
@@ -377,24 +380,22 @@ def check_vmem_contract(seam: DrivenSeam) -> list[Finding]:
             f"auto_tile would pick an overflowing tile",
         ))
 
-    policy = TilePolicy()
-    mode, tm = policy.decide(D, _BIG_M, R, seam.windowed,
-                             chunked=seam.chunked)
+    mode, tm = plan_tile(D, _BIG_M, R, seam.windowed, chunked=seam.chunked)
     if mode == "tiled" and tm:
-        if model(tile_m=tm) > policy.vmem_budget_bytes:
+        if model(tile_m=tm) > VMEM_BUDGET_BYTES:
             findings.append(Finding(
                 seam.path, seam.line, "pallas-vmem-budget",
-                f"TilePolicy picked tile_m={tm} whose own model "
+                f"plan_tile picked tile_m={tm} whose own model "
                 f"estimate {model(tile_m=tm)} exceeds the "
-                f"{policy.vmem_budget_bytes}-byte budget ({geom})",
+                f"{VMEM_BUDGET_BYTES}-byte budget ({geom})",
             ))
         actual_stream = 4 * 2 * rows * tm
-        if actual_stream > policy.vmem_budget_bytes:
+        if actual_stream > VMEM_BUDGET_BYTES:
             findings.append(Finding(
                 seam.path, seam.line, "pallas-vmem-budget",
-                f"TilePolicy picked tile_m={tm} but the recorded "
+                f"plan_tile picked tile_m={tm} but the recorded "
                 f"BlockSpecs stream {actual_stream} double-buffered "
-                f"bytes/tile, over the {policy.vmem_budget_bytes}-byte "
+                f"bytes/tile, over the {VMEM_BUDGET_BYTES}-byte "
                 f"budget ({geom})",
             ))
     if cell_bytes > _CELL_BYTES_BOUND:
@@ -405,166 +406,6 @@ def check_vmem_contract(seam: DrivenSeam) -> list[Finding]:
             f"headroom (< {_CELL_BYTES_BOUND}) ({geom})",
         ))
     return findings
-
-
-# --------------------------------------------------------------------------
-# Autotune cache validation (rule autotune-cache-invalid)
-# --------------------------------------------------------------------------
-
-_ENTRY_FIELDS = (
-    ("D", int), ("M_bucket", int), ("state_rows", int), ("tile_m", int),
-    ("windowed", bool), ("chunked", bool),
-)
-
-
-def _seam_rows(family: str, D: int, R: int,
-               memo: dict[tuple[str, int, int], int]) -> int:
-    """Streamed padded rows/tile the family's BlockSpecs actually
-    declare at (D, R) — driven through the recorder like
-    :func:`harvest_seams`, memoized per geometry."""
-    key = (family, D, R)
-    if key not in memo:
-        from repro.kernels.dpp_greedy import tiled
-
-        recorder = _Recorder()
-        orig = tiled.pl.pallas_call
-        tiled.pl.pallas_call = recorder
-        try:
-            seam = _drive_family(tiled, family, D, R, recorder)
-        finally:
-            tiled.pl.pallas_call = orig
-        memo[key] = _stream_accounting(seam.call)[0]
-    return memo[key]
-
-
-def check_autotune_cache(
-    path: Optional[str] = None,
-) -> tuple[list[Finding], dict]:
-    """Abstractly re-validate every persisted autotune cache entry.
-
-    The runtime lookup ladder already refuses out-of-contract entries
-    (it degrades them to a model-fallback miss); this rule makes the
-    same contract a *blocking CI fact* about the cache file itself, so
-    a stale or hand-edited cache is repaired at review time instead of
-    silently mistuning the fleet.  Checks per entry: the tile is a
-    LANE multiple; the key reproduces from the entry's own structured
-    fields; the analytical model fits the VMEM budget; the rows the
-    family's declared BlockSpecs actually stream fit the budget at
-    that tile; and a compiled (non-interpret) fused-chunk entry never
-    spans multiple tiles (Mosaic does not preserve non-consecutively
-    revisited output blocks — the pallas-revisit-gap hazard).
-    """
-    import json
-    import os
-
-    from repro.kernels.dpp_greedy import autotune
-    from repro.kernels.dpp_greedy.tiling import (
-        VMEM_BUDGET_BYTES,
-        tile_vmem_bytes,
-    )
-
-    path = path or autotune.active_cache_path()
-    summary = {"path": path, "present": False, "entries": 0, "checked": 0}
-    if not os.path.exists(path):
-        return [], summary
-    summary["present"] = True
-
-    def finding(msg: str) -> Finding:
-        return Finding(path, 1, "autotune-cache-invalid", msg)
-
-    try:
-        with open(path, encoding="utf-8") as fh:
-            doc = json.load(fh)
-    except (OSError, UnicodeDecodeError, ValueError) as e:
-        return [finding(f"cache file is not parseable JSON ({e})")], summary
-    if not isinstance(doc, dict) or not isinstance(doc.get("entries"), dict):
-        return [finding("cache document must be an object with an "
-                        "'entries' mapping")], summary
-    if doc.get("schema") != autotune.SCHEMA_VERSION:
-        return [finding(
-            f"cache schema {doc.get('schema')!r} != supported "
-            f"{autotune.SCHEMA_VERSION} — re-run "
-            f"python -m repro.kernels.autotune"
-        )], summary
-
-    findings: list[Finding] = []
-    rows_memo: dict[tuple[str, int, int], int] = {}
-    entries = doc["entries"]
-    summary["entries"] = len(entries)
-    for key, e in sorted(entries.items()):
-        if not isinstance(e, dict):
-            findings.append(finding(f"entry {key!r} is not an object"))
-            continue
-        bad_field = False
-        for name, typ in _ENTRY_FIELDS:
-            v = e.get(name)
-            if not isinstance(v, typ) or (typ is int and isinstance(v, bool)):
-                findings.append(finding(
-                    f"entry {key!r}: field {name!r} must be {typ.__name__}, "
-                    f"got {v!r}"
-                ))
-                bad_field = True
-        if bad_field:
-            continue
-        D, mb, R = e["D"], e["M_bucket"], e["state_rows"]
-        tm, windowed, chunked = e["tile_m"], e["windowed"], e["chunked"]
-        summary["checked"] += 1
-        if mb < LANE or mb & (mb - 1):
-            findings.append(finding(
-                f"entry {key!r}: M_bucket {mb} is not a power-of-two "
-                f">= {LANE} (bucket lookup would never match it)"
-            ))
-        if tm < LANE or tm % LANE != 0:
-            findings.append(finding(
-                f"entry {key!r}: tile_m {tm} is not a positive multiple "
-                f"of the {LANE}-lane register width"
-            ))
-            continue
-        expect = autotune.cache_key(
-            e.get("device_kind"), e.get("platform"), e.get("backend"),
-            D, mb, R, windowed, chunked,
-        )
-        if key != expect:
-            findings.append(finding(
-                f"entry key {key!r} does not reproduce from its own "
-                f"fields ({expect!r}) — hand-edited or corrupted; the "
-                f"lookup ladder will never match it"
-            ))
-        model = tile_vmem_bytes(D, tm, R, windowed, chunked)
-        if model > VMEM_BUDGET_BYTES:
-            findings.append(finding(
-                f"entry {key!r}: tile_m={tm} has a model working set of "
-                f"{model} bytes, over the {VMEM_BUDGET_BYTES}-byte VMEM "
-                f"budget (D={D}, R={R}, windowed={windowed}, "
-                f"chunked={chunked})"
-            ))
-        family = (("chunk_" if chunked else "step_")
-                  + ("windowed" if windowed else "exact"))
-        try:
-            rows = _seam_rows(family, D, R, rows_memo)
-        except Exception as err:
-            findings.append(finding(
-                f"entry {key!r}: cannot drive seam family {family} at "
-                f"D={D}, R={R} to validate its declared BlockSpecs "
-                f"({type(err).__name__}: {err})"
-            ))
-            continue
-        declared = 4 * 2 * rows * tm
-        if declared > VMEM_BUDGET_BYTES:
-            findings.append(finding(
-                f"entry {key!r}: the {family} BlockSpecs stream "
-                f"{declared} double-buffered bytes at tile_m={tm}, over "
-                f"the {VMEM_BUDGET_BYTES}-byte VMEM budget"
-            ))
-        if chunked and not e.get("interpret", True) and mb > tm:
-            findings.append(finding(
-                f"entry {key!r}: a compiled (interpret=false) fused-chunk "
-                f"geometry with {mb // tm} tiles — compiled Mosaic does "
-                f"not preserve non-consecutively revisited output blocks "
-                f"(pallas-revisit-gap); tune compiled chunk kernels "
-                f"whole-M or in interpret mode"
-            ))
-    return findings, summary
 
 
 # --------------------------------------------------------------------------

@@ -25,7 +25,7 @@ Dispatch rules:
   set, else "jnp";
 * ``spec.tile_m`` — candidate-axis tile for the Pallas kernels.  On the
   pallas backend it forces the tiled streaming kernels (by default
-  ``TilePolicy`` keeps the whole-working-set resident kernels while
+  ``plan_tile`` keeps the whole-working-set resident kernels while
   they fit VMEM and tiles past that); on the sharded backend each
   device's local per-step update reuses the same tiled kernel on its
   (D, M/P) shard, and unset, the tile comes from the shard's shape on
@@ -53,7 +53,7 @@ surfacing as a shape or trace error deep inside a jitted computation.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Union
+from typing import Optional
 
 import jax.numpy as jnp
 
@@ -89,9 +89,9 @@ class GreedySpec:
     eps: float = 1e-6
     mesh: Optional[object] = None  # jax Mesh for the sharded backend
     axis_name: str = "data"  # mesh axis the candidate axis shards over
-    # Pallas candidate-axis tile: an explicit LANE multiple, "auto"
-    # (measured autotune cache, model fallback), or None (VMEM model)
-    tile_m: Union[int, str, None] = None
+    # Pallas candidate-axis tile: an explicit LANE multiple, or None
+    # (worked out from the shapes)
+    tile_m: Optional[int] = None
     chunk_size: Optional[int] = None  # greedy steps per resumable chunk
 
     def __post_init__(self):
@@ -119,26 +119,16 @@ class GreedySpec:
             from repro.kernels.dpp_greedy.tiling import validate_tile_m
 
             try:
-                validate_tile_m(self.tile_m, allow_auto=True)
+                validate_tile_m(self.tile_m)
             except ValueError as e:
                 raise GreedySpecError(str(e)) from None
-            if self.tile_m == "auto" and self.backend != "pallas":
-                raise GreedySpecError(
-                    'tile_m="auto" consults the measured autotune cache, '
-                    "which only the single-device Pallas dispatch does "
-                    "(backend='pallas') — the jnp backend ignores tile_m "
-                    "entirely and the sharded per-device update takes an "
-                    "explicit LANE multiple or, unset, works its tile out "
-                    "from the shard's shape"
-                )
             if self.backend == "jnp" or (
                 self.backend == "auto" and self.mesh is None
             ):
                 raise GreedySpecError(
-                    "tile_m= (an int or \"auto\") only applies to the "
-                    "Pallas kernels (backend='pallas', or 'sharded'/'auto' "
-                    "with a mesh) — on the jnp backend it would be "
-                    "silently ignored"
+                    "tile_m= only applies to the Pallas kernels "
+                    "(backend='pallas', or 'sharded'/'auto' with a mesh) "
+                    "— on the jnp backend it would be silently ignored"
                 )
         if self.backend not in _BACKENDS:
             raise GreedySpecError(
@@ -277,36 +267,35 @@ def greedy_map(
     return fn(V, spec.k, spec.eps, mask)
 
 
-def record_greedy_call(spec: GreedySpec, *, B: int, D: int, M: int):
+def record_greedy_call(spec: GreedySpec, *, B: int, D: int, M: int) -> None:
     """Record on the host what ``greedy_map(spec, V=(B, D, M))`` records
-    for one call, and return the kernel it resolves: ``(mode, tile_m)``
-    on the pallas backend, ``None`` on jnp.
+    for one call: the backend, and on pallas the kernel's mode and tile.
 
     For a caller that runs ``greedy_map`` inside a compiled program:
     the program's body runs its Python, hooks included, only while it
     is traced, so the caller mutes the hooks there (``obs.muted``) and
-    calls this once per call instead.  The resolution reads the
-    ``DPP_TILE_M`` override at each call, so keying the program by it
-    lets an override take effect.  Single-device backends only."""
+    calls this once per call instead.  The kernel is a function of the
+    spec and the shapes, so the program needs no key of its own for it.
+    Single-device backends only."""
     chunked = spec.chunk_size is not None
     pallas = spec.backend == "pallas"
     record_greedy_map("pallas" if pallas else "jnp", B=B, k=spec.k, M=M,
                       chunked=chunked)
     if not pallas:
-        return None
+        return
     from repro.kernels.dpp_greedy.ops import (
         dpp_greedy_plan,
         dpp_greedy_stream_plan,
     )
 
     if not chunked:
-        return dpp_greedy_plan(D, M, spec.k, spec.window, spec.tile_m)[:2]
+        dpp_greedy_plan(D, M, spec.k, spec.window, spec.tile_m)
+        return
     R = min(spec.window, spec.k) if spec.windowed() else spec.k
-    tile, Mp = dpp_greedy_stream_plan(D, M, R, spec.windowed(), spec.tile_m)
+    _, Mp = dpp_greedy_stream_plan(D, M, R, spec.windowed(), spec.tile_m)
     for done in range(0, spec.k, spec.chunk_size):
         record_chunk("pallas", B=B, chunk=min(spec.chunk_size, spec.k - done),
                      M=Mp)
-    return "fused_chunk", tile
 
 
 def greedy_map_chunks(

@@ -64,7 +64,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.greedy_chol import NEG_INF, GreedyResult
 from repro.kernels.dpp_greedy.tiling import (
     LANE,
-    TilePolicy,
+    auto_tile,
+    plan_tile,
     round_up,
     tile_vmem_bytes,
     validate_tile_m,
@@ -560,7 +561,7 @@ def _mesh_tile(tile_m, D, Mloc, state_rows, windowed):
     None tile runs the jnp step bodies.
 
     An explicit ``tile_m`` wins.  Unset, the platform and the shard's
-    shape decide, as on one chip (``TilePolicy.decide``).  Where Pallas
+    shape decide, as on one chip (``plan_tile``).  Where Pallas
     runs interpreted (off a TPU) the jnp bodies run.  Where it runs
     compiled, the model's ``tiled`` answer gives its tile; ``resident``
     (the shard fits on-core, and the mesh has no resident kernel) one
@@ -570,11 +571,9 @@ def _mesh_tile(tile_m, D, Mloc, state_rows, windowed):
         return tile_m, "explicit"
     if resolve_interpret():
         return None, None
-    policy = TilePolicy()
-    mode, tm = policy.decide(D, Mloc, state_rows, windowed)
+    mode, tm = plan_tile(D, Mloc, state_rows, windowed)
     if mode == "resident":
-        tm = min(round_up(Mloc, LANE),
-                 policy.auto_tile(D, state_rows, windowed))
+        tm = min(round_up(Mloc, LANE), auto_tile(D, state_rows, windowed))
     return tm or None, "model"
 
 

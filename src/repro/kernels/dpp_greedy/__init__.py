@@ -1,11 +1,3 @@
-from repro.kernels.dpp_greedy.autotune import (
-    AutotuneCache,
-    active_cache_path,
-    bucket_m,
-    cache_key,
-    lookup_tile,
-    run_sweep,
-)
 from repro.kernels.dpp_greedy.ops import (
     dpp_greedy,
     dpp_greedy_stream_chunk,
@@ -15,8 +7,8 @@ from repro.kernels.dpp_greedy.ops import (
 from repro.kernels.dpp_greedy.ref import dpp_greedy_ref
 from repro.kernels.dpp_greedy.tiled import dpp_greedy_tiled
 from repro.kernels.dpp_greedy.tiling import (
-    TilePolicy,
     VMEM_BUDGET_BYTES,
+    plan_tile,
     tile_vmem_bytes,
     untiled_vmem_bytes,
 )
@@ -28,13 +20,7 @@ __all__ = [
     "dpp_greedy_stream_init",
     "dpp_greedy_stream_pad",
     "dpp_greedy_tiled",
-    "AutotuneCache",
-    "active_cache_path",
-    "bucket_m",
-    "cache_key",
-    "lookup_tile",
-    "run_sweep",
-    "TilePolicy",
+    "plan_tile",
     "VMEM_BUDGET_BYTES",
     "tile_vmem_bytes",
     "untiled_vmem_bytes",

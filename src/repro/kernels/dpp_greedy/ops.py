@@ -1,6 +1,6 @@
 """Jitted public wrapper for the dpp_greedy Pallas kernels.
 
-Kernel-first dispatch (``TilePolicy``): when the whole working set
+Kernel-first dispatch (``tiling.plan_tile``): when the whole working set
 ``V (D, M)`` + Cholesky state fits the VMEM budget, the resident
 whole-slate kernels in ``dpp_greedy.py`` run (the entire greedy loop in
 one ``pallas_call``); past the budget the **tiled streaming kernels**
@@ -21,8 +21,7 @@ and the per-tile model depend on ``w`` rather than the slate length.
 """
 from __future__ import annotations
 
-import os
-from typing import Optional, Union
+from typing import Optional
 
 import jax.numpy as jnp
 
@@ -33,85 +32,16 @@ from repro.kernels.dpp_greedy.tiled import (
     fused_chunk_exact,
     fused_chunk_windowed,
 )
-# VMEM_BUDGET_BYTES / tile_vmem_bytes / untiled_vmem_bytes are
-# re-exported for back-compat: pre-tiling callers imported the budget
-# and accounting from ops (the module that used to own the gate).
-from repro.kernels.dpp_greedy.tiling import (  # noqa: F401
+from repro.kernels.dpp_greedy.tiling import (
     LANE,
     SUBLANE,
-    VMEM_BUDGET_BYTES,
-    TilePolicy,
+    plan_tile,
     round_up as _round_up,
     tile_vmem_bytes,
     untiled_vmem_bytes,
-    validate_tile_m,
 )
 from repro.kernels.platform import resolve_interpret
-from repro.obs.dispatch import (
-    record_kernel_dispatch,
-    record_tile_override,
-    record_tile_resolution,
-)
-
-_TileM = Union[int, str, None]  # int | "auto" | None
-
-
-def _env_tile_m() -> _TileM:
-    """Parse the ``DPP_TILE_M`` process override: unset/empty -> None,
-    ``auto`` -> the autotune ladder, anything else an explicit LANE
-    multiple.  Invalid values raise — a typo'd fleet-wide override must
-    fail loudly, not silently fall back to the model."""
-    raw = os.environ.get("DPP_TILE_M", "").strip()
-    if not raw:
-        return None
-    if raw.lower() == "auto":
-        return "auto"
-    try:
-        tm = int(raw)
-    except ValueError:
-        raise ValueError(
-            f'DPP_TILE_M must be an integer LANE multiple or "auto", '
-            f"got {raw!r}"
-        ) from None
-    validate_tile_m(tm)
-    return tm
-
-
-def _resolve_tile_policy(
-    tile_m: _TileM, tile_policy: Optional[TilePolicy]
-) -> TilePolicy:
-    """The tile_m precedence ladder, applied once per dispatch:
-
-        DPP_TILE_M env > explicit ``tile_m=`` > ``"auto"`` cache >
-        analytical model
-
-    (the cache-vs-model rungs resolve inside ``TilePolicy.decide``).
-    An explicit ``tile_policy=`` *object* bypasses the env override —
-    the power-user escape hatch the autotune sweep itself uses so the
-    environment being tuned cannot hijack its measurements.  Losing
-    sources are recorded in dispatch telemetry, not silently ignored.
-    """
-    if tile_m is not None and tile_policy is not None:
-        raise ValueError("pass at most one of tile_m= or tile_policy=")
-    if tile_policy is not None:
-        record_tile_resolution("policy")
-        return tile_policy
-    env = _env_tile_m()
-    if env is not None:
-        if tile_m is not None and env != tile_m:
-            record_tile_override(
-                winner="env",
-                lost="auto" if tile_m == "auto" else "explicit",
-            )
-        record_tile_resolution("env")
-        return TilePolicy(tile_m=env)
-    if tile_m == "auto":
-        record_tile_resolution("auto")
-    elif tile_m is not None:
-        record_tile_resolution("explicit")
-    else:
-        record_tile_resolution("model")
-    return TilePolicy(tile_m=tile_m)
+from repro.obs.dispatch import record_kernel_dispatch, record_tile_resolution
 
 
 def dpp_greedy_plan(
@@ -119,21 +49,20 @@ def dpp_greedy_plan(
     M: int,
     k: int,
     window: int | None = None,
-    tile_m: _TileM = None,
-    tile_policy: Optional[TilePolicy] = None,
+    tile_m: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
-    """The kernel ``dpp_greedy`` runs for ``V (B, D, M)``, resolved
-    through the tile_m precedence ladder and ``TilePolicy`` and recorded
-    as one dispatch: ``(mode, tile_m, interpret)``.
+    """The kernel ``dpp_greedy`` runs for ``V (B, D, M)``, planned by
+    ``plan_tile`` and recorded as one dispatch: ``(mode, tile_m,
+    interpret)``.
 
     ``dpp_greedy`` calls it on every dispatch.  A caller that runs
     ``dpp_greedy`` inside a compiled program, whose body records only
     while it is traced, calls it on the host once per call instead."""
     state_rows = k if window is None else min(window, k)
     windowed = window is not None and window < k
-    policy = _resolve_tile_policy(tile_m, tile_policy)
-    mode, tm = policy.decide(D, M, state_rows, windowed)
+    mode, tm = plan_tile(D, M, state_rows, windowed, tile_m=tile_m)
+    record_tile_resolution("model" if tile_m is None else "explicit")
     interpret = resolve_interpret(interpret)
     record_kernel_dispatch(
         mode, D=D, M=M, state_rows=state_rows, windowed=windowed, tile_m=tm,
@@ -155,8 +84,7 @@ def dpp_greedy(
     interpret: Optional[bool] = None,
     force_jnp: bool = False,
     window: int | None = None,
-    tile_m: _TileM = None,
-    tile_policy: Optional[TilePolicy] = None,
+    tile_m: Optional[int] = None,
 ):
     """Batched greedy DPP MAP inference.
 
@@ -165,13 +93,10 @@ def dpp_greedy(
     enforces diversity only against the last w picks (O(w M) VMEM state,
     unbounded k); ``window >= k`` or None is the exact Algorithm 1.
 
-    ``tile_m`` (or a full ``tile_policy``) forces the tiled streaming
-    kernels with that candidate-axis tile; ``tile_m="auto"`` sizes the
-    tile from the measured autotune cache (model fallback on a miss);
-    by default ``TilePolicy`` picks the resident kernels when the
-    working set fits VMEM and the widest model-fitting tile otherwise.
-    The ``DPP_TILE_M`` env var (an int or ``auto``) overrides ``tile_m``
-    process-wide; an explicit ``tile_policy=`` object bypasses the env.
+    ``tile_m`` forces the tiled streaming kernels with that
+    candidate-axis tile; by default ``plan_tile`` picks the resident
+    kernels when the working set fits VMEM and the widest fitting tile
+    otherwise.
     ``interpret`` defaults to the platform (compiled on a TPU,
     interpreted elsewhere — ``repro.kernels.platform``).
     """
@@ -188,7 +113,7 @@ def dpp_greedy(
         return dpp_greedy_ref(V, mask, k, eps, window=window)
 
     mode, tm, interpret = dpp_greedy_plan(
-        D, M, k, window, tile_m, tile_policy, interpret
+        D, M, k, window, tile_m, interpret
     )
     if mode == "jnp":  # even a single lane-width tile exceeds the budget
         return dpp_greedy_ref(V, mask, k, eps, window=window)
@@ -214,19 +139,17 @@ def dpp_greedy(
 
 
 def _stream_tile(D: int, M: int, state_rows: int, windowed: bool,
-                 tile_m: _TileM, tile_policy: Optional[TilePolicy]):
+                 tile_m: Optional[int]):
     """The candidate-axis tile a streaming state uses, derived
     deterministically from the problem shape so init and every chunk
-    agree (the autotune cache is memoized per file stamp, so a cache
-    rewritten mid-stream surfaces as the existing padded-geometry
-    mismatch error, not silent divergence).  Resident-size working sets
-    run the fused chunk kernel as a single whole-M tile (the
-    VMEM-resident analogue)."""
-    policy = _resolve_tile_policy(tile_m, tile_policy)
+    agree.  Resident-size working sets run the fused chunk kernel as a
+    single whole-M tile (the VMEM-resident analogue)."""
     # chunked=True: the fused chunk kernels stream the full Cholesky
     # block back out every step, so their per-tile working set is wider
     # than the per-step sweep the default model describes.
-    mode, tm = policy.decide(D, M, state_rows, windowed, chunked=True)
+    mode, tm = plan_tile(D, M, state_rows, windowed, chunked=True,
+                         tile_m=tile_m)
+    record_tile_resolution("model" if tile_m is None else "explicit")
     if mode == "jnp":
         raise ValueError(
             "pathological shape: even one lane-width tile exceeds the VMEM "
@@ -240,13 +163,12 @@ def _stream_tile(D: int, M: int, state_rows: int, windowed: bool,
 
 
 def dpp_greedy_stream_plan(D: int, M: int, state_rows: int, windowed: bool,
-                           tile_m: _TileM = None,
-                           tile_policy: Optional[TilePolicy] = None):
+                           tile_m: Optional[int] = None):
     """The fused chunk kernel a streaming state of ``(D, M)`` runs,
     recorded as one dispatch: ``(tile, Mp)`` (see ``_stream_tile``).
     ``dpp_greedy_stream_init`` calls it; so does a caller that runs the
     stream inside a compiled program, on the host once per call."""
-    tile, Mp = _stream_tile(D, M, state_rows, windowed, tile_m, tile_policy)
+    tile, Mp = _stream_tile(D, M, state_rows, windowed, tile_m)
     record_kernel_dispatch(
         "fused_chunk", D=D, M=M, state_rows=state_rows, windowed=windowed,
         tile_m=tile, interpret=resolve_interpret(),
@@ -261,8 +183,7 @@ def dpp_greedy_stream_init(
     k: int,
     mask: jnp.ndarray | None = None,
     window: int | None = None,
-    tile_m: _TileM = None,
-    tile_policy: Optional[TilePolicy] = None,
+    tile_m: Optional[int] = None,
 ):
     """Initial resumable state for the Pallas streaming path.
 
@@ -281,7 +202,7 @@ def dpp_greedy_stream_init(
     B, D, M = Vb.shape
     windowed = window is not None and window < k
     R = min(window, k) if windowed else k
-    tile, Mp = dpp_greedy_stream_plan(D, M, R, windowed, tile_m, tile_policy)
+    tile, Mp = dpp_greedy_stream_plan(D, M, R, windowed, tile_m)
     if mask is None:
         mask = jnp.ones((B, M), bool)
     elif mask.ndim == 1:
@@ -326,8 +247,7 @@ def dpp_greedy_stream_chunk(
     chunk: int,
     *,
     eps: float = 1e-3,
-    tile_m: _TileM = None,
-    tile_policy: Optional[TilePolicy] = None,
+    tile_m: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
     """Advance ``chunk`` greedy steps on a Pallas streaming state.
@@ -351,7 +271,7 @@ def dpp_greedy_stream_chunk(
     B, D, M = Vb.shape
     windowed = state.win.shape[-1] > 0
     R = state.C.shape[1]
-    tile, Mp = _stream_tile(D, M, R, windowed, tile_m, tile_policy)
+    tile, Mp = _stream_tile(D, M, R, windowed, tile_m)
     if Mp != state.d2.shape[-1]:
         raise ValueError(
             f"state was built for a padded candidate axis of "

@@ -45,7 +45,7 @@ with the shard's global column offset), so sharded M/P blocks scale
 past the VMEM budget exactly like the single-device path.
 
 Dispatch between resident and tiled kernels lives in ``ops.py`` via
-``repro.kernels.dpp_greedy.tiling.TilePolicy``.
+``repro.kernels.dpp_greedy.tiling.plan_tile``.
 """
 from __future__ import annotations
 
@@ -63,7 +63,7 @@ NEG_INF = float("-inf")
 
 
 # Mosaic parameters shared by every dpp_greedy pallas_call: the
-# scoped-VMEM limit the TilePolicy budget was checked against.
+# scoped-VMEM limit the plan_tile budget was checked against.
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 

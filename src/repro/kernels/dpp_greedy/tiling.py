@@ -1,10 +1,10 @@
-"""Tile policy for the dpp_greedy Pallas kernels — the VMEM *model*, not
-a gate.
+"""The tile of the dpp_greedy Pallas kernels — one decision, made from
+the shapes by a VMEM *model*, or pinned by an explicit int.
 
 Earlier revisions guarded the kernel with a single whole-array check
 (``vmem_bytes(D, M, state_rows) > VMEM_BUDGET_BYTES`` -> silently fall
 back to pure jnp), which surrendered exactly the large-M regime the
-paper's O(M)-per-step update is about.  ``TilePolicy`` replaces that
+paper's O(M)-per-step update is about.  :func:`plan_tile` replaces that
 gate with a decision between two *kernel* execution modes:
 
 * **resident** — the whole working set (``V (D, M)``, the Cholesky
@@ -24,19 +24,12 @@ The pure-jnp path survives only as an explicit escape hatch
 (``force_jnp=True``) and as a last resort when even a single
 lane-width tile would not fit (pathological ``D``/``state_rows``).
 
-(The pre-tiling ``vmem_bytes`` name lived here as a DeprecationWarning
-shim for one release after PR 4 and is now removed; the resident-mode
-working set is :func:`untiled_vmem_bytes`, the per-tile model
-:func:`tile_vmem_bytes`.)
+The resident-mode working set is :func:`untiled_vmem_bytes`, the
+per-tile model :func:`tile_vmem_bytes`.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Optional, Union
-
-# What the tile_m knob accepts across the stack: an explicit LANE
-# multiple, the measured-autotuner mode, or None (VMEM model decides).
-TileM = Union[int, str, None]
+from typing import Optional
 
 LANE = 128
 SUBLANE = 8
@@ -48,7 +41,7 @@ VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 # kernels at the budget's edge: the pipeline double-buffers the whole
 # V block and the kernels hold full-width temporaries (one-hot masks,
 # products) beside it.  TPU v5e has 128 MiB of VMEM per core; every
-# geometry TilePolicy can pick compiles under this limit
+# geometry plan_tile can pick compiles under this limit
 # (tests/test_tpu_compile.py).
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 # Upper bound for auto-chosen tiles: past this, wider tiles stop paying
@@ -61,31 +54,17 @@ def round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def validate_tile_m(tile_m: TileM, allow_auto: bool = False) -> None:
-    """Shared tile_m validation (TilePolicy, GreedySpec, DPPRerankConfig,
-    dpp_greedy_sharded all accept the knob): ``None``, a positive LANE
-    multiple, or — where ``allow_auto`` — the string ``"auto"`` (consult
-    the measured autotune cache, fall back to the VMEM model).  Call
-    sites that cannot consult the cache (the sharded per-device update,
-    the jnp backend) keep the default ``allow_auto=False`` so a stray
-    ``"auto"`` fails loudly instead of leaking a string into tile
-    arithmetic."""
+def validate_tile_m(tile_m: Optional[int]) -> None:
+    """Shared tile_m validation (every call site that accepts a tile:
+    ``GreedySpec``, ``DPPRerankConfig``, ``dpp_greedy``,
+    ``dpp_greedy_sharded``): ``None`` (the shapes decide) or a positive
+    LANE multiple."""
     if tile_m is None:
         return
-    if tile_m == "auto":
-        if allow_auto:
-            return
-        raise ValueError(
-            'tile_m="auto" (the measured autotune cache) is only '
-            "understood by the single-device Pallas dispatch — this "
-            f"call site needs None or an explicit positive multiple of "
-            f"the {LANE}-lane register width"
-        )
     if (not isinstance(tile_m, int) or isinstance(tile_m, bool)
             or tile_m < LANE or tile_m % LANE != 0):
         raise ValueError(
-            f'tile_m must be None, "auto" (measured autotune cache with '
-            f"VMEM-model fallback), or a positive multiple of the "
+            f"tile_m must be None or a positive multiple of the "
             f"{LANE}-lane register width, got {tile_m!r}"
         )
 
@@ -131,81 +110,42 @@ def tile_vmem_bytes(
     return 4 * 2 * streamed * tile_m + small
 
 
-@dataclasses.dataclass(frozen=True)
-class TilePolicy:
-    """How the dpp_greedy kernels use VMEM.
+def auto_tile(
+    D: int, state_rows: int, windowed: bool, chunked: bool = False,
+) -> int:
+    """Widest LANE-multiple tile whose working set fits the budget
+    (0 when even one lane-width tile does not fit)."""
+    lo = tile_vmem_bytes(D, LANE, state_rows, windowed, chunked)
+    if lo > VMEM_BUDGET_BYTES:
+        return 0
+    per_lane = tile_vmem_bytes(D, 2 * LANE, state_rows, windowed, chunked) - lo
+    tm = LANE * (1 + (VMEM_BUDGET_BYTES - lo) // max(per_lane, 1))
+    return min(tm, MAX_AUTO_TILE)
 
-    tile_m:
-        Explicit candidate-axis tile width (multiple of ``LANE``).
-        Forces the tiled streaming kernels even when the resident
-        kernels would fit — that is how tiled-vs-resident parity is
-        tested.  ``None`` picks automatically: resident when the whole
-        working set fits ``vmem_budget_bytes``, otherwise the widest
-        fitting tile.  ``"auto"`` keeps the resident-when-it-fits rule
-        but sizes the tiled mode from the *measured* autotune cache
-        (``repro.kernels.dpp_greedy.autotune``) when it has an entry
-        for this device/geometry, falling back to the analytical model
-        — never an error — when it does not.
-    vmem_budget_bytes:
-        The budget both models are checked against.
+
+def plan_tile(
+    D: int, M: int, state_rows: int, windowed: bool,
+    chunked: bool = False, tile_m: Optional[int] = None,
+) -> tuple[str, Optional[int]]:
+    """-> ("resident", None) | ("tiled", tile_m) | ("jnp", None).
+
+    An explicit ``tile_m`` always runs tiled at that width (that is how
+    tiled-vs-resident parity is tested).  Unset: resident when the whole
+    working set fits ``VMEM_BUDGET_BYTES``, else the widest fitting tile
+    (:func:`auto_tile`, capped at ``round_up(M, LANE)``), else jnp.
+
+    ``chunked`` must be set when the tile will feed the fused
+    multi-step chunk kernels, whose per-tile working set is larger
+    than the per-step exact sweep's (full state streams back out
+    every step) — sizing a chunked tile with the per-step model
+    overflows the budget by ``~8 * state_rows * tile_m`` bytes.
     """
-
-    tile_m: TileM = None
-    vmem_budget_bytes: int = VMEM_BUDGET_BYTES
-
-    def __post_init__(self):
-        validate_tile_m(self.tile_m, allow_auto=True)
-        if self.vmem_budget_bytes <= 0:
-            raise ValueError(
-                f"vmem_budget_bytes must be positive, got "
-                f"{self.vmem_budget_bytes}"
-            )
-
-    def auto_tile(
-        self, D: int, state_rows: int, windowed: bool,
-        chunked: bool = False,
-    ) -> int:
-        """Widest LANE-multiple tile whose working set fits the budget
-        (0 when even one lane-width tile does not fit)."""
-        lo = tile_vmem_bytes(D, LANE, state_rows, windowed, chunked)
-        if lo > self.vmem_budget_bytes:
-            return 0
-        per_lane = (
-            tile_vmem_bytes(D, 2 * LANE, state_rows, windowed, chunked) - lo
-        )
-        spare = self.vmem_budget_bytes - lo
-        tm = LANE * (1 + spare // max(per_lane, 1))
-        return min(tm, MAX_AUTO_TILE)
-
-    def decide(
-        self, D: int, M: int, state_rows: int, windowed: bool,
-        chunked: bool = False,
-    ) -> tuple[str, Optional[int]]:
-        """-> ("resident", None) | ("tiled", tile_m) | ("jnp", None).
-
-        ``chunked`` must be set when the tile will feed the fused
-        multi-step chunk kernels, whose per-tile working set is larger
-        than the per-step exact sweep's (full state streams back out
-        every step) — sizing a chunked tile with the per-step model
-        overflows the budget by ``~8 * state_rows * tile_m`` bytes.
-        """
-        if self.tile_m is not None and self.tile_m != "auto":
-            return "tiled", self.tile_m
-        if untiled_vmem_bytes(D, M, state_rows) <= self.vmem_budget_bytes:
-            return "resident", None
-        tm = None
-        if self.tile_m == "auto":
-            # measured winner for this device/geometry, prefiltered to
-            # the budget; a miss (no cache, unknown device, corrupted
-            # JSON) falls through to the analytical model below
-            from repro.kernels.dpp_greedy.autotune import lookup_tile
-
-            tm = lookup_tile(
-                D=D, M=M, state_rows=state_rows, windowed=windowed,
-                chunked=chunked, vmem_budget_bytes=self.vmem_budget_bytes,
-            )
-        if tm is None:
-            tm = self.auto_tile(D, state_rows, windowed, chunked)
-        if tm == 0:
-            return "jnp", None
-        return "tiled", min(tm, round_up(M, LANE))
+    validate_tile_m(tile_m)
+    if tile_m is not None:
+        return "tiled", tile_m
+    if untiled_vmem_bytes(D, M, state_rows) <= VMEM_BUDGET_BYTES:
+        return "resident", None
+    tm = auto_tile(D, state_rows, windowed, chunked)
+    if tm == 0:
+        return "jnp", None
+    return "tiled", min(tm, round_up(M, LANE))

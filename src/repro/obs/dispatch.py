@@ -19,7 +19,7 @@ Two halves:
 
 * **Dispatch recording** — small helpers the greedy dispatch layers
   call to count *which path actually ran*: the kernel execution mode
-  ``ops.py`` picked (jnp / resident / tiled and the ``TilePolicy``
+  ``ops.py`` picked (jnp / resident / tiled and the ``plan_tile``
   tile/VMEM numbers behind it), the backend ``greedy_map`` routed to,
   and the launched work in greedy steps and per-step marginal
   evaluations (each greedy step updates and argmaxes over M candidate
@@ -190,7 +190,7 @@ def record_kernel_dispatch(
 ) -> None:
     """One kernel execution-mode decision: which kernel path won
     (``jnp`` / ``resident`` / ``tiled`` / ``fused_chunk``), whether the
-    Pallas kernel runs interpreted, and the ``TilePolicy`` numbers
+    Pallas kernel runs interpreted, and the ``plan_tile`` numbers
     behind it."""
     reg = _obs.registry()
     if reg is None:
@@ -211,7 +211,7 @@ def record_kernel_dispatch(
     if vmem_bytes is not None:
         reg.gauge(
             "dpp_vmem_bytes_est",
-            "TilePolicy VMEM working-set estimate of the last dispatch",
+            "VMEM working-set estimate of the last dispatch",
         ).set(vmem_bytes)
 
 
@@ -234,59 +234,16 @@ def record_sharded_merges(steps: int, *, windowed: bool) -> None:
 
 
 def record_tile_resolution(source: str) -> None:
-    """Which source won one tile_m precedence resolution in ``ops.py``
-    (``env`` > ``explicit`` int > requested ``auto`` > ``model``;
-    ``policy`` = an explicit TilePolicy object bypassed the ladder), or
-    chose a mesh's per-device tile in ``core/sharded.py`` (``explicit``
-    or ``model``)."""
+    """Where one dispatch's tile came from: the caller's ``explicit``
+    int, or the VMEM ``model`` (``tiling.plan_tile``, in ``ops.py`` and,
+    for a mesh's per-device tile, in ``core/sharded.py``)."""
     reg = _obs.registry()
     if reg is None:
         return
     reg.counter(
         "dpp_tile_source_total",
-        "tile_m precedence winners by source "
-        "(env/explicit/auto/model/policy)",
+        "where each dispatch's tile came from (explicit/model)",
     ).inc(source=source)
-
-
-def record_tile_override(winner: str, lost: str) -> None:
-    """A tile_m request that *lost* the precedence resolution (e.g. a
-    call-site ``tile_m=`` shadowed by the ``DPP_TILE_M`` env override) —
-    recorded instead of silently ignored."""
-    reg = _obs.registry()
-    if reg is None:
-        return
-    reg.counter(
-        "dpp_tile_override_total",
-        "tile_m requests shadowed by a higher-precedence source",
-    ).inc(winner=winner, lost=lost)
-
-
-def record_autotune_lookup(
-    outcome: str, *, reason: str = "", tile_m: Optional[int] = None
-) -> None:
-    """One ``tile_m=\"auto\"`` cache consultation: an ``exact`` or
-    nearest-``bucket`` hit (with the chosen geometry), or a ``miss``
-    with its reason (empty/corrupt/no_entry/error) — the miss falls
-    back to the analytical VMEM model, never an error."""
-    reg = _obs.registry()
-    if reg is None:
-        return
-    if outcome in ("exact", "bucket"):
-        reg.counter(
-            "autotune_cache_hits_total",
-            "tile_m='auto' lookups that produced a measured tile",
-        ).inc(kind=outcome)
-        if tile_m is not None:
-            reg.gauge(
-                "autotune_tile_m",
-                "tile chosen by the last autotune cache hit",
-            ).set(tile_m)
-    else:
-        reg.counter(
-            "autotune_cache_misses_total",
-            "tile_m='auto' lookups that fell back to the VMEM model",
-        ).inc(reason=reason or "unknown")
 
 
 def record_greedy_map(backend: str, *, B: int, k: int, M: int,

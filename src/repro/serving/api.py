@@ -385,19 +385,18 @@ def _rerank_impl(scores, feats, cfg, mask):
     request's shapes, so a call after the first of its kind traces
     nothing.  Its body records only while it is traced, so the counters
     are recorded here, on the host, once per call, from static shapes;
-    among them the kernel's mode and tile, resolved per call (a
-    ``DPP_TILE_M`` override takes effect at the next call)."""
+    among them the kernel's mode and tile."""
     shape = jnp.shape(scores)
     spec = cfg.greedy_spec()
     C = _shortlist_width(cfg, shape[-1])
-    kernel = record_greedy_call(spec, B=shape[0] if len(shape) == 2 else 1,
-                                D=jnp.shape(feats)[-1], M=C)
+    record_greedy_call(spec, B=shape[0] if len(shape) == 2 else 1,
+                       D=jnp.shape(feats)[-1], M=C)
     with obs.span("serving.rerank.shortlist"):
         V, m_top, top_i = _shortlist_program(
             scores, feats, mask, C=C, alpha=cfg.alpha
         )
     with obs.span("serving.rerank.greedy"):
-        return _greedy_program(V, m_top, top_i, spec=spec, kernel=kernel)
+        return _greedy_program(V, m_top, top_i, spec=spec)
 
 
 def _rerank_batch_impl(scores, feats, cfg, mask):
@@ -424,12 +423,10 @@ def _shortlist_program(scores, feats, mask, *, C, alpha):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "kernel"))
-def _greedy_program(V, mask, top_i, *, spec, kernel):
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _greedy_program(V, mask, top_i, *, spec):
     """``greedy_map`` over the shortlist's V and the picks mapped to
-    global ids (-1 after an eps-stop), per user of a batch.  ``kernel``,
-    the host's resolution of the kernel for this call, keys the program:
-    traced right after it, the body resolves the same."""
+    global ids (-1 after an eps-stop), per user of a batch."""
     batched = V.ndim == 3
     record_rerank_trace("batched" if batched else "single", "greedy")
     pick = functools.partial(_pick, spec)

@@ -11,7 +11,7 @@ All greedy variants are reached through ``repro.core.greedy_map``:
   TPU, interpreted on other platforms); the default jnp path lowers
   through XLA.  Shortlists whose working set fits VMEM run the resident
   whole-slate-in-VMEM kernel; past the budget the tiled streaming
-  kernels take over (``TilePolicy`` — there is no silent jnp fallback
+  kernels take over (``tiling.plan_tile`` — there is no silent jnp fallback
   at scale any more), and ``tile_m=`` pins the tile width explicitly.
 * ``window=w`` enforces diversity only against the last ``w`` picks
   (the NeurIPS'18 sliding-window variant, O(w M) per step) so the
@@ -57,7 +57,7 @@ builder the session API dispatches through.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Union
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -90,9 +90,9 @@ class DPPRerankConfig:
     window: Optional[int] = None  # sliding diversity window (None = exact)
     mesh: Optional[object] = None  # shard the candidate axis over this mesh
     axis_name: str = "data"  # mesh axis carrying the candidate shards
-    # Pallas candidate-axis tile: an explicit LANE multiple, "auto"
-    # (measured autotune cache, model fallback), or None (VMEM model)
-    tile_m: Union[int, str, None] = None
+    # Pallas candidate-axis tile: an explicit LANE multiple, or None
+    # (worked out from the shapes)
+    tile_m: Optional[int] = None
     chunk_size: Optional[int] = None  # Reranker.stream emission granularity
     obs: Optional[ObsConfig] = None  # observability (installed by Reranker)
 
@@ -117,19 +117,12 @@ class DPPRerankConfig:
         if self.tile_m is not None:
             from repro.kernels.dpp_greedy.tiling import validate_tile_m
 
-            validate_tile_m(self.tile_m, allow_auto=True)
-            if self.tile_m == "auto" and not self.use_kernel:
-                raise ValueError(
-                    'tile_m="auto" consults the measured autotune cache, '
-                    "which only the Pallas kernels do — set "
-                    "use_kernel=True (the jnp and sharded backends do "
-                    "not consult the cache)"
-                )
+            validate_tile_m(self.tile_m)
             if not self.use_kernel and self.mesh is None:
                 raise ValueError(
-                    'tile_m= (an int or "auto") tiles the Pallas kernels '
-                    "— it needs use_kernel=True or mesh= (the jnp "
-                    "backend would silently ignore it)"
+                    "tile_m= tiles the Pallas kernels — it needs "
+                    "use_kernel=True or mesh= (the jnp backend would "
+                    "silently ignore it)"
                 )
 
     def greedy_spec(self) -> GreedySpec:

@@ -29,7 +29,7 @@ greedy run from step 0 on every scroll event, this layer
 State ownership: the device arrays (``_state``, ``_V``) are owned by
 the session and may vanish at any moment (eviction); the host mirrors
 (pool vectors, raw features, global ids, shown history, dead set) are
-authoritative and never evicted.  DESIGN.md §11 has the delta-update
+authoritative and never evicted.  DESIGN.md §10 has the delta-update
 math and the LRU contract.
 
 Observability: spans ``serving.session.{resume,extend,rescore,

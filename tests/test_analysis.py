@@ -46,7 +46,6 @@ AST_RULES = {
 KERNEL_RULES = {
     "pallas-coverage-gap", "pallas-block-divisibility",
     "pallas-revisit-gap", "pallas-vmem-budget", "pallas-vmem-model",
-    "autotune-cache-invalid",
 }
 
 # the corpus' planted violations: (fixture file, line, rule)
@@ -164,10 +163,7 @@ def test_bad_suppression_cannot_be_suppressed():
 # --------------------------------------------------------------------------
 
 
-def test_whole_repo_has_zero_findings(tmp_path, monkeypatch):
-    # hermetic vs developer machines: a stale tuned cache in the
-    # per-user default location is not a property of this repo
-    monkeypatch.setenv("DPP_AUTOTUNE_CACHE", str(tmp_path / "absent.json"))
+def test_whole_repo_has_zero_findings():
     paths = [str(ROOT / p) for p in ("src", "benchmarks", "examples")
              if (ROOT / p).exists()]
     findings, summary = run_analysis(paths)
@@ -318,7 +314,7 @@ def test_stale_vmem_model_fires(monkeypatch):
 
 
 def test_undercounting_model_breaks_the_budget(monkeypatch):
-    """If tile_vmem_bytes undercounted the streams, TilePolicy would
+    """If tile_vmem_bytes undercounted the streams, plan_tile would
     pick a tile whose recorded working set overflows VMEM — the budget
     rule catches it from the BlockSpec actuals."""
     from repro.kernels.dpp_greedy import tiling
@@ -331,80 +327,6 @@ def test_undercounting_model_breaks_the_budget(monkeypatch):
     )
     rules = {f.rule for f in ak.check_vmem_contract(seam)}
     assert "pallas-vmem-budget" in rules
-
-
-# --------------------------------------------------------------------------
-# Autotune cache validation (rule autotune-cache-invalid)
-# --------------------------------------------------------------------------
-
-
-def test_autotune_cache_fixture_fires_every_violation():
-    """The seeded over-budget cache fixture: each planted entry fires
-    its intended facet of autotune-cache-invalid."""
-    fx = FIXTURES / "fx_autotune_cache.json"
-    findings, summary = ak.check_autotune_cache(str(fx))
-    assert summary == {
-        "path": str(fx), "present": True, "entries": 4, "checked": 4,
-    }
-    assert findings and {f.rule for f in findings} == {
-        "autotune-cache-invalid"
-    }
-    msgs = "\n".join(f.message for f in findings)
-    assert "over the" in msgs and "VMEM" in msgs          # over-budget
-    assert "not a positive multiple" in msgs              # non-LANE tile
-    assert "does not reproduce from its own fields" in msgs  # hand-edit
-    assert "compiled (interpret=false) fused-chunk" in msgs  # revisit gap
-    assert all(f.path == str(fx) for f in findings)
-
-
-def test_autotune_cache_missing_and_valid_are_clean(tmp_path):
-    from repro.kernels.dpp_greedy.autotune import AutotuneCache
-
-    missing = tmp_path / "absent.json"
-    findings, summary = ak.check_autotune_cache(str(missing))
-    assert findings == [] and summary["present"] is False
-
-    cache = AutotuneCache(str(tmp_path / "good.json"), {})
-    cache.put(D=64, M_bucket=65536, state_rows=8, windowed=True,
-              chunked=False, tile_m=512, best_us=10.0,
-              candidates={512: 10.0}, interpret=True,
-              device=("dev", "cpu", "cpu"))
-    cache.save()
-    findings, summary = ak.check_autotune_cache(cache.path)
-    assert findings == [], "\n".join(f.format() for f in findings)
-    assert summary["checked"] == 1
-
-
-def test_autotune_cache_corrupt_file_fires(tmp_path):
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    findings, _ = ak.check_autotune_cache(str(bad))
-    assert [f.rule for f in findings] == ["autotune-cache-invalid"]
-    assert "not parseable" in findings[0].message
-
-    foreign = tmp_path / "foreign.json"
-    foreign.write_text('{"schema": 99, "entries": {}}')
-    findings, _ = ak.check_autotune_cache(str(foreign))
-    assert [f.rule for f in findings] == ["autotune-cache-invalid"]
-    assert "schema" in findings[0].message
-
-
-def test_run_analysis_validates_the_active_cache(tmp_path, monkeypatch):
-    """The CLI wiring: with $DPP_AUTOTUNE_CACHE pointing at a bad
-    cache, a whole-repo run surfaces the finding; the corpus run
-    (kernel_checks=False) never touches the cache."""
-    import shutil
-
-    bad = tmp_path / "cache.json"
-    shutil.copy(FIXTURES / "fx_autotune_cache.json", bad)
-    monkeypatch.setenv("DPP_AUTOTUNE_CACHE", str(bad))
-    src = ROOT / "src"
-    findings, summary = run_analysis([str(src)])
-    assert "autotune-cache-invalid" in {f.rule for f in findings}
-    assert summary["autotune_cache"]["present"] is True
-    findings, summary = run_analysis([str(src)], kernel_checks=False)
-    assert findings == []
-    assert summary["autotune_cache"] is None
 
 
 # --------------------------------------------------------------------------
@@ -467,8 +389,8 @@ def test_stream_tile_fits_chunked_budget():
     from repro.kernels.dpp_greedy import ops, tiling
 
     D, M, R = 64, 1 << 20, 128
-    tile, Mp = ops._stream_tile(D, M, R, False, None, None)
+    tile, Mp = ops._stream_tile(D, M, R, False, None)
     assert tile > 0 and Mp % tile == 0
     assert tiling.tile_vmem_bytes(D, tile, R, windowed=False,
                                   chunked=True) \
-        <= tiling.TilePolicy().vmem_budget_bytes
+        <= tiling.VMEM_BUDGET_BYTES

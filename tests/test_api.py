@@ -255,37 +255,6 @@ def test_front_door_traces_each_phase_once_per_kind(kind):
             "backend=pallas": N * -(-cfg.slate_size // cfg.chunk_size)}
 
 
-def test_front_door_resolves_the_tile_per_call(monkeypatch):
-    """A ``DPP_TILE_M`` override set between two calls takes effect at
-    the next: the greedy program is traced again with the tiled kernel,
-    and dropping the override serves the resident program cached
-    before it."""
-    from repro import obs
-
-    s, f = _problem(56, seed=22)
-    rr = Reranker(KERNEL_CFG)
-    req = RerankRequest(scores=s, feats=f)
-    monkeypatch.delenv("DPP_TILE_M", raising=False)
-    _fresh_programs()
-    obs.disable()
-    with obs.session(obs.ObsConfig(enabled=True)) as sess:
-        resident = rr.rerank(req)
-        monkeypatch.setenv("DPP_TILE_M", "128")
-        tiled = rr.rerank(req)
-        tm = sess.registry.get("dpp_tile_m").value()
-        monkeypatch.delenv("DPP_TILE_M")
-        again = rr.rerank(req)
-        snap = sess.registry.snapshot()
-    assert tm == 128
-    assert _counters(snap, "dpp_kernel_dispatch_total") == {
-        "mode=resident,windowed=False": 2, "mode=tiled,windowed=False": 1}
-    assert _counters(snap, "serving_rerank_traces_total") == {
-        "path=single,phase=shortlist": 1, "path=single,phase=greedy": 2}
-    for out in (tiled, again):
-        np.testing.assert_array_equal(np.asarray(out[0]),
-                                      np.asarray(resident[0]))
-
-
 # ---------------------------------------------------------------------------
 # The removal pin (ISSUE 8: the PR-6 shims' grace period has elapsed)
 # ---------------------------------------------------------------------------

@@ -7,10 +7,10 @@ import jax.numpy as jnp
 
 from conftest import assert_greedy_parity, make_greedy_inputs as make_inputs
 from repro.kernels.dpp_greedy import (
-    TilePolicy,
     VMEM_BUDGET_BYTES,
     dpp_greedy,
     dpp_greedy_ref,
+    plan_tile,
     untiled_vmem_bytes,
 )
 
@@ -84,11 +84,11 @@ def test_kernel_nonaligned_padding():
 
 
 def test_dispatch_past_gate_is_tiled_not_jnp():
-    """Huge M no longer falls back to jnp — TilePolicy dispatches the
+    """Huge M no longer falls back to jnp — plan_tile dispatches the
     tiled streaming kernels; the jnp path needs an explicit force_jnp."""
     B, D, M, k = 1, 8, 4096, 4
     assert untiled_vmem_bytes(64, 1 << 20, 32) > VMEM_BUDGET_BYTES
-    mode, tile = TilePolicy().decide(64, 1 << 20, 32, windowed=False)
+    mode, tile = plan_tile(64, 1 << 20, 32, windowed=False)
     assert mode == "tiled" and tile is not None
     V = make_inputs(19, B, D, M)
     sel, _ = dpp_greedy(V, k, force_jnp=True)
@@ -185,5 +185,5 @@ def test_kernel_windowed_vmem_budget_uses_window():
     D, M, k, w = 32, 8192, 512, 8
     assert untiled_vmem_bytes(D, M, k) > VMEM_BUDGET_BYTES
     assert untiled_vmem_bytes(D, M, w) < VMEM_BUDGET_BYTES
-    assert TilePolicy().decide(D, M, k, windowed=False)[0] == "tiled"
-    assert TilePolicy().decide(D, M, w, windowed=True) == ("resident", None)
+    assert plan_tile(D, M, k, windowed=False)[0] == "tiled"
+    assert plan_tile(D, M, w, windowed=True) == ("resident", None)

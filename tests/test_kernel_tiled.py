@@ -1,17 +1,17 @@
-"""Tiled-vs-resident dpp_greedy kernel parity + TilePolicy dispatch.
+"""Tiled-vs-resident dpp_greedy kernel parity + the tile plan.
 
 The tiled streaming kernels must select the identical slate (d_hist to
 ~1 ulp) as the resident whole-in-VMEM kernels and the jnp oracle across
 tile sizes {M (single tile), M/2, 128}, ragged tails, masks, eps-stop
 and windowed eviction — and a config past the old VMEM gate must run
 the Pallas path (interpret mode here) instead of falling back to jnp.
-
-The CI tiled-matrix job sweeps extra tile widths through the
-``DPP_TILE_M`` env var (appended to the parametrized grid).
+``plan_tile`` is checked over a table of geometries, each cell's shape
+among them.
 
 The 8-device sharded tiled-local-update parity runs in a subprocess in
 the slow lane (same isolation contract as tests/test_distributed.py).
 """
+import functools
 import os
 import subprocess
 import sys
@@ -26,27 +26,23 @@ from jax.sharding import AxisType
 from conftest import assert_greedy_parity, make_greedy_inputs as make_inputs
 from repro.core import GreedySpec, GreedySpecError, greedy_map
 from repro.kernels.dpp_greedy import (
-    TilePolicy,
     VMEM_BUDGET_BYTES,
     dpp_greedy,
     dpp_greedy_ref,
+    plan_tile,
     tile_vmem_bytes,
     untiled_vmem_bytes,
 )
+from repro.kernels.dpp_greedy import tiling
+from repro.kernels.dpp_greedy.tiling import LANE, round_up, validate_tile_m
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# extra tile width injected by the CI tiled-matrix job; non-numeric
-# values ("auto" in the autotune lane) name a policy mode, not a width
-_ENV_TILES = (
-    [int(os.environ["DPP_TILE_M"])]
-    if os.environ.get("DPP_TILE_M", "").isdigit() else []
-)
-
 
 def _tiles(M):
-    """{M (single tile), M/2, 128} + the CI matrix tile, deduplicated."""
-    ts = {M, M // 2, 128, *_ENV_TILES}
+    """{M (single tile), M/2, 128}, plus 384 (lane-aligned but not a
+    power of two) and 640 (one tile wider than M), deduplicated."""
+    ts = {M, M // 2, 128, 384, 640}
     return sorted(t for t in ts if t >= 128 and t % 128 == 0)
 
 
@@ -271,13 +267,13 @@ def test_vmap_of_tiled_update_matches_per_problem(window):
 
 def test_past_gate_runs_kernel_and_matches_oracle():
     """D=64, M=131072, w=8 exceeds the whole-array VMEM budget — the old
-    gate silently fell back to jnp here.  TilePolicy must now dispatch
+    gate silently fell back to jnp here.  plan_tile must now dispatch
     the tiled Pallas kernels (interpret mode on CPU) and the slate must
     be identical to the jnp oracle.  k > w so the *windowed* tiled
     kernel (eviction included) is the one exercised past the gate."""
     B, D, M, k, w = 1, 64, 131072, 16, 8
     assert untiled_vmem_bytes(D, M, w) > VMEM_BUDGET_BYTES
-    mode, tm = TilePolicy().decide(D, M, w, windowed=True)
+    mode, tm = plan_tile(D, M, w, windowed=True)
     assert mode == "tiled" and tm is not None
     assert tile_vmem_bytes(D, tm, w, windowed=True) <= VMEM_BUDGET_BYTES
     V = make_inputs(19, B, D, M)
@@ -291,34 +287,160 @@ def test_past_gate_runs_kernel_and_matches_oracle():
 
 
 # ---------------------------------------------------------------------------
-# TilePolicy / dispatch plumbing
+# The tile plan / dispatch plumbing
 # ---------------------------------------------------------------------------
 
 
 def test_tile_policy_decides():
     # comfortably in budget -> resident
-    assert TilePolicy().decide(64, 4096, 16, False) == ("resident", None)
+    assert plan_tile(64, 4096, 16, False) == ("resident", None)
     # past budget -> tiled with a fitting, lane-aligned tile
-    mode, tm = TilePolicy().decide(64, 1 << 20, 16, False)
+    mode, tm = plan_tile(64, 1 << 20, 16, False)
     assert mode == "tiled" and tm % 128 == 0
     assert tile_vmem_bytes(64, tm, 16, False) <= VMEM_BUDGET_BYTES
     # explicit tile_m forces tiling even when resident would fit
-    assert TilePolicy(tile_m=128).decide(16, 256, 4, False) == ("tiled", 128)
+    assert plan_tile(16, 256, 4, False, tile_m=128) == ("tiled", 128)
     # pathological row count: even one lane tile exceeds the budget
-    assert TilePolicy().decide(200_000, 1 << 20, 16, False) == ("jnp", None)
+    assert plan_tile(200_000, 1 << 20, 16, False) == ("jnp", None)
 
 
 def test_tile_policy_validation():
     with pytest.raises(ValueError, match="tile_m"):
-        TilePolicy(tile_m=100)
+        validate_tile_m(100)
     with pytest.raises(ValueError, match="tile_m"):
-        TilePolicy(tile_m=-128)
-    with pytest.raises(ValueError, match="vmem_budget_bytes"):
-        TilePolicy(vmem_budget_bytes=0)
-    with pytest.raises(ValueError, match="at most one"):
-        dpp_greedy(
-            jnp.ones((1, 4, 128)), 2, tile_m=128, tile_policy=TilePolicy()
-        )
+        plan_tile(16, 256, 4, False, tile_m=100)
+    with pytest.raises(ValueError, match="tile_m"):
+        plan_tile(16, 256, 4, False, tile_m=-128)
+
+
+# (D, M, state_rows, windowed, chunked, tile_m) -> (mode, tile).  The
+# expected tiles are the VMEM model's at the module budget.
+_PLANS = {
+    "feed1k": ((100, 1000, 50, False, False, None), ("resident", None)),
+    "pool1m": ((32, 1_000_000, 20, False, False, None), ("tiled", 19584)),
+    "pool1m-4chip-shard": (
+        (32, 250_000, 20, False, False, None), ("tiled", 19584)),
+    "pool1m-4chip-explicit": (
+        (32, 250_000, 20, False, False, 2048), ("tiled", 2048)),
+    "pool1m-chunked": ((32, 1_000_000, 20, False, True, None),
+                       ("tiled", 16256)),
+    "resident-edge": ((32, 49_152, 20, False, False, None),
+                      ("resident", None)),
+    "past-resident-edge": ((32, 49_153, 20, False, False, None),
+                           ("tiled", 19584)),
+    "windowed": ((64, 131_072, 8, True, False, None), ("tiled", 16256)),
+    "windowed-chunked": ((64, 131_072, 8, True, True, None),
+                         ("tiled", 16256)),
+    "wide-state": ((64, 1 << 20, 32, False, False, None), ("tiled", 13056)),
+    "narrow-D": ((8, 1 << 22, 1, False, False, None), ("tiled", 39296)),
+    "wide-D": ((256, 1 << 22, 50, False, False, None), ("tiled", 4608)),
+    "wide-D-windowed-chunked": ((256, 1 << 22, 50, True, True, None),
+                                ("tiled", 3968)),
+    "huge-D-short-pool": ((4096, 1000, 20, False, False, None),
+                          ("tiled", 256)),
+    "huge-D-short-pool-chunked": ((4096, 1000, 20, False, True, None),
+                                  ("tiled", 256)),
+    "long-window": ((1000, 100_000, 50, True, False, None), ("tiled", 1280)),
+    "small-shard": ((16, 500, 12, False, False, None), ("resident", None)),
+    "explicit-over-resident": ((16, 500, 12, False, False, 128),
+                               ("tiled", 128)),
+    "pathological-D": ((200_000, 1 << 20, 16, False, False, None),
+                       ("jnp", None)),
+    "pathological-D-windowed": ((100_000, 1 << 20, 16, True, False, None),
+                                ("jnp", None)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PLANS))
+def test_plan_tile(name):
+    """Each geometry plans the expected mode and tile.  A resident plan
+    is a working set within the budget; a planned tile fits the budget
+    and is the widest LANE multiple that does (short of the caps); an
+    explicit tile is taken as given; jnp means one lane-width tile
+    already overflows."""
+    (D, M, R, windowed, chunked, tile_m), want = _PLANS[name]
+    got = plan_tile(D, M, R, windowed, chunked, tile_m)
+    assert got == want
+    mode, tm = got
+    untiled = untiled_vmem_bytes(D, M, R)
+    per_tile = functools.partial(tile_vmem_bytes, D, state_rows=R,
+                                 windowed=windowed, chunked=chunked)
+    if tile_m is not None:
+        return
+    if mode == "resident":
+        assert untiled <= VMEM_BUDGET_BYTES
+        return
+    assert untiled > VMEM_BUDGET_BYTES
+    if mode == "jnp":
+        assert per_tile(tile_m=LANE) > VMEM_BUDGET_BYTES
+        return
+    assert tm % LANE == 0 and per_tile(tile_m=tm) <= VMEM_BUDGET_BYTES
+    assert tm == min(tiling.MAX_AUTO_TILE, round_up(M, LANE)) \
+        or per_tile(tile_m=tm + LANE) > VMEM_BUDGET_BYTES
+
+
+def test_plan_tile_caps_at_max_auto_tile(monkeypatch):
+    """At the module budget no geometry reaches ``MAX_AUTO_TILE`` (the
+    narrowest streams allow ~39k lanes); under a budget large enough to
+    allow more, the planned tile stops at the cap."""
+    monkeypatch.setattr(tiling, "VMEM_BUDGET_BYTES", 1 << 30)
+    assert plan_tile(8, 1 << 24, 1, False) == ("tiled", tiling.MAX_AUTO_TILE)
+
+
+def _reject_spec():
+    GreedySpec(k=4, backend="pallas", tile_m="auto")
+
+
+def _reject_config():
+    from repro.serving.reranker import DPPRerankConfig
+
+    DPPRerankConfig(use_kernel=True, tile_m="auto")
+
+
+def _reject_kernel():
+    dpp_greedy(jnp.ones((1, 4, 128)), 2, tile_m="auto")
+
+
+def _reject_sharded():
+    from repro.core.sharded import dpp_greedy_sharded
+
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
+    dpp_greedy_sharded(jnp.ones((4, 128)), 2, mesh=mesh, tile_m="auto")
+
+
+@pytest.mark.parametrize("call", [
+    _reject_spec, _reject_config, _reject_kernel, _reject_sharded,
+    lambda: validate_tile_m("auto"),
+], ids=["GreedySpec", "DPPRerankConfig", "dpp_greedy",
+        "dpp_greedy_sharded", "validate_tile_m"])
+def test_tile_m_auto_is_rejected(call):
+    """A tile is an int or None: the string the removed measured-cache
+    mode took is malformed input wherever a tile is accepted."""
+    with pytest.raises(ValueError, match="tile_m"):
+        call()
+
+
+# the process-wide tile override that earlier revisions read, spelled in
+# two parts so a search for the removed name finds only history
+_FORMER_OVERRIDE = "DPP_" + "TILE_M"
+
+
+@pytest.mark.parametrize("value", ["128", "2048", "auto"])
+def test_former_tile_override_is_ignored(monkeypatch, value):
+    """The environment no longer sets the tile: each cell's shape plans
+    and dispatches the model's tile whatever the former override says."""
+    from repro import obs
+    from repro.kernels.dpp_greedy.ops import dpp_greedy_plan
+
+    monkeypatch.setenv(_FORMER_OVERRIDE, value)
+    obs.disable()
+    with obs.session(obs.ObsConfig(enabled=True)) as sess:
+        assert dpp_greedy_plan(100, 1000, 50)[:2] == ("resident", None)
+        assert sess.registry.get("dpp_tile_m").value() == 0
+        assert dpp_greedy_plan(32, 1_000_000, 20)[:2] == ("tiled", 19584)
+        assert sess.registry.get("dpp_tile_m").value() == 19584
+        snap = sess.registry.snapshot()
+    assert snap["counters"]["dpp_tile_source_total"] == {"source=model": 2}
 
 
 def test_vmem_bytes_shim_removed():

@@ -470,24 +470,36 @@ def _compiled_platform(interpret=None):
     (32, 250_000, 8, True),
     (64, 262_144, 50, False),
     (16, 500, 12, False),  # a shard that fits on-core
+    (256, 1 << 20, 50, True),
+    (32, 49_152, 20, False),  # fits on-core, wider than a tile may be
+    (100, 1000, 50, False),  # feed1k's shortlist as a shard
+    (200_000, 1 << 20, 16, False),  # not one lane-width tile fits
 ])
 def test_mesh_tile_on_a_compiled_platform(monkeypatch, D, Mloc, R, windowed):
-    """Unset, the mesh's tile comes from the VMEM model at the shard's
-    shape where Pallas runs compiled: a LANE multiple within the
-    per-tile budget, no wider than the shard; the model's tile where the
-    shard is past the budget, one tile over a shard that fits."""
+    """Unset, the mesh's tile is the one-chip ``plan_tile`` at the
+    shard's shape where Pallas runs compiled: its tiled answer as is; a
+    resident answer (the mesh has no resident kernel) one tile over the
+    shard, capped at the widest tile that fits; a jnp answer the jnp
+    step bodies.  A tile is a LANE multiple within the per-tile budget,
+    no wider than the shard."""
     from repro.core import sharded
     from repro.kernels.dpp_greedy.tiling import (
-        LANE, VMEM_BUDGET_BYTES, TilePolicy, round_up, tile_vmem_bytes,
+        LANE, VMEM_BUDGET_BYTES, auto_tile, plan_tile, round_up,
+        tile_vmem_bytes,
     )
 
     monkeypatch.setattr(sharded, "resolve_interpret", _compiled_platform)
+    mode, tm = plan_tile(D, Mloc, R, windowed)
+    want = {
+        "tiled": tm,
+        "resident": min(round_up(Mloc, LANE), auto_tile(D, R, windowed)),
+        "jnp": None,
+    }[mode]
     tile, source = sharded._mesh_tile(None, D, Mloc, R, windowed)
-    assert source == "model"
-    assert tile % LANE == 0 and LANE <= tile <= round_up(Mloc, LANE)
-    assert tile_vmem_bytes(D, tile, R, windowed) <= VMEM_BUDGET_BYTES
-    mode, tm = TilePolicy().decide(D, Mloc, R, windowed)
-    assert tile == (tm if mode == "tiled" else round_up(Mloc, LANE))
+    assert (tile, source) == (want, "model")
+    if tile is not None:
+        assert tile % LANE == 0 and LANE <= tile <= round_up(Mloc, LANE)
+        assert tile_vmem_bytes(D, tile, R, windowed) <= VMEM_BUDGET_BYTES
     # an explicit tile wins on any platform
     assert sharded._mesh_tile(2048, D, Mloc, R, windowed) == (2048, "explicit")
 

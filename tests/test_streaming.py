@@ -19,12 +19,7 @@ jnp rebuild oracle:
   its per-step launch inside the loop;
 * ``GreedySpec``/``DPPRerankConfig`` validation: ``chunk_size`` on a
   backend that would silently ignore it fails at construction.
-
-The CI tiled-matrix job re-runs this suite with extra tile widths via
-``DPP_TILE_M`` (same contract as tests/test_kernel_tiled.py).
 """
-import os
-
 import numpy as np
 import pytest
 import jax
@@ -48,22 +43,15 @@ from repro.core import (
 )
 from repro.serving.reranker import DPPRerankConfig
 
-# the CI autotune lane sets DPP_TILE_M=auto — a policy mode, not a
-# width, so only digit values contribute an explicit tile here
-_ENV_TILE = (
-    int(os.environ["DPP_TILE_M"])
-    if os.environ.get("DPP_TILE_M", "").isdigit() else None
-)
-
 BACKENDS = ["jnp", "pallas_resident", "pallas_tiled", "sharded",
             "sharded_tiled"]
 
 
 def _spec(backend, k, window, chunk=None, eps=1e-6):
     """GreedySpec for one differential backend.  ``pallas_resident``
-    leaves tile_m to the policy (resident-size problems stream as one
+    leaves tile_m to the shapes (resident-size problems stream as one
     whole-M tile); ``pallas_tiled`` forces multi-tile sweeps."""
-    tile = _ENV_TILE or 128
+    tile = 128
     if backend == "jnp":
         # the jnp spec cannot carry chunk_size (GreedySpec rejects it);
         # the streaming calls pass it explicitly
@@ -235,7 +223,7 @@ def test_prefix_of_chunks_equals_whole_prefix_property():
 
 def _serving_cfgs():
     mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
-    tile = _ENV_TILE or 128
+    tile = 128
     return {
         "jnp": {},
         "pallas": dict(use_kernel=True, tile_m=tile),
@@ -313,14 +301,10 @@ def test_fused_chunk_is_one_pallas_call(window):
     assert counts == {"flat": 1, "looped": 0}, counts
 
     # contrast: the per-step whole-slate tiled driver launches per step
-    # (explicit TilePolicy: the structural claim needs the tiled path
-    # even when a DPP_TILE_M override — e.g. "auto", which resolves
-    # these resident-size shapes to one flat launch — is in effect)
-    from repro.kernels.dpp_greedy import TilePolicy, dpp_greedy
+    from repro.kernels.dpp_greedy import dpp_greedy
 
     jaxpr_whole = jax.make_jaxpr(
-        lambda v: dpp_greedy(v, k, window=window,
-                             tile_policy=TilePolicy(tile_m=128))
+        lambda v: dpp_greedy(v, k, window=window, tile_m=128)
     )(V[None])
     whole_counts = pallas_call_structure(jaxpr_whole)
     assert whole_counts["looped"] >= 1, whole_counts

@@ -5,7 +5,7 @@ compiler refuses: lane-axis dynamic slices, scalar stores to VMEM, a
 working set over the scoped-VMEM limit.  These tests compile each
 ``dpp_greedy`` kernel family at the served widths for one chip of a
 described ``v5e:2x2`` topology — nothing runs, no chip is needed — and
-the geometries ``TilePolicy`` picks at the edge of its VMEM budget; and
+the geometries ``plan_tile`` picks at the edge of its VMEM budget; and
 the candidate-sharded greedy over all four chips of that topology.
 Each compile also checks that the program names its kernel by the
 family's ``pallas_call`` name (``dpp_{resident,step,chunk}_{exact,
@@ -34,7 +34,7 @@ from repro.kernels.dpp_greedy.tiled import (
 from repro.kernels.dpp_greedy.tiling import (
     LANE,
     VMEM_BUDGET_BYTES,
-    TilePolicy,
+    plan_tile,
     round_up,
     untiled_vmem_bytes,
 )
@@ -137,10 +137,8 @@ def _chunk(shape, R, windowed, Dn, tile):
 
 
 def _policy_tile(Dn, R, windowed, chunked=False):
-    """The tile TilePolicy picks for a pool far past the budget."""
-    mode, tm = TilePolicy().decide(
-        Dn, 1 << 22, R, windowed, chunked=chunked
-    )
+    """The tile plan_tile picks for a pool far past the budget."""
+    mode, tm = plan_tile(Dn, 1 << 22, R, windowed, chunked=chunked)
     assert mode == "tiled"
     return tm
 
@@ -258,13 +256,11 @@ def test_batched_front_door_greedy_program_compiles(shape, monkeypatch,
     Dn, C = 100, 1000
     spec = DPPRerankConfig(slate_size=K, shortlist=C, alpha=3.0, eps=1e-3,
                            use_kernel=True).greedy_spec()
-    kernel = ops.dpp_greedy_plan(Dn, C, K)[:2]
-    assert kernel == ("resident", None)
+    assert ops.dpp_greedy_plan(Dn, C, K)[:2] == ("resident", None)
     api._greedy_program.clear_cache()
     try:
         compiled = _compile(
-            lambda V, m, ids: api._greedy_program(V, m, ids, spec=spec,
-                                                  kernel=kernel),
+            lambda V, m, ids: api._greedy_program(V, m, ids, spec=spec),
             shape((B, Dn, C)), shape((B, C), jnp.bool_) if masked else None,
             shape((B, C), jnp.int32),
         )
