@@ -8,9 +8,10 @@ Because each candidate only needs its own column of ``V`` (Gartrell et
 al., arXiv:1602.05436 low-rank factorization), device ``p`` of a
 P-device mesh computes on just the ``(D, M/P)`` column shard plus its
 slice of the ``c``/``d2`` Cholesky state — the dense ``(M, M)`` kernel
-``L`` never exists anywhere.  (The eager front end below still builds
-the full ``(D, M)`` ``V`` on the host before resharding; feeding the
-shards straight from a sharded feature store is a ROADMAP item.)
+``L`` never exists anywhere.  (The serving front end still builds the
+full ``(D, M)`` ``V`` from the features before laying it out along the
+mesh; feeding the shards straight from a sharded feature store is a
+ROADMAP item.)
 
 A request batch of B users shares the mesh: ``V (B, D, M)`` keeps the
 candidate axis sharded (every device holds a ``(B, D, M/P)`` block) and
@@ -55,7 +56,7 @@ demonstrate the path end to end on a host-device mesh.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -726,6 +727,67 @@ def dpp_greedy_sharded_stream_chunk(
     return new_state, sel, dh
 
 
+class MeshGreedy(NamedTuple):
+    """One mesh greedy resolved on the host for its shapes: the compiled
+    ``shard_map`` (``_greedy_fn``) and the candidate axis it runs, ``M``
+    padded to the mesh/tile quantum (``padded_candidates``)."""
+
+    fn: Callable
+    padded: int
+
+
+def plan_greedy(
+    D: int,
+    M: int,
+    k: int,
+    *,
+    mesh,
+    axis_name: str = "data",
+    window: Optional[int] = None,
+    eps: float = 1e-6,
+    tile_m: Optional[int] = None,
+    batched: bool = False,
+) -> MeshGreedy:
+    """The host part of ``dpp_greedy_sharded``: validate, resolve the
+    per-device tile (``_mesh_tile``) and count the dispatch and its
+    merges, from static shapes, once per call.  ``run_greedy`` then runs
+    the plan, inside or outside a ``jit``."""
+    if k <= 0:
+        raise ValueError(f"k must be >= 1, got {k}")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    validate_tile_m(tile_m)
+    nshards = _mesh_axis_size(mesh, axis_name)
+    window_eff = window if (window is not None and window < k) else None
+    windowed = window_eff is not None
+    R = window_eff if windowed else k
+    tile_m, source = _mesh_tile(tile_m, D, -(-M // nshards), R, windowed)
+    _record_dispatch(D, M, R, windowed, tile_m, source)
+    record_sharded_merges(k, windowed=windowed)
+    fn = _greedy_fn(mesh, axis_name, k, window_eff, float(eps), batched,
+                    tile_m)
+    return MeshGreedy(fn, padded_candidates(M, nshards, tile_m))
+
+
+def run_greedy(plan: MeshGreedy, V: jnp.ndarray,
+               mask: Optional[jnp.ndarray] = None) -> GreedyResult:
+    """The traceable part of ``dpp_greedy_sharded``: the default mask,
+    the zero pad of ``V`` and the mask up to ``plan.padded`` (nothing
+    where they arrive padded), and the compiled ``shard_map``."""
+    M = V.shape[-1]
+    mask_shape = V.shape[:-2] + (M,)
+    if mask is None:
+        mask = jnp.ones(mask_shape, bool)
+    elif mask.shape != mask_shape:
+        mask = jnp.broadcast_to(mask, mask_shape)
+    if plan.padded != M:
+        pad = [(0, 0)] * (V.ndim - 1) + [(0, plan.padded - M)]
+        V = jnp.pad(V, pad)
+        mask = jnp.pad(mask, pad[1:], constant_values=False)
+    sel, n, d_hist = plan.fn(V, mask)
+    return GreedyResult(sel, n, d_hist)
+
+
 def dpp_greedy_sharded(
     V: jnp.ndarray,
     k: int,
@@ -765,42 +827,21 @@ def dpp_greedy_sharded(
     one the jnp step bodies (the Pallas interpreter would only be
     slower).  ``M`` is padded up to a multiple of ``P * tile``, so a
     wide tile adds padded columns.
+
+    Composed of ``plan_greedy`` (host) and ``run_greedy`` (traceable),
+    which a caller that compiles the greedy into a program of its own
+    calls apart (``serving.api._sharded_rerank_impl``).
     """
     if V.ndim not in (2, 3):
         raise ValueError(
             f"dpp_greedy_sharded takes V (D, M) or a user batch (B, D, M), "
             f"got ndim={V.ndim}"
         )
-    if k <= 0:
-        raise ValueError(f"k must be >= 1, got {k}")
-    if window is not None and window < 1:
-        raise ValueError(f"window must be >= 1, got {window}")
-    validate_tile_m(tile_m)
-    batched = V.ndim == 3
-    nshards = _mesh_axis_size(mesh, axis_name)
-    M = V.shape[-1]
-    mask_shape = (V.shape[0], M) if batched else (M,)
-    if mask is None:
-        mask = jnp.ones(mask_shape, bool)
-    elif mask.shape != mask_shape:
-        mask = jnp.broadcast_to(mask, mask_shape)
-    window_eff = window if (window is not None and window < k) else None
-    R = k if window_eff is None else window_eff
-    tile_m, source = _mesh_tile(tile_m, V.shape[-2], -(-M // nshards), R,
-                                window_eff is not None)
-    Mp = padded_candidates(M, nshards, tile_m)
-    if Mp != M:
-        pad = [(0, 0)] * (V.ndim - 1) + [(0, Mp - M)]
-        V = jnp.pad(V, pad)
-        mask = jnp.pad(mask, pad[1:], constant_values=False)
-    _record_dispatch(V.shape[-2], M, R, window_eff is not None, tile_m,
-                     source)
-    record_sharded_merges(k, windowed=window_eff is not None)
-    fn = _greedy_fn(
-        mesh, axis_name, k, window_eff, float(eps), batched, tile_m,
+    plan = plan_greedy(
+        V.shape[-2], V.shape[-1], k, mesh=mesh, axis_name=axis_name,
+        window=window, eps=eps, tile_m=tile_m, batched=V.ndim == 3,
     )
-    sel, n, d_hist = fn(V, mask)
-    return GreedyResult(sel, n, d_hist)
+    return run_greedy(plan, V, mask)
 
 
 @functools.lru_cache(maxsize=64)

@@ -152,9 +152,11 @@ def record_rerank_call(path: str) -> None:
 def record_rerank_trace(path: str, phase: str) -> None:
     """One trace of ``Reranker.rerank``'s compiled ``phase`` program
     (``shortlist`` / ``greedy``) down ``path`` (``single`` /
-    ``batched``).  Called inside the program's body, so it counts cache
-    misses: ``serving_rerank_calls_total`` less this is how often a
-    cached program served the call."""
+    ``batched`` / ``sharded``).  Called inside the program's body, so it
+    counts cache misses: ``serving_rerank_calls_total`` less this is how
+    often a cached program served the call.  The mesh's V build serves
+    ``stream`` and router admission too, at the request's own width, so
+    their traces count under ``path=sharded, phase=shortlist`` as well."""
     reg = _obs.registry()
     if reg is None:
         return

@@ -444,19 +444,37 @@ def _pick(spec, V, mask, top_i):
 
 
 def _sharded_rerank_impl(scores, feats, cfg, mask, sharded_kernel):
-    from repro.core.sharded import dpp_greedy_sharded
+    """``Reranker.rerank`` on the mesh: two cached compiled programs, as
+    off the mesh.  The V build (``sharded_kernel``: the shortlist mask,
+    the relevance map, V, its pad to the greedy's padded candidate axis
+    and its layout along the mesh) and the greedy's (the mesh
+    ``shard_map`` and the ids' int32 cast), each dispatched inside its
+    phase span.
 
+    The mesh greedy's tile, padded width and compiled ``shard_map`` are
+    resolved here, on the host, once per call, from static shapes
+    (``plan_greedy``), and so are its counters: the kernel's mode and
+    tile and the merges each call makes."""
+    from repro.core.sharded import plan_greedy
+
+    plan = plan_greedy(
+        jnp.shape(feats)[-1], jnp.shape(scores)[-1], cfg.slate_size,
+        mesh=cfg.mesh, axis_name=cfg.axis_name, window=cfg.window,
+        eps=cfg.eps, tile_m=cfg.tile_m, batched=jnp.ndim(scores) == 2,
+    )
     with obs.span("serving.rerank.shortlist"):
-        V, smask = sharded_kernel(scores, feats, cfg, mask)
+        V, smask = sharded_kernel(scores, feats, cfg, mask, width=plan.padded)
     with obs.span("serving.rerank.greedy"):
-        res = dpp_greedy_sharded(
-            V,
-            cfg.slate_size,
-            mesh=cfg.mesh,
-            axis_name=cfg.axis_name,
-            window=cfg.window,
-            eps=cfg.eps,
-            mask=smask,
-            tile_m=cfg.tile_m,
-        )
-        return res.indices.astype(jnp.int32), res.d_hist
+        return _sharded_greedy_program(V, smask, plan=plan)
+
+
+@functools.partial(jax.jit, static_argnames=("plan",))
+def _sharded_greedy_program(V, mask, *, plan):
+    """The mesh greedy over V and the mask as the V build padded and laid
+    them out, and its ids as int32.  Keyed by the plan, whose compiled
+    ``shard_map`` stands for the mesh, k, window, eps and tile."""
+    from repro.core.sharded import run_greedy
+
+    record_rerank_trace("sharded", "greedy")
+    res = run_greedy(plan, V, mask)
+    return res.indices.astype(jnp.int32), res.d_hist

@@ -457,6 +457,70 @@ def test_rerank_batch_mask():
 
 
 # ---------------------------------------------------------------------------
+# The compiled mesh front door: one cached program per phase
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kind", ["single", "batched", "masked", "shortlist",
+                                  "padded"])
+def test_mesh_front_door_traces_each_phase_once_per_kind(kind):
+    """N calls of one kind of request on a mesh trace each phase's
+    program once and return the slates of the eager composition they
+    replace (the V build op by op under ``jax.disable_jit()``, then
+    ``dpp_greedy_sharded``, which pads V itself), index for index, with
+    gains to float32 rounding; the per-call counters count all N: the
+    calls, the dispatches, and k merges of each kind per call.
+    ``padded`` sets a tile, so V and the mask are padded to it inside
+    the V build."""
+    from repro import obs
+    from repro.serving import api, sharded_rerank
+
+    N, B, M, D, k = 3, 3, 90, 8, 6
+    rng = np.random.default_rng(41)
+    scores = rng.uniform(size=(B, M) if kind == "batched" else M)
+    feats = rng.normal(size=(M, D))
+    feats /= np.linalg.norm(feats, axis=1, keepdims=True)
+    scores = jnp.asarray(scores, jnp.float32)
+    feats = jnp.asarray(feats, jnp.float32)
+    mask = (jnp.asarray(rng.uniform(size=M) > 0.25) if kind == "masked"
+            else None)
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
+    tile_m = 128 if kind == "padded" else None
+    cfg = DPPRerankConfig(slate_size=k, shortlist=40 if kind == "shortlist"
+                          else M, alpha=3.0, eps=1e-6, mesh=mesh,
+                          tile_m=tile_m)
+    with jax.disable_jit():
+        V, smask = sharded_rerank._sharded_kernel(scores, feats, cfg, mask)
+    ref = dpp_greedy_sharded(V, k, mesh=mesh, eps=cfg.eps, mask=smask,
+                             tile_m=tile_m)
+    sharded_rerank._sharded_v_program.clear_cache()
+    api._sharded_greedy_program.clear_cache()
+    rr = Reranker(cfg)
+    req = RerankRequest(scores=scores, feats=feats, mask=mask)
+    obs.disable()
+    with obs.session(obs.ObsConfig(enabled=True)) as sess:
+        for _ in range(N):
+            ids, dh = rr.rerank(req)
+            assert ids.dtype == jnp.int32
+            np.testing.assert_array_equal(np.asarray(ids),
+                                          np.asarray(ref.indices))
+            np.testing.assert_allclose(np.asarray(dh),
+                                       np.asarray(ref.d_hist), rtol=1e-6)
+        snap = sess.registry.snapshot()["counters"]
+    assert snap["serving_rerank_traces_total"] == {
+        "path=sharded,phase=shortlist": 1, "path=sharded,phase=greedy": 1}
+    assert snap["serving_rerank_calls_total"] == {"path=sharded": N}
+    assert snap["sharded_merges_total"] == {
+        "kind=argmax": N * k, "kind=winner": N * k}
+    mode = "jnp" if tile_m is None else "tiled"
+    assert snap["dpp_kernel_dispatch_total"] == {
+        f"mode={mode},windowed=False": N}
+    if kind == "masked":
+        assert not set(np.asarray(ids).tolist()) & set(
+            np.flatnonzero(~np.asarray(mask)).tolist())
+
+
+# ---------------------------------------------------------------------------
 # The per-device tile and precision
 # ---------------------------------------------------------------------------
 
@@ -605,6 +669,55 @@ def test_mesh_rerank_matches_float64_reference_on_four_devices(window, tile_m):
     kinds = ["argmax", "winner"] + (["window"] if window else [])
     # k merges of each kind for the call, k more over the stream's chunks
     assert got["merges"] == {f"kind={kind}": 20 for kind in kinds}
+
+
+@pytest.mark.slow
+def test_mesh_rerank_batched_masked_shortlist_on_four_devices():
+    """``Reranker.rerank`` on a 4-device mesh for a user batch with
+    per-user masks and a shortlist narrower than the pool, at a tile
+    that pads M (1001 -> 1024): both compiled programs, the V build's
+    sharded top-k and pad among them, select each user's float64
+    reference slate (``bench/reference.py``) index for index, and a
+    second call traces nothing."""
+    out = run_subprocess("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import json
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import AxisType
+        from bench import reference
+        from repro import obs
+        from repro.obs import ObsConfig
+        from repro.serving import DPPRerankConfig, Reranker, RerankRequest
+        assert jax.device_count() == 4
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
+        rng = np.random.default_rng(12)
+        B, M, D, C, k, alpha = 2, 1001, 16, 300, 8, 3.0
+        feats = rng.normal(size=(M, D)).astype(np.float32)
+        feats /= np.linalg.norm(feats, axis=1, keepdims=True)
+        scores = rng.uniform(size=(B, M)).astype(np.float32)
+        mask = rng.uniform(size=(B, M)) > 0.3
+        ref = [reference.ref_rerank(feats, scores[b], C, k, alpha, 1e-6,
+                                    mask=mask[b])[0].tolist()
+               for b in range(B)]
+        session = obs.enable(ObsConfig(enabled=True, trace=False))
+        cfg = DPPRerankConfig(slate_size=k, shortlist=C, alpha=alpha,
+                              eps=1e-6, mesh=mesh, tile_m=128)
+        rr = Reranker(cfg)
+        req = RerankRequest(scores=jnp.asarray(scores),
+                            feats=jnp.asarray(feats), mask=jnp.asarray(mask))
+        ids = [np.asarray(rr.rerank(req)[0]).tolist() for _ in range(2)]
+        snap = session.registry.snapshot()["counters"]
+        print(json.dumps({
+            "ref": ref, "ids": ids,
+            "traces": snap["serving_rerank_traces_total"],
+            "modes": snap["dpp_kernel_dispatch_total"]}))
+    """)
+    got = json.loads(out.strip().splitlines()[-1])
+    assert got["ids"] == [got["ref"]] * 2
+    assert got["traces"] == {"path=sharded,phase=shortlist": 1,
+                             "path=sharded,phase=greedy": 1}
+    assert got["modes"] == {"mode=tiled,windowed=False": 2}
 
 
 # ---------------------------------------------------------------------------

@@ -268,3 +268,61 @@ def test_batched_front_door_greedy_program_compiles(shape, monkeypatch,
         api._greedy_program.clear_cache()
     assert _kernel_names(compiled) == {_family("resident", False)}
     assert "dot_general" not in compiled.as_text()
+
+
+def test_mesh_front_door_programs_compile(shape, topo, monkeypatch):
+    """``Reranker.rerank``'s two compiled programs on the mesh, over the
+    four chips of the described topology at the ``pool1m-4chip`` cell's
+    width and tile (D=32, k=20, tile 2048) and a reduced pool that the
+    tile pads: the V build leaves V and the mask laid out along the
+    mesh, moving at most V's padded columns between chips (one
+    ``collective-permute``), and the greedy's program reshards nothing
+    and runs the Pallas step kernel, with no jnp step body left.
+
+    Code that asks the platform sees this process's CPU, so the test
+    steers it to the compiled platform the topology describes, and
+    drops the programs it traced either way."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import sharded
+    from repro.kernels.dpp_greedy import tiled
+    from repro.serving import api, sharded_rerank
+
+    def compiled_platform(interpret=None):
+        return False if interpret is None else bool(interpret)
+
+    monkeypatch.setattr(sharded, "resolve_interpret", compiled_platform)
+    monkeypatch.setattr(tiled, "resolve_interpret", compiled_platform)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    along_m = lambda *spec: NamedSharding(mesh, P(*spec))
+    Dn, Mn, k = 32, 4 * 65536 - 1000, 20
+    plan = sharded.plan_greedy(Dn, Mn, k, mesh=mesh, tile_m=2048)
+    assert plan.padded == 4 * 65536
+    scores = jax.ShapeDtypeStruct((Mn,), jnp.float32,
+                                  sharding=along_m("data"))
+    feats = jax.ShapeDtypeStruct((Mn, Dn), jnp.float32,
+                                 sharding=along_m("data", None))
+    try:
+        build = sharded_rerank._sharded_v_program.lower(
+            scores, feats, None, mesh=mesh, axis_name="data", C=Mn,
+            alpha=3.0, width=plan.padded).compile()
+        V = jax.ShapeDtypeStruct((Dn, plan.padded), jnp.float32,
+                                 sharding=along_m(None, "data"))
+        mask = jax.ShapeDtypeStruct((plan.padded,), jnp.bool_,
+                                    sharding=along_m("data"))
+        greedy = api._sharded_greedy_program.lower(V, mask,
+                                                   plan=plan).compile()
+    finally:
+        sharded_rerank._sharded_v_program.clear_cache()
+        api._sharded_greedy_program.clear_cache()
+    assert [s.spec for s in build.output_shardings] == [
+        P(None, "data"), P("data")]
+    text = build.as_text()
+    assert text.count("collective-permute-start(") <= 1
+    assert not re.search(r"all-gather|all-to-all|all-reduce", text)
+    text = greedy.as_text()
+    assert _kernel_names(greedy) == {_family("step", False)}
+    assert not re.search(r"collective-permute|all-to-all|all-gather\(",
+                         text)
+    assert "dot_general" not in text
